@@ -33,32 +33,6 @@ from chainermn_tpu.resilience.cutpoints import COMM_ALLGATHER_OBJ, comm_point
 from chainermn_tpu.resilience.faults import inject
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX generations.
-
-    New JAX exposes ``jax.shard_map(check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(check_rep=...)`` (same knob,
-    pre-rename: static replication tracking). Every shard_map in the
-    framework funnels through here (or through :meth:`MeshCommunicator.
-    shard_map`), so the emulated-CPU-mesh harness — and the serving
-    engine's tensor-parallel decode — run on both generations.
-    """
-    if hasattr(jax, "shard_map"):  # the deprecation stub raises -> False
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    # check_rep is ALWAYS off on the legacy API: its psum2/pbroadcast
-    # rewrite auto-psums backward gradients of replicated inputs — the
-    # exact behavior the framework's pcast-to-varying pattern suppresses
-    # on new JAX (training.py: grads must stay per-rank so the
-    # communicator strategy owns the one reduction). With the rewrite
-    # disabled, legacy gradients are per-rank local by default and the
-    # explicit collectives carry the same semantics on both generations.
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
 def _is_traced(x) -> bool:
     return any(
         isinstance(leaf, jax.core.Tracer) for leaf in jax.tree_util.tree_leaves(x)
@@ -211,7 +185,7 @@ class MeshCommunicator(CommunicatorBase):
         statically-unprovable replication turn the check off)."""
         if check_vma is None:
             check_vma = self.check_vma
-        return _shard_map(
+        return jax.shard_map(
             f, mesh=self._mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=check_vma,
         )
@@ -459,7 +433,7 @@ class MeshCommunicator(CommunicatorBase):
                 return jax.tree_util.tree_map(lambda o: o[None, ...], out)
 
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     wrapper, mesh=self._mesh, in_specs=spec, out_specs=spec
                 )
             )

@@ -2,8 +2,7 @@
 
 ChainerMN's core lesson is that scaling dies on the host: the accelerator
 step is fast and everything serialized around it — data feeding, loss
-fetches, snapshot writes — becomes the wall (PERF.md: per-step blocked
-timing costs ~80 ms of host RTT vs ~52 ms queued on the same step). The
+fetches, snapshot writes — becomes the wall. The
 jitted steps already donate buffers; this package takes the HOST loop
 around them off the critical path, in three pieces:
 
@@ -13,8 +12,7 @@ around them off the critical path, in three pieces:
 - :class:`LossWindow` + :func:`device_fetch` — dispatch-ahead stepping:
   losses stay on device and are fetched batched every ``window`` steps
   (one round trip closes the whole window), bounding in-flight dispatch;
-  ``device_fetch`` is the trustworthy completion barrier (PERF.md's
-  relay-ack hazard) shared with ``bench.py``'s timing methodology.
+  ``device_fetch`` is the completion barrier that returns the values.
 - ``MultiNodeCheckpointer.save_async`` (``extensions.checkpoint``) —
   ``device_get`` on the training thread (the consistency point), then
   serialize + CRC footer + atomic rename + GC on a writer thread.

@@ -4,10 +4,9 @@
 TraceMe — the region shows up on the Python/host rows of an XProf/Perfetto
 capture) and ``jax.named_scope`` (trace-time name stack — the region's XLA
 ops carry the name in their metadata, so device rows are legible too).
-Either half degrades to a no-op when the running JAX lacks it (legacy
-releases), and entering them is cheap when no profiler is attached, so the
-annotations stay on permanently in the hot paths (train step bodies,
-serving prefill/decode, communicator collectives).
+Entering them is cheap when no profiler is attached, so the annotations
+stay on permanently in the hot paths (train step bodies, serving
+prefill/decode, communicator collectives).
 
 Scope names deliberately avoid XLA collective opcode spellings
 (``all-reduce`` etc.): names land in HLO ``op_name`` metadata, and
@@ -34,18 +33,10 @@ class _Annotation:
         # by whatever produced the work being annotated
         import jax
 
-        try:
-            tm = jax.profiler.TraceAnnotation(self._name)
-            tm.__enter__()
-            self._tm = tm
-        except Exception:
-            self._tm = None
-        try:
-            ns = jax.named_scope(self._name)
-            ns.__enter__()
-            self._ns = ns
-        except Exception:
-            self._ns = None
+        self._tm = jax.profiler.TraceAnnotation(self._name)
+        self._tm.__enter__()
+        self._ns = jax.named_scope(self._name)
+        self._ns.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
@@ -69,8 +60,7 @@ def annotate(name: str) -> _Annotation:
 
     Inside a trace the enclosed ops get ``name`` in their HLO metadata
     (named_scope); around a host call the region appears on the host
-    timeline (TraceAnnotation). No-op fallback on JAX builds lacking
-    either API.
+    timeline (TraceAnnotation).
     """
     return _Annotation(str(name))
 

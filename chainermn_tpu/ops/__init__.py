@@ -6,7 +6,11 @@ holds the ops where hand-written kernels beat XLA's default lowering, plus
 TPU-idiomatic extensions (microbatched pipeline schedule).
 """
 
-from chainermn_tpu.ops.flash_attention import flash_attention
+from chainermn_tpu.ops.flash_attention import (
+    flash_attention,
+    kernels_interpreted,
+    set_kernels_interpreted,
+)
 from chainermn_tpu.ops.pipeline import (
     init_pipeline_lm,
     jit_pp_lm_train_step,
@@ -17,6 +21,8 @@ from chainermn_tpu.ops.pipeline import (
 
 __all__ = [
     "flash_attention",
+    "kernels_interpreted",
+    "set_kernels_interpreted",
     "pipeline_apply",
     "make_pipeline_lm",
     "init_pipeline_lm",

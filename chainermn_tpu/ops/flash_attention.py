@@ -104,8 +104,28 @@ def _pick_block(t: int, preferred: int = 1024) -> int:
     return b if b >= step else 1
 
 
-def _interpret_default() -> bool:
+_interpret_override: Optional[bool] = None
+
+
+def kernels_interpreted() -> bool:
+    """Whether the Pallas kernels run in the interpreter: they compile with
+    Mosaic on a TPU backend and are interpreted anywhere else. The ONE place
+    that decides it, for the flash kernels, the paged decode kernel and the
+    train steps' ``check_vma`` choice alike. A script that lowers for an
+    abstract TPU from a CPU host (``scripts/aot_*.py``) calls
+    :func:`set_kernels_interpreted` first — lowering the interpreted program
+    there describes a program the chip never runs."""
+    if _interpret_override is not None:
+        return _interpret_override
     return jax.default_backend() != "tpu"
+
+
+def set_kernels_interpreted(value: Optional[bool]) -> None:
+    """Override :func:`kernels_interpreted` for every kernel and train step
+    (``False``: trace as the chip does); ``None`` returns to the backend's
+    default."""
+    global _interpret_override
+    _interpret_override = value
 
 
 def _default_block(t: int) -> int:
@@ -120,22 +140,6 @@ def _default_block(t: int) -> int:
     return 1024
 
 
-def _sds(shape, dtype, vma):
-    """``jax.ShapeDtypeStruct`` with a vma annotation where supported;
-    legacy JAX has no vma field (and no tracking to need it)."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _compiler_params(**kw):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams``
-    (legacy 0.4.x) — same fields, pre-rename."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
 def _out_vma(*xs) -> frozenset:
     """Varying-manner annotation for kernel outputs: the union of the
     inputs' vma sets. pallas_call does not infer vma, so under
@@ -144,12 +148,8 @@ def _out_vma(*xs) -> frozenset:
     schedule analysis (scripts/aot_ring_overlap.py); the CPU suite never
     sees it because interpret-mode tests run with check_vma=False."""
     vma = frozenset()
-    if not hasattr(jax, "typeof"):  # legacy JAX: no vma tracking at all
-        return vma
     for x in xs:
-        v = getattr(jax.typeof(x), "vma", None)
-        if v:
-            vma |= v
+        vma |= jax.typeof(x).vma
     return vma
 
 
@@ -178,9 +178,9 @@ def _static_delta(causal, q_offset, k_offset):
     causal FLOP saving (pl.when compute skip) gains the matching ~2x DMA
     saving. This matters more than it sounds: the reduction-chunk grids
     re-stream K/V once per q-block (and q/do once per k-block in the dkv
-    kernel), so attention bytes, not attention FLOPs, are the LM step's
-    roofline term (scripts/lm_roofline_aot.jsonl: ~1% of FLOPs, over half
-    the bytes). Traced offsets (ring shards) return None — the ring layer
+    kernel), so a masked chunk that is still copied costs bytes for no
+    FLOPs (the kernels' share of the LM step: not measured). Traced
+    offsets (ring shards) return None — the ring layer
     already skips wholly-invisible blocks at the block level."""
     if (causal and isinstance(q_offset, (int, np.integer))
             and isinstance(k_offset, (int, np.integer))):
@@ -331,17 +331,17 @@ def _fwd(q, k, v, q_offset, k_offset, *, scale, causal, block_q, block_k,
             # out_dtype=f32 lets ring callers merge partial block outputs
             # without a bf16 round-trip (q/k/v still feed the MXU in their
             # input dtype; the kernel accumulates f32 regardless)
-            _sds((bh, tq, d), out_dtype or q.dtype,
-                                 _out_vma(qo, ko, q, k, v)),
-            _sds((bh, tq, _LANE), jnp.float32,
-                                 _out_vma(qo, ko, q, k, v)),
+            jax.ShapeDtypeStruct((bh, tq, d), out_dtype or q.dtype,
+                                 vma=_out_vma(qo, ko, q, k, v)),
+            jax.ShapeDtypeStruct((bh, tq, _LANE), jnp.float32,
+                                 vma=_out_vma(qo, ko, q, k, v)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running max m
             pltpu.VMEM((block_q, _LANE), jnp.float32),   # running denom l
             pltpu.VMEM((block_q, d), jnp.float32),       # unnormalized acc
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qo, ko, q, k, v)
@@ -502,10 +502,11 @@ def _dq_call(q, k, v, do, lse, delta, qo2, ko2, *, scale, causal, block_q,
             pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((bh, tq, d), grad_dtype or q.dtype,
-                                       _out_vma(qo2, ko2, q, k, v, do)),
+        out_shape=jax.ShapeDtypeStruct(
+            (bh, tq, d), grad_dtype or q.dtype,
+            vma=_out_vma(qo2, ko2, q, k, v, do)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qo2, ko2, q, k, v, do, lse, delta)
@@ -542,17 +543,17 @@ def _dkv_call(q, k, v, do, lse, delta, qo2, ko2, *, scale, causal, block_q,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            _sds((bh, tk, d), grad_dtype or k.dtype,
-                                 _out_vma(qo2, ko2, q, k, v, do)),
-            _sds((bh, tk, d), grad_dtype or v.dtype,
-                                 _out_vma(qo2, ko2, q, k, v, do)),
+            jax.ShapeDtypeStruct((bh, tk, d), grad_dtype or k.dtype,
+                                 vma=_out_vma(qo2, ko2, q, k, v, do)),
+            jax.ShapeDtypeStruct((bh, tk, d), grad_dtype or v.dtype,
+                                 vma=_out_vma(qo2, ko2, q, k, v, do)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         # the q-chunk dim accumulates into the scratch -> sequential
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qo2, ko2, q, k, v, do, lse, delta)
@@ -628,7 +629,7 @@ def flash_attention(
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernels_interpreted()
     bq = _pick_block(tq, block_q or _default_block(tq))
     bk = _pick_block(tk, block_k or _default_block(tk))
     if bq < min(8, tq) or bk < min(8, tk):
@@ -698,7 +699,7 @@ def flash_fwd_with_lse(q, k, v, *, causal=False, scale=None, q_offset=0,
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernels_interpreted()
     bq = _pick_block(tq, block_q or _default_block(tq))
     bk = _pick_block(tk, block_k or _default_block(tk))
     _check_blocks(bq, bk, tq, tk)
@@ -729,7 +730,7 @@ def flash_block_grads(q, k, v, do, lse, delta, *, causal=False, scale=None,
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernels_interpreted()
     bq = _pick_block(tq, block_q or _default_block(tq))
     bk = _pick_block(tk, block_k or _default_block(tk))
     _check_blocks(bq, bk, tq, tk)

@@ -34,8 +34,6 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from chainermn_tpu.utils import axis_size as _axis_size
-from chainermn_tpu.utils import pcast_varying
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,7 +68,7 @@ def pipeline_apply(
     """
     if remat:
         stage_fn = jax.checkpoint(stage_fn)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b = x.shape[0]
     if b % n_microbatches:
@@ -91,7 +89,7 @@ def pipeline_apply(
 
     # the carry is per-device state (varying over the pipeline axis); without
     # the cast the scan carry's replicated-ness differs between input/output
-    state0 = pcast_varying(jnp.zeros_like(micro[0]), (axis_name,))
+    state0 = lax.pcast(jnp.zeros_like(micro[0]), (axis_name,), to="varying")
     _, outs = lax.scan(tick, state0, jnp.arange(ticks))
     # the last stage emits valid microbatch m at tick m + n - 1; everything
     # it produced earlier is fill garbage. Select the valid window and

@@ -62,11 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 from chainermn_tpu.ops.flash_attention import (
     _LANE,
     _NEG_BIG,
-    _compiler_params,
-    _interpret_default,
     _out_vma,
     _prec,
-    _sds,
+    kernels_interpreted,
 )
 
 
@@ -109,8 +107,9 @@ def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     (``row % H != col % H``) are masked to the sentinel and zeroed in
     ``p`` exactly like dead positions, so they add exact +0.0 terms to
     the contractions. That spends H× the MXU work of a per-head sweep —
-    free in practice: decode attention is DMA-bound (PERF.md's roofline),
-    and this shape is what buys one DMA per live block for ALL heads."""
+    expected to be free: decode attention reads each KV byte once for a
+    few FLOPs (kernel time on the chip: not measured), and this shape is
+    what buys one DMA per live block for ALL heads."""
     if quant:
         ks_ref, vs_ref, o_ref, m_acc, l_acc, o_acc = rest
     else:
@@ -242,7 +241,7 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernels_interpreted()
     quant = k_scale is not None
     table = jnp.asarray(table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -285,8 +284,8 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
                 pltpu.VMEM((s_len * h, d), jnp.float32),      # unnorm. acc
             ],
         ),
-        out_shape=_sds((b, s_len * h, d), q.dtype, vma),
-        compiler_params=_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((b, s_len * h, d), q.dtype, vma=vma),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, lengths, *operands)

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chainermn_tpu.utils import axis_size as _axis_size
 
 _NEG_BIG = -1e30  # finite "minus infinity": avoids inf-inf NaNs in masked rows
 
@@ -65,27 +64,14 @@ def chunk_spans(start: int, total: int, chunk_len: int
     return spans
 
 
-def _typeof_vma(x):
-    """Varying-manner set of a traced value; empty on legacy JAX (no
-    ``jax.typeof``/vma — replication tracking is off there, see
-    ``_vary_to``)."""
-    return jax.typeof(x).vma if hasattr(jax, "typeof") else frozenset()
-
-
 def _vary_to(x, vma):
     """pcast ``x`` to varying over exactly the axes in ``vma`` it does not
     already vary on. A plain ``pcast(..., to='varying')`` on a value that
     already carries some of the axes raises ("Unsupported pcast
     from=varying, to='varying'") — hit once the flash kernels started
-    propagating input vma to their outputs (round 5). Legacy JAX (no
-    ``jax.typeof``/vma) runs shard_map with replication tracking off
-    (``mesh_communicator._shard_map``), where everything is already
-    varying — identity."""
-    if not hasattr(jax, "typeof"):
-        return x
-    need = tuple(a for a in vma if a not in _typeof_vma(x))
+    propagating input vma to their outputs (round 5)."""
+    need = tuple(a for a in vma if a not in jax.typeof(x).vma)
     return lax.pcast(x, need, to="varying") if need else x
-
 
 
 def _block_attend(q, k, v, *, scale, mask, m, l, o):
@@ -134,7 +120,7 @@ def ring_attention(
             f"ring_attention needs a single named mesh axis, got {axis_name!r} "
             "— use a flat communicator (e.g. 'tpu') for sequence parallelism"
         )
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     if scale is None:
@@ -147,8 +133,8 @@ def ring_attention(
     # q/k/v carry the tensor axis's vma too; a ring-axis-only pcast would
     # make the carry types diverge after one iteration). With check_vma off
     # the vma sets are empty and this degenerates to the ring axis alone.
-    vma = (frozenset({axis_name}) | _typeof_vma(q)
-           | _typeof_vma(k) | _typeof_vma(v))
+    vma = (frozenset({axis_name}) | jax.typeof(q).vma
+           | jax.typeof(k).vma | jax.typeof(v).vma)
     _vary = lambda x: _vary_to(x, vma)
     m0 = _vary(jnp.full((b, h, t), _NEG_BIG, jnp.float32))
     l0 = _vary(jnp.zeros((b, h, t), jnp.float32))
@@ -241,12 +227,12 @@ def _ring_flash(q, k, v, axis_name, causal, scale):
 def _ring_flash_fwd_pass(q, k, v, axis_name, causal, scale):
     from chainermn_tpu.ops.flash_attention import flash_fwd_with_lse
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
-    vma = (frozenset({axis_name}) | _typeof_vma(q)
-           | _typeof_vma(k) | _typeof_vma(v))
+    vma = (frozenset({axis_name}) | jax.typeof(q).vma
+           | jax.typeof(k).vma | jax.typeof(v).vma)
     _vary = lambda x: _vary_to(x, vma)
     o0 = _vary(jnp.zeros((b, t, h, d), jnp.float32))
     lse0 = _vary(jnp.full((b, h, t), _NEG_BIG, jnp.float32))
@@ -276,7 +262,7 @@ def _ring_flash_bwd_rule(axis_name, causal, scale, res, do):
     from chainermn_tpu.ops.flash_attention import flash_block_grads
 
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -284,7 +270,7 @@ def _ring_flash_bwd_rule(axis_name, causal, scale, res, do):
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1)
-    vma = (_typeof_vma(q) | _typeof_vma(do)
+    vma = (jax.typeof(q).vma | jax.typeof(do).vma
            | frozenset({axis_name}))
     _vary = lambda x: _vary_to(x, vma)
     dq0 = _vary(jnp.zeros((b, t, h, d), jnp.float32))
@@ -361,7 +347,7 @@ def _zigzag_flash(q, k, v, axis_name, scale):
 def _zigzag_flash_fwd_pass(q, k, v, axis_name, scale):
     from chainermn_tpu.ops.flash_attention import flash_fwd_with_lse
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     if t % 2:
@@ -441,7 +427,7 @@ def _zigzag_flash_bwd_rule(axis_name, scale, res, do):
     from chainermn_tpu.ops.flash_attention import flash_block_grads
 
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     c = t // 2
@@ -449,7 +435,7 @@ def _zigzag_flash_bwd_rule(axis_name, scale, res, do):
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1)
-    vma = _typeof_vma(q) | _typeof_vma(do) | frozenset({axis_name})
+    vma = jax.typeof(q).vma | jax.typeof(do).vma | frozenset({axis_name})
     _vary = lambda x: _vary_to(x, vma)
     off_e, off_l = my * c, (2 * n - 1 - my) * c
 
@@ -628,7 +614,7 @@ def zigzag_ring_attention(
             f"zigzag_ring_attention needs a single named mesh axis, got "
             f"{axis_name!r}"
         )
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t, h, d = q.shape
     if t % 2:
@@ -638,8 +624,8 @@ def zigzag_ring_attention(
         scale = d ** -0.5
 
     q32 = q.astype(jnp.float32)
-    vma = (frozenset({axis_name}) | _typeof_vma(q)
-           | _typeof_vma(k) | _typeof_vma(v))
+    vma = (frozenset({axis_name}) | jax.typeof(q).vma
+           | jax.typeof(k).vma | jax.typeof(v).vma)
     _vary = lambda x: _vary_to(x, vma)
     m = _vary(jnp.full((b, h, t), _NEG_BIG, jnp.float32))
     l = _vary(jnp.zeros((b, h, t), jnp.float32))
@@ -751,7 +737,7 @@ def ulysses_attention(
         # O(T^2) score tile the flag exists to avoid
         raise ValueError(
             f"block_impl must be 'xla' or 'flash', got {block_impl!r}")
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n != 0:
         raise ValueError(f"heads ({h}) must be divisible by axis size ({n})")
@@ -1099,7 +1085,7 @@ def sequence_parallel_attention(
 
     def f(q, k, v):
         try:
-            _axis_size(axis_name)
+            lax.axis_size(axis_name)
         except NameError:
             # axis not bound: we're outside shard_map (flax init, eval on a
             # gathered sequence) — the whole sequence is local, so exact

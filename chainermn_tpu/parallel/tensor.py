@@ -45,10 +45,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis_name: str) -> int:
-    return lax.psum(1, axis_name)
-
-
 class ColumnParallelDense(nn.Module):
     """``y = x @ W[:, my_slice] + b[my_slice]`` — output feature-sharded.
 
@@ -66,7 +62,7 @@ class ColumnParallelDense(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        n = _axis_size(self.axis_name)
+        n = lax.axis_size(self.axis_name)
         if self.features % n:
             raise ValueError(
                 f"global features {self.features} not divisible by "
@@ -103,7 +99,7 @@ class RowParallelDense(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        n = _axis_size(self.axis_name)
+        n = lax.axis_size(self.axis_name)
         local_in = x.shape[-1]
         global_in = self.in_features or local_in * n
         if global_in % n:
@@ -181,7 +177,7 @@ class TensorParallelAttention(nn.Module):
                 "mask); causal=False with a cache would silently mask "
                 "attention to later cached positions"
             )
-        n = _axis_size(self.axis_name)
+        n = lax.axis_size(self.axis_name)
         if self.n_heads % n:
             raise ValueError(
                 f"n_heads {self.n_heads} not divisible by tensor-axis size {n}"
@@ -339,12 +335,6 @@ def global_objective(local_loss, axes):
 
     if isinstance(axes, str):
         axes = (axes,)
-    if not hasattr(jax, "typeof"):
-        # Legacy JAX has no vma tracking at all: pmean over EVERY requested
-        # axis. Math is unchanged — pmean of a value that happens to be
-        # replicated over an axis returns the same value — and the backward
-        # psums the pattern needs come from pmean's own transpose.
-        return lax.pmean(local_loss, axes)
     # The pattern is built ON vma tracking: with check_vma=False every value
     # reads as vma-empty, no pmean would ever fire, and the "grads" would be
     # per-rank garbage — fail loudly instead (axis_index is varying by

@@ -512,12 +512,13 @@ class ServingEngine:
             self._build_tp_fns(comm)
         elif self.paged:
             self.caches = None          # the block store IS the cache
-            self._store = self._init_paged_store()
+            self._store = self._place(self._init_paged_store())
             self._build_fns()
         else:
-            self.caches = init_kv_caches(model, self.n_slots, self.cache_len)
+            self.caches = self._place(
+                init_kv_caches(model, self.n_slots, self.cache_len))
             if self.prefix_cache is not None:
-                self._store = self._init_store()
+                self._store = self._place(self._init_store())
             self._build_fns()
 
         # host-side slot mirror: the scheduler reads/writes through the
@@ -580,9 +581,31 @@ class ServingEngine:
         if self.model.tensor_axis is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            keys = jax.device_put(
+            return jax.device_put(
                 keys, NamedSharding(self._comm.mesh, P()))
-        return keys
+        return self._place(keys)
+
+    def _place(self, tree):
+        """Put engine-owned device state (KV store, caches, sampler keys)
+        where the parameters are. A program's outputs take the placement
+        of its committed inputs: with parameters committed to a device —
+        a trainer's ``bcast_data``, a restored checkpoint, any
+        ``device_put`` — state created uncommitted comes back from its
+        first call committed, and every program then meets a second jit
+        cache key (one recompile each) on the first traffic after
+        :meth:`warmup`. Uncommitted parameters leave everything
+        uncommitted; the tensor-parallel path places its own state."""
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        if (self.model.tensor_axis is not None
+                or not getattr(leaf, "committed", False)
+                or not leaf.sharding.is_fully_replicated):
+            return tree
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        sh = leaf.sharding
+        if isinstance(sh, NamedSharding):
+            sh = NamedSharding(sh.mesh, P())    # fits any rank
+        return jax.device_put(tree, sh)
 
     def _watched(self, label: str, **ctx):
         """Watchdog context for one device-program call (no-op when hang
@@ -1246,10 +1269,7 @@ class ServingEngine:
                         jnp.zeros((k,), bool),
                         jnp.zeros((k, 2), jnp.uint32))
             with self._watched("serving warmup decode"):
-                self._store, _, _ = self._decode_fn(
-                    self.params, self._store, jnp.asarray(self._tables),
-                    jnp.asarray(self._token), jnp.asarray(self._pos),
-                    jnp.asarray(self._active), self._keys)
+                self._store, _, _ = self._decode_fn(*self._decode_args())
             if self.migration_supported:
                 # all-scratch ids + n_used=0 at EVERY bucket width: the
                 # gather reads scratch, the scatter re-writes scratch's
@@ -1265,10 +1285,7 @@ class ServingEngine:
             if self.decode_window > 1:
                 with self._watched("serving warmup decode_window"):
                     self._store, _, _ = self._window_fn(
-                        self.params, self._store,
-                        jnp.asarray(self._tables),
-                        jnp.asarray(self._token), jnp.asarray(self._pos),
-                        jnp.asarray(self._active), self._keys)
+                        *self._decode_args())
             if self._spec is not None:
                 # all rows inactive + valid=0: every verify-window write
                 # lands in the scratch block — the one compile covers
@@ -1297,10 +1314,7 @@ class ServingEngine:
                         zeros_i, jnp.zeros((k,), bool),
                         jnp.zeros((k, 2), jnp.uint32), *extra)
             with self._watched("serving warmup decode"):
-                self.caches, _, _ = self._decode_fn(
-                    self.params, self.caches, jnp.asarray(self._token),
-                    jnp.asarray(self._pos), jnp.asarray(self._active),
-                    self._keys)
+                self.caches, _, _ = self._decode_fn(*self._decode_args())
             if self.prefix_cache is not None:
                 ids = jnp.zeros((self._n_prog_blocks,), jnp.int32)
                 with self._watched("serving warmup prefix"):
@@ -2248,6 +2262,25 @@ class ServingEngine:
             self._store = self._init_store()
         self.prefix_cache.clear()
 
+    def _decode_args(self) -> tuple:
+        """The operands of the decode program (and of the window program,
+        which takes the same): parameters, the KV state it hands back
+        updated — the block store and its tables, or the dense caches —
+        and the host-side slot mirror. Warm-up, every decode call and
+        :meth:`decode_program_text` build them here, so they cannot drift
+        apart."""
+        kv = ((self._store, jnp.asarray(self._tables)) if self.paged
+              else (self.caches,))
+        return (self.params, *kv, jnp.asarray(self._token),
+                jnp.asarray(self._pos), jnp.asarray(self._active),
+                self._keys)
+
+    def _set_kv_state(self, state) -> None:
+        if self.paged:
+            self._store = state
+        else:
+            self.caches = state
+
     def decode_step(self, ctx: Optional[dict] = None) -> dict[int, int]:
         """Advance every active slot one token (ONE compiled call for the
         whole pool); returns ``{slot: token}`` for the active slots. No-op
@@ -2261,16 +2294,8 @@ class ServingEngine:
         with self._watched("serving decode_step", **(ctx or {})), \
                 annotate("chainermn.serving_decode"):
             inject(SERVING_DECODE, active=int(self._active.sum()))
-            if self.paged:
-                self._store, nxt, self._keys = self._decode_fn(
-                    self.params, self._store, jnp.asarray(self._tables),
-                    jnp.asarray(self._token), jnp.asarray(self._pos),
-                    jnp.asarray(self._active), self._keys)
-            else:
-                self.caches, nxt, self._keys = self._decode_fn(
-                    self.params, self.caches, jnp.asarray(self._token),
-                    jnp.asarray(self._pos), jnp.asarray(self._active),
-                    self._keys)
+            state, nxt, self._keys = self._decode_fn(*self._decode_args())
+            self._set_kv_state(state)
             nxt = device_fetch(nxt)
         self._c_decode_steps.inc()
         self._events.emit("decode_step", active=int(self._active.sum()))
@@ -2301,16 +2326,8 @@ class ServingEngine:
         with self._watched("serving decode_steps", **(ctx or {})), \
                 annotate("chainermn.serving_decode"):
             inject(SERVING_DECODE, active=int(self._active.sum()), window=n)
-            if self.paged:
-                self._store, out, self._keys = self._window_fn(
-                    self.params, self._store, jnp.asarray(self._tables),
-                    jnp.asarray(self._token), jnp.asarray(self._pos),
-                    jnp.asarray(self._active), self._keys)
-            else:
-                self.caches, out, self._keys = self._window_fn(
-                    self.params, self.caches, jnp.asarray(self._token),
-                    jnp.asarray(self._pos), jnp.asarray(self._active),
-                    self._keys)
+            state, out, self._keys = self._window_fn(*self._decode_args())
+            self._set_kv_state(state)
             out = device_fetch(out)
         self._c_decode_steps.inc()
         self._events.emit("decode_step", active=int(self._active.sum()),
@@ -2503,12 +2520,12 @@ class ServingEngine:
         if self.model.tensor_axis is not None:
             self._init_tp_caches(self._comm)
         elif self.paged:
-            self._store = self._init_paged_store()
+            self._store = self._place(self._init_paged_store())
         else:
-            self.caches = init_kv_caches(self.model, self.n_slots,
-                                         self.cache_len)
+            self.caches = self._place(init_kv_caches(
+                self.model, self.n_slots, self.cache_len))
             if self.prefix_cache is not None:
-                self._store = self._init_store()
+                self._store = self._place(self._init_store())
         if self.prefix_cache is not None:
             self.prefix_cache.clear()
         if self.paged:
@@ -2623,6 +2640,14 @@ class ServingEngine:
         """Recompiles observed past each program's warmup compile (the
         guard's live count; empty == the invariant holds)."""
         return self._guard.recompiles
+
+    def decode_program_text(self) -> str:
+        """The decode step as lowered for this backend (StableHLO text):
+        what an on-chip check reads to see which attention read path the
+        program really holds — a Mosaic ``tpu_custom_call``, the
+        interpreted kernel, or the XLA gather. Lowering traces but
+        compiles and runs nothing, so ``compile_counts`` do not move."""
+        return self._decode_fn.lower(*self._decode_args()).as_text()
 
     def prefix_stats(self) -> dict:
         """The prefix cache's hit/eviction/occupancy numbers (empty dict

@@ -35,11 +35,9 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform, ensure_batch_fits
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
 from chainermn_tpu import models
 from chainermn_tpu.training import jit_train_step
+from chainermn_tpu.utils import enable_compilation_cache, ensure_batch_fits
 
 ARCHS = {
     "resnet18": lambda n: models.ResNet18(num_classes=n),
@@ -180,6 +178,7 @@ def main() -> None:
                              "gather/scatter (parallel.fsdp); BN statistics "
                              "become global-batch (sync-BN) by construction")
     args = parser.parse_args()
+    enable_compilation_cache()
 
     if args.fsdp and (args.mnbn or args.double_buffering):
         # MNBN's explicit collectives need shard_map axis names, which the

@@ -170,16 +170,14 @@ import jax.numpy as jnp
 import numpy as np
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform
-
-apply_env_platform()
-from chainermn_tpu import monitor  # noqa: E402
-from chainermn_tpu.models import TransformerLM  # noqa: E402
-from chainermn_tpu.serving import (  # noqa: E402
+from chainermn_tpu import monitor
+from chainermn_tpu.models import TransformerLM
+from chainermn_tpu.serving import (
     QueueFullError,
     ServingClient,
     ServingEngine,
 )
+from chainermn_tpu.utils import enable_compilation_cache
 
 
 def main() -> None:
@@ -404,6 +402,7 @@ def main() -> None:
                          "steps up, a drained queue steps back down, "
                          "and the episode prints at the end (0: off)")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     comm = chainermn_tpu.create_communicator("tpu") if args.tensor_parallel \
         else None

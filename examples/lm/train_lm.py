@@ -30,12 +30,10 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform
-
-apply_env_platform()
-from chainermn_tpu import monitor  # noqa: E402
-from chainermn_tpu.models import TransformerLM  # noqa: E402
-from chainermn_tpu.training import jit_lm_train_step  # noqa: E402
+from chainermn_tpu import monitor
+from chainermn_tpu.models import TransformerLM
+from chainermn_tpu.training import jit_lm_train_step
+from chainermn_tpu.utils import enable_compilation_cache
 
 
 def _dump_traces(args) -> None:
@@ -514,6 +512,7 @@ def main() -> None:
                         help="positional-embedding table size "
                              "(default: just enough for --seq-len)")
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator("tpu")

@@ -28,11 +28,9 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform, ensure_batch_fits
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
 from chainermn_tpu.models import MLP
 from chainermn_tpu.training import jit_train_step
+from chainermn_tpu.utils import enable_compilation_cache, ensure_batch_fits
 
 
 def load_mnist(path: str | None, n_train: int, n_test: int, seed: int = 0):
@@ -91,6 +89,7 @@ def main() -> None:
     parser.add_argument("--n-train", type=int, default=10000)
     parser.add_argument("--n-test", type=int, default=2000)
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator(args.communicator)

@@ -21,11 +21,9 @@ import jax.numpy as jnp
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform, ensure_batch_fits
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
 from chainermn_tpu.models import MLP
 from chainermn_tpu.training import jit_train_step
+from chainermn_tpu.utils import enable_compilation_cache, ensure_batch_fits
 
 from train_mnist import ArrayDataset, collate, load_mnist  # noqa: E402 (sibling)
 
@@ -46,6 +44,7 @@ def main() -> None:
     parser.add_argument("--data", type=str, default=None)
     parser.add_argument("--n-train", type=int, default=4000)
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator(args.communicator)

@@ -26,9 +26,7 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
+from chainermn_tpu.utils import enable_compilation_cache
 
 from train_mnist import ArrayDataset, collate, load_mnist  # noqa: E402 (sibling)
 
@@ -73,6 +71,7 @@ def main() -> None:
              "instead of a jit per stage",
     )
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator("naive")

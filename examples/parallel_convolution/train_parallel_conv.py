@@ -47,9 +47,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform, ensure_batch_fits
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
+from chainermn_tpu.utils import enable_compilation_cache, ensure_batch_fits
 
 
 # --------------------------------------------------------------------------- #
@@ -211,6 +209,7 @@ def main() -> None:
                         help="assert parallel forward == serial forward "
                              "with the same weights before training")
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator("tpu")

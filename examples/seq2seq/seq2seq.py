@@ -39,9 +39,7 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import apply_env_platform
-
-apply_env_platform()  # honor JAX_PLATFORMS even under plugin-forcing containers
+from chainermn_tpu.utils import enable_compilation_cache
 
 BOS = 0  # decoder start token; task vocabulary occupies [1, vocab)
 
@@ -154,6 +152,7 @@ def main() -> None:
                         help="data x model parallel over >= 4 devices "
                              "(comm.split by role, reference seq2seq_mp1)")
     args = parser.parse_args()
+    enable_compilation_cache()
 
     chainermn_tpu.add_global_except_hook()
     comm = chainermn_tpu.create_communicator("naive")

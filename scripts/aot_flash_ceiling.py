@@ -38,13 +38,9 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import importlib
+    from chainermn_tpu.ops import flash_attention, set_kernels_interpreted
 
-    # import_module, not `import ... as`: ops/__init__ re-exports the
-    # flash_attention FUNCTION, which shadows the submodule in attribute
-    # lookup (the same trap aot_ring_overlap.py sidesteps)
-    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
-    fa._interpret_default = lambda: False  # Mosaic lowering during AOT trace
+    set_kernels_interpreted(False)  # Mosaic lowering during AOT trace
 
     # smallest valid v5e topology is 2x2 (chips_per_host_bounds); the
     # ceiling is still a single-device property — the kernel call is
@@ -87,9 +83,9 @@ def main():
 
             def loss(q, k, v):
                 def body(q, k, v):
-                    o = fa.flash_attention(q, k, v, causal=True,
-                                           interpret=False, block_q=blk,
-                                           block_k=blk)
+                    o = flash_attention(q, k, v, causal=True,
+                                        interpret=False, block_q=blk,
+                                        block_k=blk)
                     return jnp.sum(o.astype(jnp.float32) ** 2)
 
                 return jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 3,

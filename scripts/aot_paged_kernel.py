@@ -39,7 +39,6 @@ def main():
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import topologies
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from chainermn_tpu.parallel import paged_kernel as pk
@@ -88,9 +87,9 @@ def main():
                     return pk.paged_attend(q, sk, sv, table, lengths,
                                            interpret=False, **kw)
 
-                return shard_map(
+                return jax.shard_map(
                     body, mesh=mesh, in_specs=(P(),) * len(avals),
-                    out_specs=P(), check_rep=False,
+                    out_specs=P(), check_vma=False,
                 )(q, sk, sv, table, lengths, *scales)
 
             rec = {"cell": label, "kv_quant": quant, "batch": b,

@@ -122,12 +122,7 @@ def main():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     shard_map = jax.shard_map
-    import importlib
-
-    # NOT `import chainermn_tpu.ops.flash_attention` — the ops package
-    # re-exports the flash_attention FUNCTION under that name, shadowing
-    # the submodule attribute
-    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    from chainermn_tpu.ops import set_kernels_interpreted
     from chainermn_tpu.parallel.sequence import (
         ring_attention,
         ring_flash_attention,
@@ -139,7 +134,7 @@ def main():
     # Force COMPILED pallas lowering during AOT tracing: default_backend()
     # is cpu here, but the target is the abstract TPU — interpret-mode
     # kernels would not produce Mosaic custom-calls to schedule.
-    fa._interpret_default = lambda: False
+    set_kernels_interpreted(False)
 
     topo = topologies.get_topology_desc("v5e:2x2", "tpu")
     mesh = Mesh(np.array(topo.devices).reshape(4), ("sp",))

@@ -190,7 +190,7 @@ def _load_record(path: str) -> dict:
         else raw
 
 
-def check_repo(repo: str) -> tuple[bool, str]:
+def check_trajectory(repo: str) -> tuple[bool, str]:
     """The lint-hook pass: committed trajectory must match a rebuild
     from the committed rounds, and the newest successful round must sit
     inside the bands derived from the rounds BEFORE it."""
@@ -281,7 +281,7 @@ def main(argv=None) -> int:
         print(json.dumps({"bench_compare": verdict}))
         return 0 if verdict["ok"] else 1
     if args.check:
-        ok, msg = check_repo(args.repo)
+        ok, msg = check_trajectory(args.repo)
         print(f"bench_compare --check: {msg}")
         return 0 if ok else 1
     return 0

@@ -8,8 +8,8 @@ the onchip_flash timing shapes so the default can be set from data rather
 than guessed: fwd+bwd ms/step and achieved TFLOP/s per cell, flash-vs-full
 ratio recomputed at the winning block size.
 
-Appends one JSON record per cell to scripts/flash_tune.jsonl as it lands
-(wedge protocol). Exits 0 with a "skipped" record if no TPU is attached.
+Appends one JSON record per cell to scripts/flash_tune.jsonl as it lands.
+Exits non-zero if no TPU is attached.
 """
 
 import functools
@@ -23,7 +23,6 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_HERE))
 OUT = os.path.join(_HERE, "flash_tune.jsonl")
 
-from bench import enable_compilation_cache
 from onchip_flash import time_grad_step  # the one shared timing idiom
 
 
@@ -40,17 +39,16 @@ def main():
 
     import jax
 
-    plat = os.environ.get("CHAINERMN_TPU_BENCH_PLATFORM")
-    if plat:
-        jax.config.update("jax_platforms", plat)
-    enable_compilation_cache(jax)
+    from chainermn_tpu.utils import enable_compilation_cache
+
+    enable_compilation_cache()
 
     import jax.numpy as jnp
 
     devs = jax.devices()
     if devs[0].platform != "tpu":
-        emit({"test": "platform", "skipped": f"no TPU ({devs[0].platform})"})
-        return
+        raise SystemExit(f"flash_tune times the compiled kernels on the TPU; "
+                         f"JAX found {devs[0].platform!r}. Nothing was run.")
     emit({"test": "platform", "device_kind": devs[0].device_kind})
 
     from chainermn_tpu.ops.flash_attention import flash_attention

@@ -6,14 +6,11 @@ Three worlds are pinned here:
 - **same mesh** — restore is bit-exact (the plain path);
 - **flat-DP world resize** (8 ranks -> 4 ranks) — the multi-node
   optimizer re-wrap via :func:`restore_train_state`; the wrapper pmeans
-  grads explicitly, so 10-step loss parity is exact in every JAX
-  version;
+  grads explicitly, so 10-step loss parity is exact;
 - **(d=8, m=1) -> (d=4, m=2) dp x tp** — the TP-degree change routes
   through the qkv column permutation. The permutation + re-slice are
-  grad-free and assert exactly everywhere; the 10-step loss-parity run
-  additionally needs vma-tracking shard_map for the TP global-objective
-  gradients (legacy JAX runs check_rep=False with no automatic backward
-  replication assembly — same guard as tests/parallel_tests).
+  grad-free and assert exactly; the 10-step loss-parity run rides the TP
+  global-objective gradients.
 """
 
 import os
@@ -34,13 +31,6 @@ from chainermn_tpu.deploy import (
 from chainermn_tpu.extensions.sharded_checkpoint import ShardedCheckpointer
 from chainermn_tpu.models import TransformerLM
 from chainermn_tpu.training import jit_lm_train_step
-
-_requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma-tracking shard_map: legacy JAX runs check_rep=False "
-    "with no automatic backward replication assembly for the TP "
-    "global-objective gradients",
-)
 
 VOCAB, DMODEL, HEADS, LAYERS = 64, 32, 4, 2
 TOKENS = jax.random.randint(jax.random.PRNGKey(0), (8, 12), 0, VOCAB)
@@ -212,7 +202,6 @@ def test_tp_degree_change_permutes_and_matches_forward(tmp_path):
     assert losses[-1] < losses[0], losses
 
 
-@_requires_vma
 def test_tp_degree_change_loss_parity_over_10_steps(tmp_path):
     """The full dp x tp acceptance (vma JAX only — see module docstring):
     train 3 steps on (8,1), snapshot, and the (4,2) restore's next 10

@@ -15,14 +15,6 @@ from chainermn_tpu import create_communicator
 from chainermn_tpu import functions as F
 
 
-_requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma-tracking shard_map: legacy JAX runs check_rep=False "
-    "(mesh_communicator._shard_map) with no automatic backward "
-    "replication assembly",
-)
-
-
 @pytest.fixture(scope="module")
 def comm():
     return create_communicator("naive")
@@ -89,7 +81,6 @@ def test_bcast_backward_sums_at_root(comm, root):
     np.testing.assert_allclose(g[mask], 0.0)
 
 
-@_requires_vma
 def test_scatter_gather_roundtrip_and_grad(comm):
     n = comm.size
 
@@ -131,7 +122,6 @@ def test_allreduce_function_grad(comm):
     np.testing.assert_allclose(np.asarray(g), np.full((n, 2), float(n)), rtol=1e-6)
 
 
-@_requires_vma
 def test_finite_difference_through_collectives(comm):
     """End-to-end numerical check: composite program mixing compute and
     communication, jax.grad vs central differences."""

@@ -15,14 +15,6 @@ from chainermn_tpu import create_communicator
 from chainermn_tpu import functions as F
 
 
-_requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma-tracking shard_map: legacy JAX runs check_rep=False "
-    "(mesh_communicator._shard_map) with no automatic backward "
-    "replication assembly",
-)
-
-
 @pytest.fixture(scope="module")
 def comm():
     return create_communicator("naive")
@@ -99,7 +91,6 @@ def test_recv_requires_delegate(comm):
             F.recv(comm, rank=0)
 
 
-@_requires_vma
 def test_pseudo_connect_preserves_value_and_gradient(comm):
     n = comm.size
 

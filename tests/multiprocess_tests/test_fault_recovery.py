@@ -12,8 +12,6 @@ abrupt process death and a cross-launch resume."""
 
 import os
 
-import jax
-import pytest
 
 from .test_multiprocess import _launch_world
 
@@ -21,20 +19,11 @@ _WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "worker_resume.py")
 
 
-_requires_cpu_multiprocess = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="legacy jaxlib: 'Multiprocess computations aren't implemented "
-    "on the CPU backend' — the emulated multi-controller harness needs a "
-    "newer runtime",
-)
-
-
 def _launch(phase: str, tmpdir: str, size: int = 2, timeout: float = 240.0):
     return _launch_world(size, tmpdir, timeout=timeout, worker=_WORKER,
                          extra_env={"MP_TEST_PHASE": phase})
 
 
-@_requires_cpu_multiprocess
 def test_crash_then_resume(tmp_path):
     tmpdir = str(tmp_path)
 

@@ -11,8 +11,6 @@ import socket
 import subprocess
 import sys
 
-import jax
-import pytest
 
 import chainermn_tpu
 
@@ -20,14 +18,6 @@ _WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "worker_traced.py")
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-
-_requires_cpu_multiprocess = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="legacy jaxlib: 'Multiprocess computations aren't implemented "
-    "on the CPU backend' — the emulated multi-controller harness needs a "
-    "newer runtime",
-)
 
 
 def _free_port() -> int:
@@ -39,7 +29,6 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-@_requires_cpu_multiprocess
 def test_multicontroller_traced_training(tmp_path):
     from tests.multiprocess_tests import worker_traced
 

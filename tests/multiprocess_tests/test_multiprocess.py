@@ -20,14 +20,6 @@ _WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "worker.py")
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-_requires_cpu_multiprocess = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="legacy jaxlib: 'Multiprocess computations aren't implemented "
-    "on the CPU backend' — the emulated multi-controller harness needs a "
-    "newer runtime",
-)
-
-
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -89,7 +81,6 @@ def _launch_world(size: int, tmpdir: str, timeout: float = 240.0,
 
 
 @pytest.mark.parametrize("size", [2, 4])
-@_requires_cpu_multiprocess
 def test_multiprocess_suite(size, tmp_path):
     procs, outs = _launch_world(size, str(tmp_path))
     for r, (p, out) in enumerate(zip(procs, outs)):
@@ -99,7 +90,6 @@ def test_multiprocess_suite(size, tmp_path):
         assert f"WORKER_OK {r}" in out, f"rank {r} did not finish:\n{out[-4000:]}"
 
 
-@_requires_cpu_multiprocess
 def test_multiprocess_suite_native_transport(tmp_path):
     """The FULL worker scenario suite again, but over the C++ objstore
     sidecar instead of the KV store — NativeObjectComm under a real

@@ -13,14 +13,6 @@ from chainermn_tpu.models import MLP
 from chainermn_tpu.training import jit_train_step
 
 
-_requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma-tracking shard_map: legacy JAX runs check_rep=False "
-    "(mesh_communicator._shard_map) with no automatic backward "
-    "replication assembly",
-)
-
-
 @pytest.fixture(scope="module")
 def comm():
     return chainermn_tpu.create_communicator("tpu")
@@ -68,7 +60,6 @@ def test_zero_matches_unsharded(comm, inner):
                                    rtol=2e-5, atol=2e-6)
 
 
-@_requires_vma
 def test_zero_sharded_clip_matches_replicated_clip(comm):
     """clip_by_global_norm_sharded inside the ZeRO inner chain must clip by
     the TRUE global norm: same trajectory as replicated optax.chain(
@@ -228,7 +219,6 @@ def test_zero_learns(comm):
     assert losses[-1] < losses[0], losses
 
 
-@_requires_vma
 def test_sharded_clip_replicated_grads_exact(comm):
     """ADVICE r3: composed against REPLICATED gradients inside a traced
     step, the sharded clip must not sum n identical replicas into a
@@ -253,7 +243,6 @@ def test_sharded_clip_replicated_grads_exact(comm):
                                    rtol=1e-6)
 
 
-@_requires_vma
 def test_sharded_clip_replicated_grads_split_comm(comm):
     """Same invariant-leaf correction on a split() sub-communicator: the
     reduce covers the GROUP, so the replica divisor must be the group size

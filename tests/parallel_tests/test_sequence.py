@@ -148,11 +148,6 @@ def _rf_sharded(comm, *, causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_matches_full_attention(comm, causal):
-    if not causal and not hasattr(jax, "typeof"):
-        pytest.skip(
-            "legacy jaxlib SPMD rejects the non-causal interpret-mode "
-            "kernel ('PartitionId instruction is not supported for SPMD "
-            "partitioning'); runs on vma-tracking JAX / real TPU")
     q, k, v = _qkv(t=64)
     want = full_attention(q, k, v, causal=causal)
     got = _rf_sharded(comm, causal=causal)(q, k, v)

@@ -22,14 +22,6 @@ from chainermn_tpu.parallel.tensor import global_objective
 from chainermn_tpu.parallel.sequence import full_attention
 
 
-_requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma-tracking shard_map: legacy JAX runs check_rep=False "
-    "(mesh_communicator._shard_map) with no automatic backward "
-    "replication assembly",
-)
-
-
 @pytest.fixture(scope="module")
 def comm():
     return chainermn_tpu.create_communicator("tpu")
@@ -92,7 +84,6 @@ def test_attention_matches_serial(comm):
                                rtol=1e-4, atol=1e-5)
 
 
-@_requires_vma
 def test_tp_grad_matches_serial(comm):
     """The global-objective pattern (tensor.py docstring) must reassemble the
     exact serial gradient for EVERY leaf: invariant params + pmean'd loss
@@ -168,7 +159,6 @@ def test_tp_transformer_lm_trains(comm):
     assert losses[-1] < losses[0], losses
 
 
-@_requires_vma
 def test_vocab_parallel_cross_entropy_matches_optax(comm):
     """Sharded-vocab CE must equal optax CE on the gathered logits, value
     AND gradient, for targets landing in every shard (incl. edges)."""
@@ -346,7 +336,6 @@ def test_3d_dp_sp_tp_lm_trains(comm):
     assert losses[-1] < losses[0], losses
 
 
-@_requires_vma
 def test_global_objective_rejects_vma_off(comm):
     """Under check_vma=False no pmean would ever fire and the pattern's
     grads would be silently wrong — it must raise instead."""

@@ -88,8 +88,14 @@ def test_mid_flight_admission_and_slot_reuse(lm_and_params):
 def test_zero_recompiles_after_warmup(lm_and_params):
     """Acceptance criterion: the engine owns exactly TWO executables —
     one prefill, one decode — and a second wave of requests with
-    different ragged lengths/budgets adds none (jit cache-size count)."""
+    different ragged lengths/budgets adds none (jit cache-size count).
+    Parameters committed to devices are the harder case: the engine's own
+    state must follow them there, or each program's first outputs change
+    its jit cache key. Here they come as a trainer's ``bcast_data`` leaves
+    them, replicated over the communicator's mesh; ``tests/
+    test_chip_smoke.py`` serves from a plain ``device_put``."""
     lm, params = lm_and_params
+    params = chainermn_tpu.create_communicator("tpu").bcast_data(params)
     engine = ServingEngine(lm, params, n_slots=2, prefill_len=8,
                            cache_len=32)
     sched = FCFSScheduler(engine)

@@ -108,31 +108,31 @@ def test_compare_verdicts_regression_improvement_and_new(tmp_path):
 # --------------------------------------------------------------------- #
 
 
-def test_check_repo_staleness_and_banding(tmp_path):
+def test_check_trajectory_staleness_and_banding(tmp_path):
     repo = str(tmp_path)
     # no trajectory at all
-    ok, msg = bc.check_repo(repo)
+    ok, msg = bc.check_trajectory(repo)
     assert not ok and "missing" in msg
     # one successful round: consistent but nothing to band against
     _write_rounds(tmp_path, [_rec(100.0), None])
     assert bc.main(["--repo", repo, "--build"]) == 0
-    ok, msg = bc.check_repo(repo)
+    ok, msg = bc.check_trajectory(repo)
     assert ok and "nothing to band against" in msg
     # second success inside tolerance: banded and green
     (tmp_path / "BENCH_r03.json").write_text(
         json.dumps(_round(3, parsed=_rec(110.0))))
     assert bc.main(["--repo", repo, "--build"]) == 0
-    ok, msg = bc.check_repo(repo)
+    ok, msg = bc.check_trajectory(repo)
     assert ok and "inside tolerance" in msg
     # a regressed newest round fails the hook
     (tmp_path / "BENCH_r04.json").write_text(
         json.dumps(_round(4, parsed=_rec(10.0))))
     assert bc.main(["--repo", repo, "--build"]) == 0
-    ok, msg = bc.check_repo(repo)
+    ok, msg = bc.check_trajectory(repo)
     assert not ok and "regressed" in msg
     # stale trajectory (rounds changed after --build) fails loudly
     os.remove(tmp_path / "BENCH_r04.json")
-    ok, msg = bc.check_repo(repo)
+    ok, msg = bc.check_trajectory(repo)
     assert not ok and "stale" in msg
 
 
@@ -157,5 +157,5 @@ def test_committed_trajectory_is_current():
     runs — if this fails, re-run bench_compare.py --build and commit."""
     if not (REPO / bc.TRAJECTORY).exists():
         pytest.skip("no committed trajectory yet")
-    ok, msg = bc.check_repo(str(REPO))
+    ok, msg = bc.check_trajectory(str(REPO))
     assert ok, msg

@@ -1,5 +1,5 @@
-"""bench.py harness smoke test: the retry parent + headline + strategy/db
-sweep must produce one parseable JSON record (tiny model, CPU, 8 devices).
+"""bench.py harness smoke test: the headline + strategy/db sweep must
+produce one parseable JSON record (tiny model, CPU, 8 devices).
 
 The real benchmark runs on the driver's TPU; this pins the harness logic —
 JSON shape, sweep table, bandwidth fields — so a bench-side regression is
@@ -24,7 +24,6 @@ def test_bench_smoke_tiny_cpu():
         CHAINERMN_TPU_BENCH_BATCH="16",
         CHAINERMN_TPU_BENCH_STEPS="2",
         CHAINERMN_TPU_BENCH_SWEEP_STEPS="2",
-        CHAINERMN_TPU_BENCH_ATTEMPTS="1",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     proc = subprocess.run(
@@ -528,127 +527,3 @@ def test_bench_pipeline_mode_smoke():
     snap = rec["monitor"]
     assert any(k.startswith("prefetch_batches_total")
                for k in snap["counters"])
-
-
-def test_persist_measured_is_tpu_only(tmp_path, monkeypatch):
-    """The evidence file must only ever hold real-chip records: a tiny-CPU
-    smoke run (this very suite) once displaced the round's TPU measurement.
-    Also pins _failure_record's embed chain: primary file, then reverse
-    bench_stdout scan skipping value=null lines."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    lm = tmp_path / "last_measured.json"
-    monkeypatch.setattr(bench, "_LAST_MEASURED_PATH", str(lm))
-
-    tpu_rec = {"metric": "m", "value": 2561.0, "device_kind": "TPU v5 lite"}
-    bench._persist_measured(json.dumps(tpu_rec))
-    assert json.loads(lm.read_text())["value"] == 2561.0
-
-    # a CPU record must NOT displace it
-    bench._persist_measured(json.dumps(
-        {"metric": "m", "value": 102.0, "device_kind": "cpu", "tiny": True}))
-    assert json.loads(lm.read_text())["value"] == 2561.0
-
-    # failure record embeds the persisted evidence
-    rec = bench._failure_record("TimeoutExpired", "tail", 2)
-    assert rec["value"] is None
-    assert rec["last_measured"]["value"] == 2561.0
-
-    # fallback: no primary file -> reverse-scan bench_stdout.txt past a
-    # trailing failure line
-    lm.unlink()
-    stdout_file = tmp_path / "bench_stdout.txt"
-    stdout_file.write_text(
-        json.dumps({"metric": "m", "value": 2442.0,
-                    "device_kind": "TPU v5 lite"}) + "\n"
-        + json.dumps({"metric": "m", "value": 102.0,
-                      "device_kind": "cpu", "tiny": True}) + "\n"
-        + json.dumps({"metric": "m", "value": None, "error": "x"}) + "\n")
-    rec = bench._failure_record("TimeoutExpired", "tail", 2)
-    # the scan must skip BOTH the trailing failure line and the newer
-    # CPU record (same TPU-only invariant as the primary file)
-    assert rec["last_measured"]["value"] == 2442.0
-
-
-def test_budget_plan_cold_vs_warm(tmp_path):
-    """Parent budget shape (round-5): pinned envs win verbatim; a cold
-    persistent cache turns the 5x720 ladder into one long attempt inside
-    the same total budget (a cold conv7/256 compile is ~11-12 min — longer
-    than a 720s attempt, the round-4 double-TERM); the child's
-    headline_<stem>_<per-chip-batch>.ok marker flips it back to warm."""
-    sys.path.insert(0, REPO)
-    from bench import _budget_plan
-
-    cache = str(tmp_path / "cache")
-    os.makedirs(cache)
-    base = {"CHAINERMN_TPU_BENCH_CACHE": cache}
-
-    # pinned envs are respected exactly, warm or cold
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_ATTEMPTS": "3",
-                         "CHAINERMN_TPU_BENCH_TIMEOUT": "600"})
-    assert (a, t) == (3, 600.0)
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_TIMEOUT": "2400"})
-    assert (a, t) == (5, 2400.0)
-
-    # cold: one long attempt, total budget minus margin
-    a, t = _budget_plan(base)
-    assert (a, t) == (1, 1380.0)
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_TOTAL_BUDGET": "2500"})
-    assert (a, t) == (1, 2380.0)
-
-    # warm marker for the 256 headline rung restores the retry ladder
-    open(os.path.join(cache, "headline_conv7_256.ok"), "w").write("27\n")
-    a, t = _budget_plan(base)
-    assert (a, t) == (5, 720.0)
-
-    # an explicitly keyed batch checks ITS marker, not 256's
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_BATCH": "512"})
-    assert (a, t) == (1, 1380.0)
-    open(os.path.join(cache, "headline_conv7_512.ok"), "w").write("30\n")
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_BATCH": "512"})
-    assert (a, t) == (5, 720.0)
-
-    # a different stem is a different program: cold again
-    a, t = _budget_plan({**base, "CHAINERMN_TPU_BENCH_STEM": "space_to_depth"})
-    assert (a, t) == (1, 1380.0)
-
-
-def test_warm_marker_guards(tmp_path, monkeypatch):
-    """The warm marker must never be written by tiny or non-TPU runs (a
-    CPU smoke poisoning warm detection recreates the round-4 double-TERM)
-    and must key the way _budget_plan looks it up: raw env value for an
-    explicit batch, per-chip rung otherwise."""
-    sys.path.insert(0, REPO)
-    from bench import _write_warm_marker
-
-    cache = str(tmp_path / "cache")
-    os.makedirs(cache)
-    monkeypatch.setenv("CHAINERMN_TPU_BENCH_CACHE", cache)
-    stamp = str(tmp_path / "cache" / "x-cache")
-    open(stamp, "w").write("entry")  # fresh persisted entry
-
-    import time as _t
-    now = _t.time()
-    # tiny and cpu runs: no marker, even with a fresh cache entry
-    _write_warm_marker("conv7", 256, 0, 1, True, "tpu", 5.0, now - 60)
-    _write_warm_marker("conv7", 256, 0, 1, False, "cpu", 5.0, now - 60)
-    assert not [f for f in os.listdir(cache) if f.startswith("headline")]
-
-    # real run, default ladder rung on 4 chips: per-chip key
-    _write_warm_marker("conv7", 1024, 0, 4, False, "tpu", 700.0, now - 60)
-    assert os.path.exists(os.path.join(cache, "headline_conv7_256.ok"))
-
-    # explicit batch: env-value key, regardless of chip count
-    _write_warm_marker("conv7", 512, 512, 4, False, "tpu", 700.0, now - 60)
-    assert os.path.exists(os.path.join(cache, "headline_conv7_512.ok"))
-
-    # long compile with NO fresh cache entry: serialization was skipped,
-    # the next run is still cold -> no marker
-    os.unlink(stamp)
-    _write_warm_marker("s2d", 256, 0, 1, False, "tpu", 700.0, _t.time())
-    assert not os.path.exists(os.path.join(cache, "headline_s2d_256.ok"))
-
-    # ...but a warm hit (<10s) needs no new entry
-    _write_warm_marker("s2d", 256, 0, 1, False, "tpu", 3.0, _t.time())
-    assert os.path.exists(os.path.join(cache, "headline_s2d_256.ok"))
