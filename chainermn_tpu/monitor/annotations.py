@@ -8,6 +8,11 @@ Entering them is cheap when no profiler is attached, so the annotations
 stay on permanently in the hot paths (train step bodies, serving
 prefill/decode, communicator collectives).
 
+``annotate(name, **stats)`` hands keyword statistics to the TraceMe: the
+event keeps its plain name and the numbers show as its arguments in
+XProf/Perfetto (counts where the work happens: live slots of a decode
+step, rows of a prefill program).
+
 Scope names deliberately avoid XLA collective opcode spellings
 (``all-reduce`` etc.): names land in HLO ``op_name`` metadata, and
 :func:`~chainermn_tpu.extensions.profiling.parse_hlo_collectives` scans raw
@@ -19,10 +24,11 @@ from __future__ import annotations
 class _Annotation:
     """Re-entrant-constructible, single-use context manager pair."""
 
-    __slots__ = ("_name", "_tm", "_ns")
+    __slots__ = ("_name", "_stats", "_tm", "_ns")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, stats: dict) -> None:
         self._name = name
+        self._stats = stats
         self._tm = None
         self._ns = None
 
@@ -33,7 +39,7 @@ class _Annotation:
         # by whatever produced the work being annotated
         import jax
 
-        self._tm = jax.profiler.TraceAnnotation(self._name)
+        self._tm = jax.profiler.TraceAnnotation(self._name, **self._stats)
         self._tm.__enter__()
         self._ns = jax.named_scope(self._name)
         self._ns.__enter__()
@@ -52,7 +58,7 @@ class _Annotation:
                 self._tm = None
 
 
-def annotate(name: str) -> _Annotation:
+def annotate(name: str, **stats) -> _Annotation:
     """Name a region for profiling::
 
         with monitor.annotate("chainermn.decode"):
@@ -60,9 +66,9 @@ def annotate(name: str) -> _Annotation:
 
     Inside a trace the enclosed ops get ``name`` in their HLO metadata
     (named_scope); around a host call the region appears on the host
-    timeline (TraceAnnotation).
+    timeline (TraceAnnotation), with ``stats`` as the event's arguments.
     """
-    return _Annotation(str(name))
+    return _Annotation(str(name), stats)
 
 
 __all__ = ["annotate"]

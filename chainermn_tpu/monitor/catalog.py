@@ -49,6 +49,7 @@ METRIC_NAMES = frozenset({
     "kv_block_appends_total",
     "kv_blocks_free",
     "kv_blocks_in_use",
+    "kv_blocks_live",
     "kv_blocks_per_request",
     "kv_preemptions_total",
     # chunked prefill + KV migration (disaggregated prefill/decode tiers)

@@ -471,6 +471,11 @@ class ServingEngine:
             self._tables = np.zeros((self.n_slots, self._n_max), np.int32)
             self._slot_blocks: list[list[int]] = [
                 [] for _ in range(self.n_slots)]
+            # slots holding each block, and how many blocks have a holder:
+            # the ``blocks_live`` gauge, kept where a slot gains and drops
+            # blocks so that reading it walks nothing
+            self._block_holders = [0] * self.kv_blocks
+            self._blocks_live = 0
             # worst-case growth blocks each active slot may still append
             # (admission reserves them; append_block draws them down) —
             # what makes block-budget admission preemption-free in the
@@ -1385,7 +1390,8 @@ class ServingEngine:
         try:
             try:
                 with self._watched("serving prefill", **(ctx or {})), \
-                        annotate("chainermn.serving_prefill"):
+                        annotate("chainermn.serving_prefill",
+                                 rows=len(plans), of=k, bucket=bucket):
                     if n_cached:
                         inject(SERVING_PREFIX_COPY, op="fetch",
                                hits=n_cached, batch=len(plans))
@@ -1506,7 +1512,8 @@ class ServingEngine:
         try:
             try:
                 with self._watched("serving prefill", **(ctx or {})), \
-                        annotate("chainermn.serving_prefill"):
+                        annotate("chainermn.serving_prefill",
+                                 rows=len(plans), of=k, bucket=bucket):
                     if n_cached:
                         inject(SERVING_PREFIX_COPY, op="share",
                                hits=n_cached, batch=len(plans))
@@ -1558,7 +1565,7 @@ class ServingEngine:
             self._pos[slot] = len(plan.prompt)
             self._active[slot] = True
             self._keys = self._keys.at[slot].set(keys_out[len(out)])
-            self._slot_blocks[slot] = list(ids)
+            self._slot_takes(slot, ids)
             self._c_prefills[bucket].inc()
             self._events.emit("prefill", slot=slot,
                               prompt_len=len(plan.prompt), bucket=bucket,
@@ -1646,7 +1653,7 @@ class ServingEngine:
         self._slot_reserved[slot] = (
             -(-(plen + plan.max_new) // bs) - (-(-plen // bs))
             + self._spec_headroom)
-        self._slot_blocks[slot] = list(ids)
+        self._slot_takes(slot, ids)
         self.free_slots.discard(slot)
         self._chunking[slot] = ChunkedPrefill(
             prompt=plan.prompt, rng=plan.rng, start=plan.start,
@@ -1942,7 +1949,7 @@ class ServingEngine:
         self.free_slots.discard(slot)
         self._tables[slot, :] = 0
         self._tables[slot, :n] = new
-        self._slot_blocks[slot] = list(new)
+        self._slot_takes(slot, new)
         self._slot_reserved[slot] = (
             max(0, -(-(pos + int(max_new)) // bs) - n)
             + self._spec_headroom)
@@ -2144,13 +2151,34 @@ class ServingEngine:
             return False
         block = got[0]
         self._tables[slot, idx] = block
-        self._slot_blocks[slot].append(block)
+        self._slot_takes(slot, [block])
         if self._slot_reserved[slot] > 0:
             self._slot_reserved[slot] -= 1
         self._c_appends.inc()
         self._events.emit("kv_append", slot=slot, block=block,
                           pos=int(self._pos[slot]))
         return True
+
+    def _slot_takes(self, slot: int, blocks) -> None:
+        """The slot's table gains ``blocks`` (admission, import, append)."""
+        self._slot_blocks[slot].extend(blocks)
+        for block in blocks:
+            if self._block_holders[block] == 0:
+                self._blocks_live += 1
+            self._block_holders[block] += 1
+
+    def _slot_drops(self, slot: int, blocks=None) -> None:
+        """The slot's table gives ``blocks`` up (rollback), or all it
+        holds (release)."""
+        if blocks is None:
+            blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
+        else:
+            for block in blocks:
+                self._slot_blocks[slot].remove(block)
+        for block in blocks:
+            self._block_holders[block] -= 1
+            if self._block_holders[block] == 0:
+                self._blocks_live -= 1
 
     def slot_block_count(self, slot: int) -> int:
         """Blocks the slot's table currently references (0 in dense
@@ -2168,10 +2196,16 @@ class ServingEngine:
         return sum(1.0 / max(self._pool.refs(b), 1)
                    for b in self._slot_blocks[slot])
 
-    def kv_pool_stats(self) -> tuple[int, int]:
-        """(blocks in use, blocks free) — the scheduler samples these
-        into the ``kv_blocks_in_use``/``kv_blocks_free`` gauges."""
-        return self._pool.used_blocks, self._pool.free_blocks
+    def kv_pool_stats(self) -> tuple[int, int, int]:
+        """(blocks in use, blocks free, blocks live) — the scheduler
+        samples these into the ``kv_blocks_in_use``/``kv_blocks_free``/
+        ``kv_blocks_live`` gauges. In use = every block off the free list,
+        whoever holds it: live slots AND finished prompts the prefix trie
+        keeps until evicted (so it reads near the pool's size on a busy
+        server whatever is live). Live = blocks some slot's table
+        references, a running count."""
+        return (self._pool.used_blocks, self._pool.free_blocks,
+                self._blocks_live)
 
     def kv_stats(self) -> dict:
         """Paged-store occupancy/config block for bench records (empty
@@ -2182,7 +2216,10 @@ class ServingEngine:
             "kv_blocks": self.kv_blocks,
             "kv_block_size": self.kv_block_size,
             "kv_quant": self.kv_quant,
+            # off the free list, trie-held (evictable) prompts included
             "blocks_in_use": self._pool.used_blocks,
+            # referenced by some live slot's table
+            "blocks_live": self._blocks_live,
             "blocks_free": self._pool.free_blocks,
             "blocks_reserved": int(self._slot_reserved.sum()),
             "peak_active": self.peak_active,
@@ -2275,6 +2312,13 @@ class ServingEngine:
                 jnp.asarray(self._pos), jnp.asarray(self._active),
                 self._keys)
 
+    def _decode_stats(self) -> dict:
+        """Counts a decode span carries into the profiler's trace: slots
+        the step advances and the tokens their contexts hold, both from
+        the host-side slot mirror."""
+        return {"active": int(self._active.sum()),
+                "live_tokens": int(self._pos[self._active].sum())}
+
     def _set_kv_state(self, state) -> None:
         if self.paged:
             self._store = state
@@ -2292,21 +2336,25 @@ class ServingEngine:
         # a wedged collective hangs exactly there, and that is the hang
         # the serving watchdog exists to turn into a loud abort
         with self._watched("serving decode_step", **(ctx or {})), \
-                annotate("chainermn.serving_decode"):
+                annotate("chainermn.serving_decode", **self._decode_stats()):
             inject(SERVING_DECODE, active=int(self._active.sum()))
-            state, nxt, self._keys = self._decode_fn(*self._decode_args())
+            with annotate("chainermn.serving_decode_args"):
+                args = self._decode_args()
+            state, nxt, self._keys = self._decode_fn(*args)
             self._set_kv_state(state)
-            nxt = device_fetch(nxt)
-        self._c_decode_steps.inc()
-        self._events.emit("decode_step", active=int(self._active.sum()))
-        self._guard.check()
-        out = {}
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            tok = int(nxt[slot])
-            self._token[slot] = tok
-            self._pos[slot] += 1
-            out[slot] = tok
+            with annotate("chainermn.serving_decode_fetch"):
+                nxt = device_fetch(nxt)
+        with annotate("chainermn.serving_decode_post"):
+            self._c_decode_steps.inc()
+            self._events.emit("decode_step", active=int(self._active.sum()))
+            self._guard.check()
+            out = {}
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                tok = int(nxt[slot])
+                self._token[slot] = tok
+                self._pos[slot] += 1
+                out[slot] = tok
         return out
 
     def decode_steps(self, ctx: Optional[dict] = None
@@ -2324,22 +2372,26 @@ class ServingEngine:
             return {}
         n = self.decode_window
         with self._watched("serving decode_steps", **(ctx or {})), \
-                annotate("chainermn.serving_decode"):
+                annotate("chainermn.serving_decode", **self._decode_stats()):
             inject(SERVING_DECODE, active=int(self._active.sum()), window=n)
-            state, out, self._keys = self._window_fn(*self._decode_args())
+            with annotate("chainermn.serving_decode_args"):
+                args = self._decode_args()
+            state, out, self._keys = self._window_fn(*args)
             self._set_kv_state(state)
-            out = device_fetch(out)
-        self._c_decode_steps.inc()
-        self._events.emit("decode_step", active=int(self._active.sum()),
-                          window=n)
-        self._guard.check()
-        res = {}
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            toks = [int(t) for t in out[slot]]
-            self._token[slot] = toks[-1]
-            self._pos[slot] += n
-            res[slot] = toks
+            with annotate("chainermn.serving_decode_fetch"):
+                out = device_fetch(out)
+        with annotate("chainermn.serving_decode_post"):
+            self._c_decode_steps.inc()
+            self._events.emit("decode_step", active=int(self._active.sum()),
+                              window=n)
+            self._guard.check()
+            res = {}
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                toks = [int(t) for t in out[slot]]
+                self._token[slot] = toks[-1]
+                self._pos[slot] += n
+                res[slot] = toks
         return res
 
     def spec_decode_step(self, ctx: Optional[dict] = None
@@ -2368,39 +2420,43 @@ class ServingEngine:
         with self._watched("serving spec_verify", **(ctx or {})), \
                 annotate("chainermn.serving_spec_verify"):
             inject(SERVING_SPEC_VERIFY, active=int(self._active.sum()), k=k)
-            self._store, g = self._spec_fn(
-                self.params, self._store, jnp.asarray(self._tables),
-                jnp.asarray(tokens), jnp.asarray(self._pos),
-                jnp.asarray(valid), jnp.asarray(self._active))
-            g = device_fetch(g)
-        self._c_decode_steps.inc()
-        self._events.emit("decode_step", active=int(self._active.sum()),
-                          window=k + 1)
-        self._guard.check()
-        res = {}
-        proposed = accepted = 0
-        lengths = []
-        spec_slots = {}
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            kd = min(k, int(valid[slot]) - 1)   # drafts that fit the slot
-            a = 0
-            while a < kd and int(drafts[slot, a]) == int(g[slot, a]):
-                a += 1
-            toks = [int(t) for t in drafts[slot, :a]] + [int(g[slot, a])]
-            self._token[slot] = toks[-1]
-            self._pos[slot] += len(toks)
-            self._drafter.on_commit(slot, toks)
-            self._rollback_spec_blocks(slot)
-            proposed += kd
-            accepted += a
-            lengths.append(a)
-            spec_slots[slot] = (kd, a)
-            res[slot] = toks
-        self._spec_proposed_total += proposed
-        self._spec_accepted_total += accepted
-        self._last_spec_window = (proposed, accepted, lengths)
-        self._last_spec_slots = spec_slots
+            with annotate("chainermn.serving_decode_args"):
+                args = (jnp.asarray(self._tables), jnp.asarray(tokens),
+                        jnp.asarray(self._pos), jnp.asarray(valid),
+                        jnp.asarray(self._active))
+            self._store, g = self._spec_fn(self.params, self._store, *args)
+            with annotate("chainermn.serving_decode_fetch"):
+                g = device_fetch(g)
+        with annotate("chainermn.serving_decode_post"):
+            self._c_decode_steps.inc()
+            self._events.emit("decode_step", active=int(self._active.sum()),
+                              window=k + 1)
+            self._guard.check()
+            res = {}
+            proposed = accepted = 0
+            lengths = []
+            spec_slots = {}
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                kd = min(k, int(valid[slot]) - 1)   # drafts that fit the slot
+                a = 0
+                while a < kd and int(drafts[slot, a]) == int(g[slot, a]):
+                    a += 1
+                toks = ([int(t) for t in drafts[slot, :a]]
+                        + [int(g[slot, a])])
+                self._token[slot] = toks[-1]
+                self._pos[slot] += len(toks)
+                self._drafter.on_commit(slot, toks)
+                self._rollback_spec_blocks(slot)
+                proposed += kd
+                accepted += a
+                lengths.append(a)
+                spec_slots[slot] = (kd, a)
+                res[slot] = toks
+            self._spec_proposed_total += proposed
+            self._spec_accepted_total += accepted
+            self._last_spec_window = (proposed, accepted, lengths)
+            self._last_spec_slots = spec_slots
         return res
 
     def _rollback_spec_blocks(self, slot: int) -> None:
@@ -2418,7 +2474,7 @@ class ServingEngine:
             if block == 0:
                 continue
             self._pool.decref(block)
-            self._slot_blocks[slot].remove(block)
+            self._slot_drops(slot, [block])
             self._tables[slot, idx] = 0
             self._slot_reserved[slot] += 1
             freed += 1
@@ -2492,7 +2548,7 @@ class ServingEngine:
             # the next hit (the store, not the slot, owns cached prefixes)
             for block in self._slot_blocks[slot]:
                 self._pool.decref(block)
-            self._slot_blocks[slot] = []
+            self._slot_drops(slot)
             self._slot_reserved[slot] = 0
             self._tables[slot, :] = 0
             # a half-prefilled chunked slot releases the same way: its
@@ -2536,6 +2592,8 @@ class ServingEngine:
             self._pool.reset()
             self._tables[:] = 0
             self._slot_blocks = [[] for _ in range(self.n_slots)]
+            self._block_holders = [0] * self.kv_blocks
+            self._blocks_live = 0
             self._slot_reserved[:] = 0
             self._chunking.clear()
         self._pending_inserts = []

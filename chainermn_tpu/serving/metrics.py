@@ -98,6 +98,7 @@ class ServingMetrics:
         # the "memory per request" distribution dense slots can't see
         self._g_kv_used = reg.gauge("kv_blocks_in_use", labels)
         self._g_kv_free = reg.gauge("kv_blocks_free", labels)
+        self._g_kv_live = reg.gauge("kv_blocks_live", labels)
         self._c_preempt = reg.counter("kv_preemptions_total", labels)
         self._h_req_blocks = reg.histogram("kv_blocks_per_request", labels)
         # speculative decode (PR 12): per-round accept-length histogram
@@ -191,10 +192,14 @@ class ServingMetrics:
     def record_restart(self) -> None:
         self._c_restarts.inc()
 
-    def record_kv_pool(self, in_use: int, free: int) -> None:
-        """Paged-store occupancy, sampled once per scheduler step."""
+    def record_kv_pool(self, in_use: int, free: int, live: int) -> None:
+        """Paged-store occupancy, sampled once per scheduler step:
+        ``in_use`` counts every block off the free list (live slots and
+        the finished prompts the prefix trie still holds), ``live`` only
+        those a live slot's table references."""
         self._g_kv_used.set(in_use)
         self._g_kv_free.set(free)
+        self._g_kv_live.set(live)
 
     def record_preemption(self, priority: Optional[str] = None) -> None:
         """A decoding request was evicted back to the queue (block pool
@@ -413,6 +418,7 @@ class ServingMetrics:
             out["kv_preemptions"] = int(self._c_preempt.value)
             out["kv_blocks_in_use"] = int(self._g_kv_used.value)
             out["kv_blocks_free"] = int(self._g_kv_free.value)
+            out["kv_blocks_live"] = int(self._g_kv_live.value)
         spec_prop = int(self._c_spec_proposed.value)
         if spec_prop:   # speculative engines only
             spec_acc = int(self._c_spec_accepted.value)

@@ -326,6 +326,35 @@ class KvReuseTicket:
         return self.result
 
 
+#: What the profiler sees of one ``FCFSScheduler.step()``: sibling
+#: ``monitor.annotate`` spans in step order, tiling the driving thread so
+#: that every idle gap of the chip between two device programs lies under
+#: one named phase (``serving_account``, the monitoring's own cost, has two
+#: stretches). No span encloses a whole step: a trace reduction that gives
+#: a gap to the span covering most of it would then name nothing else. A
+#: speculative engine's round is ``chainermn.serving_spec_verify`` where
+#: ``serving_decode`` stands; ``ServingClient`` sleeps under
+#: ``chainermn.serving_idle``.
+STEP_PHASES = (
+    "chainermn.serving_policy",       # shed, policy tick, swap fence, imports
+    "chainermn.serving_admit",        # the admission loop
+    "chainermn.serving_blocks",       # chunk advance, block appends, snapshot
+    "chainermn.serving_decode",       # engine: operands through fetch
+    "chainermn.serving_decode_post",  # engine: counters, guard, slot mirror
+    "chainermn.serving_account",      # cost ledger: decode and block-seconds
+    "chainermn.serving_deliver",      # per token: metrics, trace, stream_cb
+    "chainermn.serving_flush",        # deferred prefix inserts
+    "chainermn.serving_account",      # step gauges, cost-ledger flush
+)
+#: The only spans that may open inside a phase, by parent.
+STEP_PHASE_CHILDREN = {
+    "chainermn.serving_admit": ("chainermn.serving_prefill",),
+    "chainermn.serving_blocks": ("chainermn.serving_chunk_prefill",),
+    "chainermn.serving_decode": ("chainermn.serving_decode_args",
+                                 "chainermn.serving_decode_fetch"),
+}
+
+
 class FCFSScheduler:
     """First-come-first-served continuous-batching scheduler.
 
@@ -649,36 +678,39 @@ class FCFSScheduler:
         idle). Shedding, then admissions — freed slots refill BEFORE the
         decode step, so a retirement's slot never sits idle for a step."""
         emitted = 0
-        self._shed_expired()
-        self._policy_tick()
-        # 0. version fence: while a swap is pending, admissions pause so
-        # every in-flight request finishes on the weights it started
-        # with; once the pool drains the swap runs HERE, between device
-        # calls, on the one thread that owns the engine
-        with self._lock:
-            swapping = self._pending_swap is not None
-            if (swapping and not self._by_slot and not self._prefilling
-                    and not self._pending_imports):
-                ticket, self._pending_swap = self._pending_swap, None
-                swapping = False
-            else:
-                ticket = None
-        if ticket is not None:
-            self._execute_swap(ticket)
+        with annotate("chainermn.serving_policy"):
+            self._shed_expired()
+            self._policy_tick()
+            # 0. version fence: while a swap is pending, admissions pause
+            # so every in-flight request finishes on the weights it
+            # started with; once the pool drains the swap runs HERE,
+            # between device calls, on the one thread that owns the engine
+            with self._lock:
+                swapping = self._pending_swap is not None
+                if (swapping and not self._by_slot and not self._prefilling
+                        and not self._pending_imports):
+                    ticket, self._pending_swap = self._pending_swap, None
+                    swapping = False
+                else:
+                    ticket = None
+            if ticket is not None:
+                self._execute_swap(ticket)
+            # Fleet KV-reuse operations (prefix share export/import,
+            # rebalance handover) run before admission: a shared prefix
+            # landed here must be trie-resident BEFORE this step's fresh
+            # admissions match.
+            # Migrated-in requests admit first: their device time is
+            # already spent elsewhere, they only need a slot + one
+            # scatter. They admit even through a swap fence — they
+            # STARTED on the current weights elsewhere, so they must
+            # finish on them here (the fence simply waits for them like
+            # any other in-flight work).
+            self._serve_kv_reuse()
+            self._admit_imports()
         # 1. admission: one group (>= 1 same-bucket requests, one device
         # call) per iteration, FCFS-anchored; bounded prefill interleave
         # in cost-aware mode so a deep queue can't stall decode.
-        # Migrated-in requests admit first: their device time is already
-        # spent elsewhere, they only need a slot + one scatter. They
-        # admit even through a swap fence — they STARTED on the current
-        # weights elsewhere, so they must finish on them here (the fence
-        # simply waits for them like any other in-flight work).
-        # Fleet KV-reuse operations (prefix share export/import,
-        # rebalance handover) run first: a shared prefix landed here must
-        # be trie-resident BEFORE this step's fresh admissions match
-        self._serve_kv_reuse()
-        self._admit_imports()
-        with annotate("chainermn.serving_admit"):
+        with annotate("chainermn.serving_admit", queue=self.queue_depth):
             calls = 0
             while not swapping and self.engine.free_slots and (
                     self._max_prefills is None or calls < self._max_prefills):
@@ -687,24 +719,29 @@ class FCFSScheduler:
                     break
                 calls += 1
                 emitted += self._admit_group(group)
-        # 1a. chunked prefill: advance the oldest PREFILLING request by
-        # exactly ONE chunk — the bounded slice of prefill work that
-        # interleaves with this step's decode. Runs through a swap fence
-        # too: a staged chunked admission already started on the current
-        # weights, so the fence waits for it rather than stranding it
-        emitted += self._advance_chunks()
-        # 1b. paged: make sure every active slot can take this step's
-        # token — lazily append blocks for slots crossing a block
-        # boundary, preempting (requeueing, not failing) the lowest-
-        # priority request when the pool runs dry
-        if getattr(self.engine, "paged", False):
-            self._ensure_decode_blocks()
+        with annotate("chainermn.serving_blocks"):
+            # 1a. chunked prefill: advance the oldest PREFILLING request
+            # by exactly ONE chunk — the bounded slice of prefill work
+            # that interleaves with this step's decode. Runs through a
+            # swap fence too: a staged chunked admission already started
+            # on the current weights, so the fence waits for it rather
+            # than stranding it
+            emitted += self._advance_chunks()
+            # 1b. paged: make sure every active slot can take this step's
+            # token — lazily append blocks for slots crossing a block
+            # boundary, preempting (requeueing, not failing) the lowest-
+            # priority request when the pool runs dry
+            if getattr(self.engine, "paged", False):
+                self._ensure_decode_blocks()
+            # GIL-atomic snapshot for cost attribution (same contract as
+            # _flight_ctx): who occupied which slot when the decode
+            # launched
+            rows_snapshot = list(
+                self._by_slot.items())  # graftlint: unguarded-ok
+            ctx = self._flight_ctx()
         # 2. decode: every active slot, one compiled call — one token per
         # slot on the legacy path, up to k+1 (speculative) / decode_window
         # tokens per slot on the multi-token rounds
-        # GIL-atomic snapshot for cost attribution (same contract as
-        # _flight_ctx): who occupied which slot when the decode launched
-        rows_snapshot = list(self._by_slot.items())  # graftlint: unguarded-ok
         # brownout L2: bypass decode_window / speculative rounds and run
         # the always-warmed single-token decode step — less work per
         # call, zero recompiles (warmup traces _decode_fn regardless)
@@ -715,82 +752,92 @@ class FCFSScheduler:
             if force_single:
                 decoded = {
                     slot: [tok] for slot, tok in
-                    self.engine.decode_step(ctx=self._flight_ctx()).items()}
+                    self.engine.decode_step(ctx=ctx).items()}
             else:
-                decoded = self.engine.decode_round(ctx=self._flight_ctx())
+                decoded = self.engine.decode_round(ctx=ctx)
         except Exception as e:  # noqa: BLE001 — degradation boundary
             if not self._engine_failure(e):
                 raise
             decoded = {}
         t_dec1 = time.perf_counter()
-        if self.costs is not None and rows_snapshot and decoded:
-            # split the shared decode call across the n_slots rows the
-            # compiled program actually ran; slots with no request book
-            # as `idle`, rejected speculative drafts as `wasted`.
-            # Under brownout L2 the speculative window never ran, so the
-            # (stale) last_spec_slots must not attribute draft cost here.
-            spec_info = (self.engine.last_spec_slots
-                         if (not force_single
-                             and getattr(self.engine, "spec_enabled", False))
-                         else {})
-            rows = []
-            for slot, req in rows_snapshot:
-                if slot in spec_info:
-                    kd, a = spec_info[slot]
-                    rows.append((req.id, req.tenant, a + 1, kd - a))
-                else:
-                    rows.append((req.id, req.tenant,
-                                 max(len(decoded.get(slot, ())), 1), 0))
-            self.costs.record_decode(t_dec1 - t_dec0,
-                                     n_rows=self.engine.n_slots, rows=rows)
-        if self.costs is not None and getattr(self.engine, "paged", False):
-            # block-seconds: integral of blocks held over wall time,
-            # sampled once per step; shared prefix blocks split by live
-            # refcount so a popular prefix isn't billed N times over
-            if self._t_block_sample is not None and rows_snapshot:
-                self.costs.record_block_seconds(
-                    t_dec1 - self._t_block_sample,
-                    [(req.tenant, self.engine.slot_block_shares(slot))
-                     for slot, req in rows_snapshot])
-            self._t_block_sample = t_dec1
-        for slot, toks in decoded.items():
-            for tok in toks:
-                # dict.get is GIL-atomic and a concurrent cancel() is
-                # handled by the None check — taking _lock per token would
-                # serialize the decode loop against the submit path for
-                # nothing. Re-fetched per token: EOS/length retirement can
-                # fire MID-window, and the window's tail past it must be
-                # dropped, not delivered to the next slot tenant.
-                req = self._by_slot.get(slot)  # graftlint: unguarded-ok
-                if req is None or req.finished:
-                    break              # released / retired mid-window
-                now = time.perf_counter()
-                self.metrics.record_token(req.t_last_token, now)
-                # the shared decode call, attributed to every participant:
-                # one decode_step span per request per step (token index in
-                # the labels), bounded by the trace's span cap
-                req.trace.add_span("decode_step", t_dec0, t_dec1,
-                                   token=len(req.tokens))
-                self._deliver(req, tok, now)
-                emitted += 1
-        if getattr(self.engine, "spec_enabled", False):
-            window = self.engine.pop_spec_window()
-            if window is not None:
-                self.metrics.record_spec_window(*window)
+        with annotate("chainermn.serving_account"):
+            if self.costs is not None and rows_snapshot and decoded:
+                # split the shared decode call across the n_slots rows the
+                # compiled program actually ran; slots with no request
+                # book as `idle`, rejected speculative drafts as `wasted`.
+                # Under brownout L2 the speculative window never ran, so
+                # the (stale) last_spec_slots must not attribute draft
+                # cost here.
+                spec_info = (self.engine.last_spec_slots
+                             if (not force_single
+                                 and getattr(self.engine, "spec_enabled",
+                                             False))
+                             else {})
+                rows = []
+                for slot, req in rows_snapshot:
+                    if slot in spec_info:
+                        kd, a = spec_info[slot]
+                        rows.append((req.id, req.tenant, a + 1, kd - a))
+                    else:
+                        rows.append((req.id, req.tenant,
+                                     max(len(decoded.get(slot, ())), 1), 0))
+                self.costs.record_decode(t_dec1 - t_dec0,
+                                         n_rows=self.engine.n_slots,
+                                         rows=rows)
+            if self.costs is not None and getattr(self.engine, "paged",
+                                                  False):
+                # block-seconds: integral of blocks held over wall time,
+                # sampled once per step; shared prefix blocks split by
+                # live refcount so a popular prefix isn't billed N times
+                if self._t_block_sample is not None and rows_snapshot:
+                    self.costs.record_block_seconds(
+                        t_dec1 - self._t_block_sample,
+                        [(req.tenant, self.engine.slot_block_shares(slot))
+                         for slot, req in rows_snapshot])
+                self._t_block_sample = t_dec1
+        with annotate("chainermn.serving_deliver"):
+            for slot, toks in decoded.items():
+                for tok in toks:
+                    # dict.get is GIL-atomic and a concurrent cancel() is
+                    # handled by the None check — taking _lock per token
+                    # would serialize the decode loop against the submit
+                    # path for nothing. Re-fetched per token: EOS/length
+                    # retirement can fire MID-window, and the window's
+                    # tail past it must be dropped, not delivered to the
+                    # next slot tenant.
+                    req = self._by_slot.get(slot)  # graftlint: unguarded-ok
+                    if req is None or req.finished:
+                        break              # released / retired mid-window
+                    now = time.perf_counter()
+                    self.metrics.record_token(req.t_last_token, now)
+                    # the shared decode call, attributed to every
+                    # participant: one decode_step span per request per
+                    # step (token index in the labels), bounded by the
+                    # trace's span cap
+                    req.trace.add_span("decode_step", t_dec0, t_dec1,
+                                       token=len(req.tokens))
+                    self._deliver(req, tok, now)
+                    emitted += 1
         # deferred prefix-cache inserts run AFTER this step's tokens were
         # delivered (off the TTFT path) and before the next step can
         # reuse a donor slot
-        self.engine.flush_inserts()
-        with self._lock:
-            depth = len(self._queue)
-            batch_depth = sum(1 for r in self._queue
-                              if r.priority == "batch")
-        self.metrics.record_step(depth, self.engine.active_slots,
-                                 batch_depth=batch_depth)
-        if getattr(self.engine, "paged", False):
-            self.metrics.record_kv_pool(*self.engine.kv_pool_stats())
-        if self.costs is not None:
-            self.costs.flush()
+        with annotate("chainermn.serving_flush"):
+            self.engine.flush_inserts()
+        with annotate("chainermn.serving_account"):
+            if getattr(self.engine, "spec_enabled", False):
+                window = self.engine.pop_spec_window()
+                if window is not None:
+                    self.metrics.record_spec_window(*window)
+            with self._lock:
+                depth = len(self._queue)
+                batch_depth = sum(1 for r in self._queue
+                                  if r.priority == "batch")
+            self.metrics.record_step(depth, self.engine.active_slots,
+                                     batch_depth=batch_depth)
+            if getattr(self.engine, "paged", False):
+                self.metrics.record_kv_pool(*self.engine.kv_pool_stats())
+            if self.costs is not None:
+                self.costs.flush()
         return emitted
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> int:
@@ -1884,5 +1931,7 @@ __all__ = [
     "QueueFullError",
     "Request",
     "RequestState",
+    "STEP_PHASES",
+    "STEP_PHASE_CHILDREN",
     "SwapTicket",
 ]
