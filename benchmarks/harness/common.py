@@ -153,7 +153,8 @@ def emit(result: dict, checks: dict) -> None:
     result["checks"] = checks
     sys.stdout.flush()
     for name, c in checks.items():
-        print(f"check {name}: value {c['value']!r} limit {c['limit']!r} "
+        print(f"check {name}: value {c['value']!r} "
+              f"{c.get('must_be', '<=')} limit {c['limit']!r} "
               f"{'ok' if c['ok'] else 'FAILED'}", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
