@@ -1,40 +1,45 @@
-"""Fused Pallas paged-attention decode kernel (ROADMAP item 5).
+"""Fused Pallas paged-attention decode kernel (ROADMAP Speed 1).
 
 The XLA paged decode path (:func:`chainermn_tpu.parallel.sequence.
 paged_update_cache_and_attend`) reads the shared block store through a
 ``jnp.take`` gather that materializes each row's FULL table span as a
-dense ``[B, max_blocks*bs, H, D]`` view — in f32 when the store is int8,
-so the ``kv_quant`` bandwidth win (PERF.md "KV memory model") is thrown
-away at read time, and rows past each sequence's length are streamed
-just to be masked. This kernel fuses the whole read path per batch row:
+dense ``[B, max_blocks*bs, H, D]`` view, and streams rows past each
+sequence's length just to mask them. This kernel fuses the whole read
+path, one program per batch row (slot):
 
-- **block-table gather in the index map**: the ``[B, max_blocks]`` table
-  and the per-row ``lengths`` ride as scalar-prefetch operands
-  (``PrefetchScalarGridSpec``), so the K/V streaming index maps resolve
-  ``table[b, j]`` on the fly — blocks are DMA'd straight from the store,
-  and the dense per-sequence view never exists;
-- **clamp-skip past ``lengths``** (the paged analog of the flash
-  kernels' causal DMA clamp, PERF.md "Causal DMA clamp + block-1024
-  ceiling"): grid steps past ``ceil(lengths[b]/bs)`` alias the row's
-  last active block in the index map — Mosaic's pipeline elides the
-  repeat copy — and skip their compute via ``pl.when``, so a row streams
-  only the blocks it actually occupies;
-- **one DMA per live block, all heads**: heads fold into the row
-  dimension (free contiguous reshapes — ``q`` as ``[B, S*H, D]``, store
-  blocks as ``[bs*H, D]`` tiles) and each ``(b, j)`` grid cell computes
-  one dense all-head-pairs score tile with a head-match mask. Mosaic's
-  tiling rules force this shape anyway (single-head ``(..., 1, D)``
-  blocks and strided middle-dim slices are both unloadable), and it is
-  the right read schedule: a store block's bytes move once per decode
-  step, not once per head;
-- **in-register int8 dequant**: the per-row-per-head scales
-  ``[bs, H]`` tiles fold into the score/output contractions
-  (``s *= k_scale[t]`` after the QK dot; ``p *= v_scale[t]`` before the
-  PV dot) — bytes moved stay int8 + the tiny f32 scale vectors;
-- **position-masked online softmax**: the flash (m, l, acc) recurrence
-  in f32 VMEM scratch across the block sweep, flushed once at the last
-  grid step — exactly :func:`_fwd_kernel`'s structure with the k-chunk
-  axis replaced by table-indexed store blocks.
+- **the sweep**: the ``[B, max_blocks]`` table and the per-row ``lengths``
+  ride as scalar-prefetch operands; the store and its scale arrays stay in
+  HBM, and the program walks its slot's live blocks in chunks of C table
+  entries, starting one copy per block (K rows, V rows and, for an int8
+  store, the two scale rows) from ``store[table[b, j]]`` into one of two
+  VMEM buffers and computing on one chunk while the next is on its way.
+  The dense per-sequence view never exists;
+- **a trip count from ``lengths``**: the walk takes
+  ``cdiv(cdiv(lengths[b], bs), C)`` steps, so a dead table tail costs
+  nothing; in the last chunk the entries past the last live block copy
+  that block again and the position mask drops them, so a dead entry is
+  never looked through, whatever it points at;
+- **C from the shapes** (:func:`chunk_blocks`): the largest power of two
+  whose two buffers fit a fixed VMEM budget — 8 blocks, 128 tokens, at 16
+  heads of 128 in int8;
+- **all heads at once**: heads fold into the row dimension (free
+  contiguous reshapes — ``q`` as ``[B, S*H, D]``, store blocks as
+  ``[bs*H, D]`` tiles) and a chunk's scores are one ``[C, S·H, bs·H]``
+  tile over all head pairs with a head-match mask. Mosaic's tiling rules
+  force this shape (single-head ``(..., 1, D)`` blocks and strided
+  middle-dim slices are both unloadable); a block's bytes move once per
+  decode step, not once per head, and the MXU does ``H`` times the useful
+  work for it. Heads narrower than the 128 lanes share a store row, so the
+  kernel copies whole lanes at any head size that divides 128;
+- **operands no wider than exact**: int8 rows are exact in the query's
+  type and ride the MXU in it (bf16 x bf16 with f32 accumulation in a
+  served model); the per-row-per-head scales fold into the contractions
+  (``s *= k_scale[t]`` after the QK product; ``p *= v_scale[t]`` before
+  the PV product), and P, the one f32 operand, goes in as two or three
+  bf16 terms against the exact V (:func:`_pv`). A float32 store keeps
+  float32 operands at ``HIGHEST``;
+- **position-masked online softmax**: the flash (m, l, acc) recurrence in
+  f32 across the walk, written out once at its end.
 
 Shapes are the serving decode family: ``S = 1`` (per-token decode), the
 ``decode_window`` fori_loop body, and the speculative verify window
@@ -42,9 +47,12 @@ Shapes are the serving decode family: ``S = 1`` (per-token decode), the
 redirect affects only WRITES (handled XLA-side before the kernel runs);
 the attention itself is position-masked identically to
 :func:`cached_attention`. Off TPU the kernel runs in Pallas interpret
-mode (the same code path CPU tier-1 tests pin); real-hardware evidence
-lands per PERF.md's chip-free AOT discipline
-(``scripts/aot_paged_kernel.py``).
+mode (the same code path CPU tier-1 tests pin);
+``scripts/aot_paged_kernel.py`` lowers it for the chip without one, and
+PERF.md §5 and §6 hold what it takes on the chip. A model's layers call
+:func:`paged_attend` with one set of shapes, and the kernel is traced and
+lowered once for all of them (:func:`_attend`): tracing it a layer at a
+time cost a served model's warm-up more than compiling it did.
 """
 
 from __future__ import annotations
@@ -86,125 +94,216 @@ def kernel_supported() -> tuple[bool, str]:
     return True, ""
 
 
-def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                   scale: float, bs: int, n_j: int, n_heads: int,
-                   quant: bool):
-    """Grid ``(batch row b, table slot j)``, j INNERMOST: the
-    online-softmax state (m, l, acc) lives in f32 VMEM scratch across the
-    row's block sweep and the output block flushes once at the last slot.
-    ``k_ref``/``v_ref`` blocks arrive via the table-indexed clamped maps
-    (:func:`_store_map`), so slot j past the row's active block count
-    re-delivers the last active block — its compute is skipped below, so
-    values are unchanged and Mosaic elides the repeat DMA.
+# What the two chunk buffers of K, V and (int8) their scale rows may take
+# of VMEM, as Mosaic lays them out. The chip's scoped default is 16 MiB,
+# which the score tile, the query, the output and the compiler's own
+# temporaries share; at the served widths (16 heads of 128, blocks of 16
+# tokens, int8) this gives chunks of 8 blocks, 128 tokens.
+_VMEM_BUDGET = 2 * 2 ** 20
 
-    Heads are NOT a grid axis, and they are not sliced in-kernel either:
-    the caller flattens them into the row dimension (``q`` arrives as
-    ``[1, S*H, D]`` blocks with row ``t*H + h``; K/V store blocks as
-    ``[1, bs*H, D]``), so every operation here touches full 2D tiles —
-    Mosaic's tiling rules reject both single-head ``(..., 1, D)`` blocks
-    and strided middle-dim ref slices. One dense ``(S·H, bs·H)`` score
-    tile per block covers all head pairs; the cross-head entries
-    (``row % H != col % H``) are masked to the sentinel and zeroed in
-    ``p`` exactly like dead positions, so they add exact +0.0 terms to
-    the contractions. That spends H× the MXU work of a per-head sweep —
-    expected to be free: decode attention reads each KV byte once for a
-    few FLOPs (kernel time on the chip: not measured), and this shape is
-    what buys one DMA per live block for ALL heads."""
+
+def _tile_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM bytes of a ``[rows, cols]`` array in Mosaic's tiling: 128
+    lanes by 8, 16 or 32 sublanes for 4-, 2- and 1-byte elements."""
+    size = jnp.dtype(dtype).itemsize
+    sub = 32 // size
+    return -(-rows // sub) * sub * -(-cols // _LANE) * _LANE * size
+
+
+def chunk_blocks(bs: int, n_heads: int, head_dim: int, dtype, quant: bool,
+                 n_j: int) -> int:
+    """Store blocks per chunk of the sweep: the largest power of two whose
+    two buffers of K and V rows (and scale rows, for an int8 store) fit
+    :data:`_VMEM_BUDGET`, at least 1 and at most the ``n_j`` entries of a
+    slot's table row. Read from the operands' shapes, never from a user."""
+    block = 2 * _tile_bytes(bs * n_heads, head_dim, dtype)
     if quant:
-        ks_ref, vs_ref, o_ref, m_acc, l_acc, o_acc = rest
+        block += 2 * _tile_bytes(1, bs * n_heads, jnp.float32)
+    c = 1
+    while 2 * c <= n_j and 2 * (2 * c) * block <= _VMEM_BUDGET:
+        c *= 2
+    return c
+
+
+def _pv(p, vb, out_dtype):
+    """``P·V`` over a chunk, ``[C, S·H, bs·H] x [C, bs·H, D]``, summed
+    over the chunk's blocks. A float32 store keeps float32 operands at
+    ``HIGHEST``. int8 and bfloat16 rows are exact in bfloat16, so there P,
+    the one float32 operand, is split into bfloat16 terms of 8 mantissa
+    bits, each riding the MXU's native mode: three for a float32 result
+    (24 bits: float32 again, half the passes of a float32 product), two
+    where the result is rounded to the model's bfloat16 anyway (16 bits
+    against its 8)."""
+    dims = (((2,), (1,)), ((0,), (0,)))
+    if vb.dtype == jnp.float32:
+        pv = jax.lax.dot_general(p, vb, dims,
+                                 preferred_element_type=jnp.float32,
+                                 precision=jax.lax.Precision.HIGHEST)
+        return jnp.sum(pv, axis=0)
+    vb = vb.astype(jnp.bfloat16)
+    pv = None
+    for _ in range(3 if out_dtype == jnp.float32 else 2):
+        hi = p.astype(jnp.bfloat16)
+        term = jax.lax.dot_general(hi, vb, dims,
+                                   preferred_element_type=jnp.float32)
+        pv = term if pv is None else pv + term
+        p = p - hi.astype(jnp.float32)
+    return jnp.sum(pv, axis=0)
+
+
+def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                  scale: float, bs: int, n_j: int, n_heads: int,
+                  pack: int, chunk: int, quant: bool):
+    """One program per slot ``b``: it walks the slot's live blocks in
+    chunks of ``chunk`` table entries, copying each chunk's blocks from the
+    store (left in HBM) into one of two VMEM buffers while it computes on
+    the other, and keeps the online-softmax state (m, l, acc) in float32
+    across the walk. The loop runs ``cdiv(cdiv(lengths[b], bs), chunk)``
+    times: a dead table tail costs nothing, and a slot of length 0 writes
+    zeros. In the last chunk the entries past the last live block copy
+    that block again, so a buffer never holds anything but live blocks of
+    its slot — whatever a dead table entry points at (NaN rows, infinite
+    scales) is never read — and the position mask drops the repeats.
+
+    The copies run one chunk ahead of the arithmetic ACROSS slots: while a
+    slot's last chunk is computed on, the first chunk of the next slot
+    that has one is already on its way (the programs run in order, and
+    ``par`` carries the buffer's parity from one to the next), so only
+    the call's very first chunk is waited for with nothing to do.
+
+    Heads are not a grid axis and are not sliced: they fold into the row
+    dimension (``q`` as ``[S*H, D]`` with row ``t*H + h``, a store block as
+    ``[bs*H, D]``), since Mosaic loads neither single-head ``(..., 1, D)``
+    blocks nor strided middle-dimension slices. A chunk's scores are one
+    ``[C, S·H, bs·H]`` tile over all head pairs, of which the head mask
+    keeps one in ``H``: the MXU does ``H`` times the useful work, the price
+    of moving a block's bytes once for all heads. Where a head is narrower
+    than the 128 lanes, ``pack`` of them share a store row (see
+    :func:`paged_attend`) and a column stands for ``pack`` heads."""
+    if quant:
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, par = rest
     else:
-        o_ref, m_acc, l_acc, o_acc = rest
-    sh = q_ref.shape[1]                                    # S * H
-    kvh = k_ref.shape[1]                                   # bs * H
-    s_len = sh // n_heads
+        o_ref, k_buf, v_buf, sem, par = rest
+    sh, d = q_ref.shape[1], q_ref.shape[2]                 # S * H, D
+    kvh = k_buf.shape[2]                                   # bs * H / pack
+    s_len, hp = sh // n_heads, n_heads // pack
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
     length = len_ref[b]
 
-    @pl.when(j == 0)
-    def _init():
-        m_acc[...] = jnp.full_like(m_acc, _NEG_BIG)
-        l_acc[...] = jnp.zeros_like(l_acc)
-        o_acc[...] = jnp.zeros_like(o_acc)
+    def live_blocks(slot_b):
+        return jnp.minimum(pl.cdiv(len_ref[slot_b], bs), n_j)
 
-    def compute():
-        q = q_ref[0]                                       # [S*H, D]
-        kb = k_ref[0]                                      # [bs*H, D]
-        vb = v_ref[0]
-        m = m_acc[:, 0]
-        l = l_acc[:, 0]
-        if quant:
-            # int8 rows hit the MXU through an in-register cast; the
-            # dequant SCALES fold into the contractions instead of
-            # scaling the tiles (same math, fewer multiplies, and the
-            # f32 dense view never exists anywhere). q rides along to
-            # f32 (exact): Mosaic's matmul wants matching operand types
-            # and XLA's mixed-dtype dot promotes to f32 anyway.
-            kb = kb.astype(jnp.float32)
-            q = q.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_prec(q, kb),
-        ) * scale
-        if quant:
-            s = s * ks_ref[0]                              # [1, bs*H]
-        # row i is (token t = i // H, head i % H) at global position
-        # lengths-S+t; col c is (store row c // H, head c % H) at
-        # position j*bs + c//H — keep causal AND same-head entries
-        ri = jax.lax.broadcasted_iota(jnp.int32, (sh, kvh), 0)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (sh, kvh), 1)
-        q_pos = (length - s_len) + ri // n_heads
-        k_pos = j * bs + ci // n_heads
-        keep = (k_pos <= q_pos) & (ri % n_heads == ci % n_heads)
-        s = jnp.where(keep, s, _NEG_BIG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        corr = jnp.exp(m - m_new)
-        # explicit zero for masked entries (see _fwd_kernel: a fully-
-        # masked row within a visited block would otherwise accumulate
-        # mean-of-V garbage through exp(sentinel - sentinel) == 1);
-        # here the zeroing also erases the cross-head columns
-        p = jnp.where(s <= _NEG_BIG / 2, 0.0, jnp.exp(s - m_new[:, None]))
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        if quant:
-            p = p * vs_ref[0]
-        # the PV product runs f32·f32 with V upcast IN-REGISTER —
-        # matching cached_attention's `p @ v.astype(f32)` numerics, NOT
-        # the flash kernels' storage-dtype MXU trick: greedy decode
-        # argmax-ties against the XLA paged path (the token-parity
-        # acceptance bar) are far tighter than a bf16 probability
-        # matrix's ~0.4% rounding. Streamed bytes are unaffected (the
-        # cast happens after the DMA).
-        pv = jax.lax.dot_general(
-            p, vb.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_prec(p),
-        )
-        m_acc[...] = jnp.broadcast_to(m_new[:, None], m_acc.shape)
-        l_acc[...] = jnp.broadcast_to(l_new[:, None], l_acc.shape)
-        o_acc[...] = o_acc[...] * corr[:, None] + pv
+    n_chunks = pl.cdiv(live_blocks(b), chunk)
+    # the slot after this one has a first chunk to send for
+    has_next = (b + 1 < pl.num_programs(0)) & (live_blocks(nxt) > 0)
 
-    # blocks wholly past the row's length never contribute — skip the
-    # math (their DMA is already aliased away by the clamped map)
-    pl.when(j * bs < length)(compute)
+    pairs = [(k_hbm, k_buf), (v_hbm, v_buf)]
+    if quant:
+        pairs += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
 
-    @pl.when(j == n_j - 1)
-    def _flush():
-        l = l_acc[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (o_acc[...] / l_safe[:, None]).astype(o_ref.dtype)
+    def copies(slot_b, i, buf, act):
+        """``act`` (start or wait) on every copy of chunk ``i`` of slot
+        ``slot_b`` into buffer ``buf``: per block its K rows, its V rows
+        and, for an int8 store, its two scale rows."""
+        last = live_blocks(slot_b) - 1
 
+        def block(c, carry):
+            blk = table_ref[slot_b, jnp.minimum(i * chunk + c, last)]
+            for src, dst in pairs:
+                act(pltpu.make_async_copy(src.at[blk], dst.at[buf, c],
+                                          sem.at[buf]))
+            return carry
 
-def _store_map(bs: int):
-    """Streaming-side index map for the K/V store (and its scale
-    arrays): slot j of row b maps to store block ``table[b, j]``, and
-    slots past the row's last active block alias that block — the paged
-    analog of :func:`_kv_clamped_map`'s causal DMA clamp, driven by the
-    scalar-prefetched per-row ``lengths`` instead of a static delta."""
-    def kv_map(b, j, table_ref, len_ref):
-        n_active = (len_ref[b] + bs - 1) // bs
-        jc = jnp.minimum(j, jnp.maximum(n_active - 1, 0))
-        return (table_ref[b, jc], 0, 0)
+        jax.lax.fori_loop(0, chunk, block, None)
 
-    return kv_map
+    def start(slot_b, i, buf):
+        copies(slot_b, i, buf, lambda cp: cp.start())
+
+    @pl.when(b == 0)
+    def _first():
+        par[0] = 0
+        pl.when(n_chunks > 0)(lambda: start(b, 0, 0))
+
+    first = par[0]                  # the buffer this slot's chunk 0 is in
+
+    def sweep():
+        # row i of the query is (token i // H, head i % H), the token at
+        # global position lengths-S + i//H; column c of block j in the
+        # chunk is (store row c // hp, heads c % hp * pack and the
+        # pack - 1 after it, hp = H / pack of them a token): keep causal
+        # entries whose column holds the row's head
+        ri = jax.lax.broadcasted_iota(jnp.int32, (1, sh, 1), 1)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kvh), 2)
+        ji = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
+        same_head = ri % n_heads // pack == ci % hp        # [1, S*H, kvh]
+        k_tok = ji * bs + ci // hp                         # [C, 1, kvh]
+        q_tok = (length - s_len) + ri // n_heads           # [1, S*H, 1]
+
+        def scales(ref, buf):
+            """The scale of each column for each row's own head: one row
+            of the block's ``pack``, ``[C, 1 or S*H, kvh]``."""
+            out = ref[buf, :, 0:1, :kvh]
+            for u in range(1, pack):
+                out = jnp.where(ri % pack == u, ref[buf, :, u:u + 1, :kvh],
+                                out)
+            return out
+
+        q = q_ref[0]
+        # int8 rows are exact in the query's type (the model's bf16, or
+        # the tests' f32) and ride the MXU in it, as the XLA read path's
+        # ``k8.astype(q.dtype)`` does; the scales fold in after the product
+        ctype = jnp.promote_types(q.dtype, k_buf.dtype)
+        qc = jnp.broadcast_to(q.astype(ctype)[None], (chunk, sh, d))
+
+        def body(i, carry):
+            m, l, acc = carry
+            buf = jax.lax.rem(first + i, 2)
+            more = i + 1 < n_chunks
+            # send for what is computed on next: this slot's next
+            # chunk, or after its last one the next slot's first
+            @pl.when(more | has_next)
+            def _ahead():
+                start(jnp.where(more, b, nxt), jnp.where(more, i + 1, 0),
+                      1 - buf)
+
+            copies(b, i, buf, lambda cp: cp.wait())
+            s = jax.lax.dot_general(
+                qc, k_buf[buf].astype(ctype),
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32, precision=_prec(qc),
+            )                                              # [C, S*H, bs*H]
+            # the softmax scale rides on the scale row where there is
+            # one: a row, not the tile's S*H
+            s = s * (scales(ks_buf, buf) * scale if quant else scale)
+            keep = same_head & (i * (chunk * bs) + k_tok <= q_tok)
+            s = jnp.where(keep, s, _NEG_BIG)
+            m_new = jnp.maximum(
+                m, jnp.max(jnp.max(s, axis=0), axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            # an explicit zero where masked: a row with nothing to see
+            # yet has s == m_new == the sentinel, and exp(0) would count
+            p = jnp.where(keep, jnp.exp(s - m_new[None]), 0.0)
+            l_new = l * corr + jnp.sum(jnp.sum(p, axis=0), axis=-1,
+                                       keepdims=True)
+            if quant:
+                p = p * scales(vs_buf, buf)
+            return m_new, l_new, acc * corr + _pv(p, v_buf[buf], o_ref.dtype)
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, body,
+            (jnp.full((sh, 1), _NEG_BIG, jnp.float32),
+             jnp.zeros((sh, 1), jnp.float32),
+             jnp.zeros((sh, d), jnp.float32)))
+        o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        par[0] = jax.lax.rem(first + n_chunks, 2)
+
+    @pl.when(n_chunks == 0)
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        pl.when(has_next)(lambda: start(nxt, 0, first))
+
+    pl.when(n_chunks > 0)(sweep)
 
 
 def paged_attend(q, store_k, store_v, table, lengths, *,
@@ -221,74 +320,111 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
       it moves ``S`` rows; the kernel owns the O(length) read side);
     - ``table``: ``[B, max_blocks]`` int32 block table;
     - ``lengths``: ``[B]`` int32 — valid KV rows per row AFTER the
-      write (``pos + S``). Blocks past ``ceil(lengths[b]/bs)`` are
-      clamp-skipped: neither streamed nor computed;
+      write (``pos + S``). Only the ``ceil(lengths[b]/bs)`` blocks they
+      reach are copied or computed on; a table entry past them is never
+      looked through, whatever it holds;
     - ``k_scale``/``v_scale``: ``[n_blocks, bs, H]`` f32, present iff
       the store is int8 (dequant folds into the contractions);
-    - ``max_blocks``: optional static cap on table slots to sweep
-      (callers with static positions pass the batch-max active count —
-      the grid then never visits provably-dead table tail entries).
+    - ``max_blocks``: optional static cap on the table entries a row can
+      have live (callers with static positions pass the batch-max active
+      count); it bounds the sweep's trip count and nothing else.
 
     Returns ``[B, S, H, D]`` in ``q.dtype`` — position-masked exactly
     like :func:`~chainermn_tpu.parallel.sequence.cached_attention` over
     the gathered table span, to fp tolerance (same masked set, flash
     summation order). Off TPU runs in interpret mode by default."""
-    b, s_len, h, d = q.shape
-    bs = store_k.shape[1]
     n_j = table.shape[1]
     if max_blocks is not None:
         n_j = max(1, min(n_j, int(max_blocks)))
     if scale is None:
-        scale = d ** -0.5
+        scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = kernels_interpreted()
-    quant = k_scale is not None
-    table = jnp.asarray(table, jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
+    return _attend(q, store_k, store_v, jnp.asarray(table, jnp.int32),
+                   jnp.asarray(lengths, jnp.int32), k_scale, v_scale,
+                   scale=float(scale), n_j=n_j, interpret=bool(interpret))
 
-    kv_map = _store_map(bs)
+
+@functools.partial(jax.jit, static_argnames=("scale", "n_j", "interpret"),
+                   inline=True)
+def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
+            scale: float, n_j: int, interpret: bool):
+    """:func:`paged_attend` with its defaults filled in. A model calls it
+    once a layer with the same shapes: ``jit`` traces the kernel for the
+    first and hands the others the same equations, which then also lower
+    once a program; ``inline`` writes them into the caller's trace under
+    the caller's own names, so no call stands between a block and its
+    kernel."""
+    b, s_len, h, d = q.shape
+    n_blocks, bs = store_k.shape[:2]
+    quant = k_scale is not None
+
+    def lanes(x):
+        """``x`` zero-padded to whole tiles of 128 lanes: Mosaic copies
+        nothing narrower out of HBM."""
+        pad = -x.shape[-1] % _LANE
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
     # heads fold into the ROW dimension (free contiguous reshapes) so
-    # every block is a full 2D tile: Mosaic's tiling rules reject both
-    # single-head (..., 1, D) blocks and strided middle-dim slices, and
-    # the flat shape is the better schedule anyway — one DMA per live
-    # block for ALL heads. Scales flatten to [n_blocks, 1, bs*H] row
-    # vectors for the same reason.
-    n_blocks = store_k.shape[0]
-    qf = q.reshape(b, s_len * h, d)
-    kf = store_k.reshape(n_blocks, bs * h, d)
-    vf = store_v.reshape(n_blocks, bs * h, d)
-    qo_map = lambda b_, j_, table_ref, len_ref: (b_, 0, 0)
-    in_specs = [
-        pl.BlockSpec((1, s_len * h, d), qo_map),
-        pl.BlockSpec((1, bs * h, d), kv_map),
-        pl.BlockSpec((1, bs * h, d), kv_map),
-    ]
-    operands = [qf, kf, vf]
+    # every copy and every operand is a full 2D tile; the scales flatten
+    # to row vectors for the same reason. The store stays where it is and
+    # the kernel copies the blocks it needs. A head narrower than the 128
+    # lanes shares a row with its neighbours (``pack`` heads a row, a free
+    # view again): the query then sits in its own head's lanes of a row of
+    # zeros, the scales go in as ``pack`` rows a block, and each output row
+    # is read from its head's lanes. Only what neither view brings to whole
+    # lanes is padded, at the price of a copy a call: nothing at the served
+    # widths (heads of 128, bs*H of 256), the small scale rows of a store
+    # sharded down to a few heads, a toy head size that does not divide 128.
+    pack = _LANE // d if _LANE % d == 0 and h % (_LANE // d) == 0 else 1
+    sh, rows, dl = s_len * h, bs * h // pack, pack * d
+    qf = q.reshape(b, sh, 1, d)
+    if pack > 1:
+        own = (jnp.arange(sh) % pack)[:, None] == jnp.arange(pack)
+        own = own[None, :, :, None].astype(q.dtype)       # [1, S*H, pack, 1]
+        qf = qf * own
+    operands = [lanes(qf.reshape(b, sh, dl)),
+                lanes(store_k.reshape(n_blocks, rows, dl)),
+                lanes(store_v.reshape(n_blocks, rows, dl))]
+    dp = operands[0].shape[-1]
+    # from the table's width, not from ``max_blocks``: the cap bounds the
+    # trip count and leaves the chunking, so the summation order, alone
+    chunk = chunk_blocks(bs, h // pack, dp, store_k.dtype, quant,
+                         table.shape[1])
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    qo_spec = pl.BlockSpec((1, sh, dp),
+                           lambda b_, table_ref, len_ref: (b_, 0, 0))
+    in_specs = [qo_spec, in_hbm, in_hbm]
+    scratch = [pltpu.VMEM((2, chunk, rows, dp), store_k.dtype),
+               pltpu.VMEM((2, chunk, rows, dp), store_v.dtype)]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, bs * h), kv_map),
-                     pl.BlockSpec((1, 1, bs * h), kv_map)]
-        operands += [k_scale.reshape(n_blocks, 1, bs * h),
-                     v_scale.reshape(n_blocks, 1, bs * h)]
+        in_specs += [in_hbm, in_hbm]
+        operands += [lanes(sc.reshape(n_blocks, rows, pack).swapaxes(1, 2))
+                     for sc in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, chunk) + operands[-1].shape[1:],
+                               jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
     vma = _out_vma(q, store_k, store_v, table, lengths)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, bs=bs, n_j=n_j,
-                          n_heads=h, quant=quant),
+        functools.partial(_sweep_kernel, scale=scale, bs=bs, n_j=n_j,
+                          n_heads=h, pack=pack, chunk=chunk, quant=quant),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, n_j),
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, s_len * h, d), qo_map),
-            scratch_shapes=[
-                pltpu.VMEM((s_len * h, _LANE), jnp.float32),  # running max m
-                pltpu.VMEM((s_len * h, _LANE), jnp.float32),  # running l
-                pltpu.VMEM((s_len * h, d), jnp.float32),      # unnorm. acc
-            ],
+            out_specs=qo_spec,
+            scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s_len * h, d), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((b, sh, dp), q.dtype, vma=vma),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            # in order: a slot's last chunk sends for the next slot's
+            # first (one TensorCore a chip on the v5e, so nothing is lost)
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(table, lengths, *operands)
+    out = out[..., :dl]
+    if pack > 1:
+        out = jnp.sum(out.reshape(b, sh, pack, d) * own, axis=2)
     return out.reshape(b, s_len, h, d)
 
 

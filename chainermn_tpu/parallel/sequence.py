@@ -899,12 +899,14 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
 
     A truthy ``'use_kernel'`` entry routes the read side through the
     fused Pallas kernel (:func:`chainermn_tpu.parallel.paged_kernel.
-    paged_attend`): table-indexed block gather, in-register dequant and
-    online-softmax attention in one pass, streaming only each row's
-    ``ceil(len/bs)`` active blocks. The scatter (write side) is XLA on
-    every path — it moves ``S`` rows, the kernel owns the O(length)
-    read. ``'use_kernel'`` must be a static Python bool (it selects a
-    trace, it is not an operand).
+    paged_attend`): one program per row walks that row's
+    ``ceil(len/bs)`` live blocks in chunks, copying them from the store
+    itself while it computes, with the dequant and the online softmax in
+    the same pass; the table's dead tail is neither copied nor looked
+    through. The scatter (write side) is XLA on every path — it moves
+    ``S`` rows, the kernel owns the O(length) read. ``'use_kernel'``
+    must be a static Python bool (it selects a trace, it is not an
+    operand).
 
     Static shapes throughout — table contents change, programs never
     recompile. Returns ``(out, new_cache)`` where ``new_cache`` carries

@@ -46,9 +46,11 @@ machine plus its host/device programs.
 Under ``ServingEngine(paged_kernel=True)`` the verify window's
 attention reads ride the fused Pallas paged-decode kernel
 (``parallel.paged_kernel.paged_attend``) like every other decode shape:
-the S=k+1 window is just a wider query block, and the per-slot
-``valid`` write caps redirect rejected rows before the kernel ever
-reads them, so acceptance bookkeeping is unchanged and the committed
+the S=k+1 window is ``k+1`` times the query rows against the same chunks
+of each slot's live blocks (the sweep's trip count comes from
+``lengths = pos + S``), and the per-slot ``valid`` write caps redirect
+rejected rows before the kernel ever reads them, so acceptance
+bookkeeping is unchanged and the committed
 stream stays token-for-token identical to the XLA paged path (pinned
 in ``tests/serving_tests/test_paged_kernel_engine.py``).
 """
