@@ -8,7 +8,9 @@ from chainermn_tpu.models.resnet import (
     ResNet101,
     ResNet152,
 )
+from chainermn_tpu.models.smallthinker import SmallThinkerLM
 from chainermn_tpu.models.transformer import (
+    KVCacheKind,
     TransformerBlock,
     TransformerLM,
     generate,
@@ -29,6 +31,8 @@ __all__ = [
     "GoogLeNet",
     "InceptionBlock",
     "VGG16",
+    "KVCacheKind",
+    "SmallThinkerLM",
     "TransformerBlock",
     "TransformerLM",
     "generate",

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import dataclasses
 import functools
 
 import flax.linen as nn
@@ -27,6 +28,22 @@ from jax import lax
 
 from chainermn_tpu.parallel.moe import ExpertParallelMLP
 from chainermn_tpu.parallel.sequence import sequence_parallel_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class KVCacheKind:
+    """One kind of KV state a served model keeps, as its
+    ``kv_cache_spec()`` tells the serving engine: which layers are of the
+    kind, the KV heads and head size of a stored row, and ``window`` — the
+    positions a layer of the kind sees back from its own (``None``: every
+    one, so the state grows with the sequence). The engine keeps a block
+    store, a table and a block budget per kind."""
+
+    name: str
+    layers: tuple
+    kv_heads: int
+    head_dim: int
+    window: Optional[int] = None
 
 
 class TransformerBlock(nn.Module):
@@ -204,9 +221,16 @@ class TransformerLM(nn.Module):
     # kv_caches decode has no backward and ignores it.
     remat: bool = False
 
+    def kv_cache_spec(self) -> tuple:
+        """One kind: every layer keeps every token, a K and a V row of
+        ``n_heads`` heads each."""
+        return (KVCacheKind("full", tuple(range(self.n_layers)),
+                            self.n_heads, self.d_model // self.n_heads),)
+
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_aux: bool = False,
-                 kv_caches=None, return_hidden: bool = False):
+                 kv_caches=None, return_hidden: bool = False,
+                 logits_at=None):
         if self.tensor_axis is not None and self.moe_experts:
             raise ValueError(
                 "tensor_axis and moe_experts are mutually exclusive: the MoE "
@@ -293,6 +317,11 @@ class TransformerLM(nn.Module):
                 raise ValueError("return_hidden is a training-loss path; "
                                  "decode wants logits")
             return (x, aux_total) if return_aux else x
+        if logits_at is not None:
+            # a prefill samples from one position a row: only that row of
+            # the hidden states goes through the head, logits [B, vocab]
+            x = jnp.take_along_axis(
+                x, logits_at[:, None, None], axis=1)[:, 0]
         if self.vocab_parallel_head:
             from chainermn_tpu.parallel.tensor import ColumnParallelDense
 
@@ -324,12 +353,14 @@ def init_kv_caches(model: TransformerLM, batch: int, cache_len: int,
     return [{"k": z(), "v": z()} for _ in range(model.n_layers)]
 
 
-def init_paged_kv_caches(model: TransformerLM, n_blocks: int,
-                         block_size: int, *,
+def init_paged_kv_caches(model, n_blocks, block_size: int, *,
                          local_heads: Optional[int] = None,
                          quant: str = "none"):
     """Zeroed per-layer **paged** KV block stores: a list of ``{'k','v'}``
-    dicts shaped ``[n_blocks, block_size, heads, d_head]`` — one pool of
+    dicts shaped ``[n_blocks, block_size, heads, d_head]`` in the model's
+    layer order, heads and head size as the model's ``kv_cache_spec()``
+    gives them for the layer's kind; ``n_blocks`` is one count, or one per
+    kind in the spec's order. Within a kind it is one pool of
     fixed-size token blocks shared by every sequence, addressed through a
     ``[B, max_blocks]`` block table the caller threads into each layer
     dict as its ``'table'`` entry (see
@@ -340,19 +371,22 @@ def init_paged_kv_caches(model: TransformerLM, n_blocks: int,
     Tensor-parallel decode passes ``local_heads=n_heads // tp_size``."""
     if quant not in ("none", "int8"):
         raise ValueError(f"quant must be 'none' or 'int8', got {quant!r}")
-    h = local_heads or model.n_heads
-    dh = model.d_model // model.n_heads
+    spec = model.kv_cache_spec()
+    if isinstance(n_blocks, int):
+        n_blocks = (n_blocks,) * len(spec)
     dt = jnp.int8 if quant == "int8" else model.compute_dtype
 
-    def layer():
-        d = {"k": jnp.zeros((n_blocks, block_size, h, dh), dt),
-             "v": jnp.zeros((n_blocks, block_size, h, dh), dt)}
+    def layer(n, h, dh):
+        d = {"k": jnp.zeros((n, block_size, h, dh), dt),
+             "v": jnp.zeros((n, block_size, h, dh), dt)}
         if quant == "int8":
-            d["k_scale"] = jnp.zeros((n_blocks, block_size, h), jnp.float32)
-            d["v_scale"] = jnp.zeros((n_blocks, block_size, h), jnp.float32)
+            d["k_scale"] = jnp.zeros((n, block_size, h), jnp.float32)
+            d["v_scale"] = jnp.zeros((n, block_size, h), jnp.float32)
         return d
 
-    return [layer() for _ in range(model.n_layers)]
+    layers = {i: layer(n, local_heads or kind.kv_heads, kind.head_dim)
+              for kind, n in zip(spec, n_blocks) for i in kind.layers}
+    return [layers[i] for i in range(len(layers))]
 
 
 def generate(

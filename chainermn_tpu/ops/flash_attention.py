@@ -194,13 +194,16 @@ def _static_delta(causal, q_offset, k_offset):
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_acc, l_acc, o_acc, *, scale: float, causal: bool,
-                n_k: int):
+                n_k: int, window: Optional[int] = None):
     """Grid ``(bh, q-block, k-chunk)``, k-chunk INNERMOST: the online-
     softmax state (m, l, acc) lives in f32 VMEM scratch across the k sweep
     and the o/lse output blocks flush once at the last chunk — per-cell
     VMEM is O(block_q + block_k) regardless of T (the previous form held
     the full [tk, d] K/V blocks per cell). Fully-masked chunks skip their
-    compute via pl.when (the former dynamic trip-count clamp)."""
+    compute via pl.when (the former dynamic trip-count clamp). ``window``
+    (causal only) lets a query see the ``window`` positions up to its own:
+    chunks wholly before a q-block's first visible position are skipped
+    like those after its last."""
     bq, d = q_ref.shape[1], q_ref.shape[2]
     bk = k_ref.shape[1]
     j = pl.program_id(2)
@@ -231,7 +234,10 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = k_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_BIG)
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG_BIG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         corr = jnp.exp(m - m_new)
         # explicit zero for masked entries: when a row is fully masked within
@@ -250,7 +256,12 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             (l * corr + jnp.sum(p, axis=-1))[:, None], l_acc.shape)
         o_acc[...] = o_acc[...] * corr[:, None] + pv
 
-    if causal:
+    if causal and window is not None:
+        # ... and chunks whose last position lies before the first q
+        # position's window
+        pl.when((q_off + bq - 1 >= k_off)
+                & (k_off + bk - 1 > q_off - window))(compute)
+    elif causal:
         # chunks whose first position is beyond the last q position never
         # contribute — skip the math (the DMA still streams; same traffic
         # as the old full-block fetch)
@@ -272,18 +283,24 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
-def _kv_clamped_map(delta, block_q, block_k, n_k):
+def _kv_clamped_map(delta, block_q, block_k, n_k, group=1, window=None):
     """Streaming-side index map for grids ``(bh, q-block i, k-chunk j)``:
     chunks past q-block i's last visible chunk alias that chunk (same
     block index -> the pipeline skips the copy). The kernel's pl.when
     skips their compute by the true j, so values are unchanged.
-    ``delta=None`` (traced offsets / non-causal) -> plain streaming map."""
+    ``delta=None`` (traced offsets / non-causal) -> plain streaming map.
+    ``group`` query heads read one KV head (``bh`` counts the query's);
+    with a ``window`` the chunks wholly before q-block i's first visible
+    position alias its first visible chunk the same way."""
     if delta is None:
-        return lambda b, i, j: (b, j, 0)
+        return lambda b, i, j: (b // group, j, 0)
 
     def kv_map(b, i, j):
         vis = (delta + (i + 1) * block_q - 1) // block_k
-        return (b, jnp.clip(jnp.minimum(j, vis), 0, n_k - 1), 0)
+        j = jnp.minimum(j, vis)
+        if window is not None:
+            j = jnp.maximum(j, (delta + i * block_q - window + 1) // block_k)
+        return (b // group, jnp.clip(j, 0, n_k - 1), 0)
     return kv_map
 
 
@@ -301,8 +318,9 @@ def _q_clamped_map(delta, block_q, block_k, n_q):
 
 
 def _fwd(q, k, v, q_offset, k_offset, *, scale, causal, block_q, block_k,
-         interpret, out_dtype=None, static_delta=None):
+         interpret, out_dtype=None, static_delta=None, window=None):
     bh, tq, d = q.shape
+    group = bh // k.shape[0]
     tk = k.shape[1]
     n_k = tk // block_k
     # k-chunk INNERMOST (sequential: the online-softmax scratch accumulates
@@ -311,10 +329,17 @@ def _fwd(q, k, v, q_offset, k_offset, *, scale, causal, block_q, block_k,
     qo = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
     ko = jnp.asarray(k_offset, jnp.int32).reshape(1, 1)
     smem = _smem_spec()
-    kv_map = _kv_clamped_map(static_delta, block_q, block_k, n_k)
+    if group == 1 and window is None:
+        kv_map = _kv_clamped_map(static_delta, block_q, block_k, n_k)
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                                   n_k=n_k)
+    else:
+        kv_map = _kv_clamped_map(static_delta, block_q, block_k, n_k,
+                                 group, window)
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                                   n_k=n_k, window=window)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          n_k=n_k),
+        kernel,
         grid=grid,
         in_specs=[
             smem,
@@ -615,6 +640,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ):
     """Blockwise (flash) attention, layout ``[B, T, H, D]`` like
     :func:`chainermn_tpu.parallel.sequence.full_attention`.
@@ -623,9 +649,22 @@ def flash_attention(
     ``k[:, 0]`` for causal masking under sequence sharding (may be traced).
     Differentiable (custom VJP, flash backward kernels). Runs compiled on
     TPU, interpreted elsewhere (``interpret=None`` auto-detects).
+
+    Two forms are forward only (a served model's prefill; no VJP is
+    defined for them): ``k``/``v`` with fewer heads than ``q`` (grouped KV
+    heads, ``Hkv`` dividing ``H``: query head ``g`` reads KV head
+    ``g // (H // Hkv)``, nothing is repeated in memory), and ``window``
+    (causal only): position ``t`` sees ``t - window < j <= t``, and
+    k-chunks wholly outside a q-block's window are neither computed on
+    nor, with static offsets, copied.
     """
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    hk = k.shape[2]
+    if h % hk:
+        raise ValueError(f"{h} query heads do not divide over {hk} KV heads")
+    if window is not None and not causal:
+        raise ValueError("window needs causal=True")
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
@@ -641,6 +680,23 @@ def flash_attention(
             isinstance(q_offset, (int, np.integer)) and q_offset == 0
             and isinstance(k_offset, (int, np.integer)) and k_offset == 0
         )
+        if hk != h or window is not None:
+            if not (static_zero_offsets and tq == tk):
+                raise ValueError(
+                    f"flash_attention: lengths (tq={tq}, tk={tk}) have no "
+                    "usable block divisor and the XLA fallback for grouped "
+                    "KV heads or a window takes whole sequences from "
+                    "position 0 — pad the sequence to a multiple of 8")
+            k, v = (jnp.repeat(x, h // hk, axis=2) for x in (k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                           preferred_element_type=jnp.float32) * scale
+            i = jnp.arange(tq)
+            seen = i[:, None] >= i[None, :] if causal else True
+            if window is not None:
+                seen = seen & (i[:, None] - i[None, :] < window)
+            p = jax.nn.softmax(jnp.where(seen, s, _NEG_BIG), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)
+                              ).astype(q.dtype)
         if not causal or (static_zero_offsets and tq == tk):
             return full_attention(q, k, v, causal=causal, scale=scale)
         raise ValueError(
@@ -649,6 +705,16 @@ def flash_attention(
             "implemented — pad the sequence to a multiple of 8"
         )
 
+    if hk != h or window is not None:
+        (qf,) = _fold_args(b, h, d, q)
+        kf, vf = _fold_args(b, hk, d, k, v)
+        out, _ = _fwd(qf, kf, vf, jnp.asarray(q_offset, jnp.int32),
+                      jnp.asarray(k_offset, jnp.int32), scale=float(scale),
+                      causal=bool(causal), block_q=bq, block_k=bk,
+                      interpret=bool(interpret),
+                      static_delta=_static_delta(causal, q_offset, k_offset),
+                      window=None if window is None else int(window))
+        return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     qf, kf, vf = _fold_args(b, h, d, q, k, v)
     out = _flash(qf, kf, vf,
                  jnp.asarray(q_offset, jnp.int32),

@@ -13,6 +13,7 @@ from chainermn_tpu.parallel.fsdp import (
     jit_fsdp_train_step,
 )
 from chainermn_tpu.parallel.moe import (
+    DroplessMoE,
     ExpertParallelMLP,
     GShardMoE,
     MoeStatsAccumulator,
@@ -45,6 +46,7 @@ __all__ = [
     "make_mesh",
     "make_hierarchical_mesh",
     "make_3d_mesh",
+    "DroplessMoE",
     "ExpertParallelMLP",
     "GShardMoE",
     "MoeStatsAccumulator",

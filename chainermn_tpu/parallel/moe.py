@@ -373,5 +373,132 @@ class MoeStatsAccumulator:
         }
 
 
-__all__ = ["ExpertParallelMLP", "GShardMoE", "MoeStatsAccumulator",
-           "drop_frac_from_sown"]
+# Tokens the dropless layer routes at once: a prefill of 32k tokens is
+# dispatched in passes of this many, so that the sorted copies of the
+# activations (top_k rows a token) stay a few hundred MB.
+_TOKEN_PASS = 8192
+
+
+def _tile(n: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most ``cap``;
+    ``n`` itself where there is none (a whole dimension is always legal)."""
+    best = n
+    for t in range(128, min(n, cap) + 1, 128):
+        if n % t == 0:
+            best = t
+    return best if best <= cap else n
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
+    """``lhs[rows of group g] @ rhs[g]`` for every group: ``lhs [m, k]``
+    sorted by group, ``rhs [groups, k, n]``, ``group_sizes [groups]`` int32.
+    Rows past the groups' total come back undefined. The Pallas grouped
+    product that ships with JAX (megablox ``gmm``), interpreted off the TPU.
+    The row tile follows the load: 512 rows where the mean group fills one
+    (a prefill: compute-bound), 128 where groups are a dozen rows (a decode
+    step: each visited tile costs a whole tile of MXU work and the step is
+    bound by the experts' bytes)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from chainermn_tpu.ops.flash_attention import kernels_interpreted
+
+    m, k = lhs.shape
+    groups, _, n = rhs.shape
+    tm = 512 if m >= groups * 512 else 128
+    tm = min(tm, -(-m // 8) * 8)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype,
+              tiling=(tm, _tile(k, 1280), _tile(n, 1280)),
+              interpret=kernels_interpreted())
+    return out[:m] if pad else out
+
+
+class DroplessMoE(nn.Module):
+    """Top-k routing over many small gated experts with no capacity and no
+    drop: every token's ``top_k`` assignments are computed, however uneven
+    the routing. Every expert is held here: there is no exchange, so the
+    layer runs on one chip.
+
+    ``x [..., d]`` goes through the experts, ``router_in`` (default ``x``)
+    is what the router reads. Routing weights are the softmax over the
+    ``top_k`` selected logits. An expert is ``(act(x @ w_gate) * (x @
+    w_up)) @ w_down`` without biases.
+
+    One code path for a prefill of thousands of tokens and a decode step
+    of a hundred: assignments are sorted by expert, rows gathered in that
+    order, three grouped products (:func:`grouped_matmul`) over the
+    experts, and the rows gathered back and summed with their weights.
+    Under ``jax.named_scope`` the device operations read ``moe/route``
+    (router, top-k, sort, gather), ``moe/experts`` (the products) and
+    ``moe/combine``.
+
+    Stands beside :class:`ExpertParallelMLP` and :class:`GShardMoE`, which
+    drop at a capacity and route top-1/2."""
+
+    n_experts: int
+    d_model: int
+    d_ff: int
+    top_k: int
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, router_in=None):
+        if not 0 < self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts}")
+        dt = self.compute_dtype
+        d, f, k, n = self.d_model, self.d_ff, self.top_k, self.n_experts
+        init = nn.initializers.normal(d ** -0.5)
+        w_router = self.param("router", init, (d, n))
+        w_gate = self.param("w_gate", init, (n, d, f))
+        w_up = self.param("w_up", init, (n, d, f))
+        w_down = self.param("w_down", nn.initializers.normal(f ** -0.5),
+                            (n, f, d))
+        lead = x.shape[:-1]
+        x = x.reshape(-1, d).astype(dt)
+        r_in = x if router_in is None else router_in.reshape(-1, d)
+
+        def one_pass(x, r_in):
+            t = x.shape[0]
+            with jax.named_scope("route"):
+                # the router is 64 columns: float32 at full precision costs
+                # nothing and keeps near-ties where the reference has them
+                logits = jnp.dot(r_in.astype(jnp.float32),
+                                 w_router.astype(jnp.float32),
+                                 precision=lax.Precision.HIGHEST)
+                top, idx = lax.top_k(logits, k)                  # [t, k]
+                w = jax.nn.softmax(top, axis=-1)
+                expert = idx.reshape(-1)                         # [t * k]
+                order = jnp.argsort(expert, stable=True)
+                sizes = jnp.sum(
+                    expert[:, None] == jnp.arange(n)[None, :], axis=0,
+                    dtype=jnp.int32)
+                # (indices are a permutation's: no bounds to fill for)
+                rows = x.at[order // k].get(mode="promise_in_bounds")
+            with jax.named_scope("experts"):
+                g = grouped_matmul(rows, w_gate.astype(dt), sizes, dt)
+                u = grouped_matmul(rows, w_up.astype(dt), sizes, dt)
+                h = (nn.relu(g) * u).astype(dt)
+                y = grouped_matmul(h, w_down.astype(dt), sizes, dt)
+            with jax.named_scope("combine"):
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(t * k, dtype=order.dtype))
+                y = y.at[back].get(mode="promise_in_bounds",
+                                   unique_indices=True).reshape(t, k, d)
+                out = jnp.einsum("tk,tkd->td", w, y.astype(jnp.float32))
+            return out.astype(dt)
+
+        t = x.shape[0]
+        if t > _TOKEN_PASS and t % _TOKEN_PASS == 0:
+            out = lax.map(
+                lambda xr: one_pass(*xr),
+                (x.reshape(-1, _TOKEN_PASS, d),
+                 r_in.reshape(-1, _TOKEN_PASS, d))).reshape(t, d)
+        else:
+            out = one_pass(x, r_in)
+        return out.reshape(lead + (d,))
+
+
+__all__ = ["DroplessMoE", "ExpertParallelMLP", "GShardMoE",
+           "MoeStatsAccumulator", "drop_frac_from_sown", "grouped_matmul"]

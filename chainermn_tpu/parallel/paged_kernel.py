@@ -151,9 +151,10 @@ def _pv(p, vb, out_dtype):
     return jnp.sum(pv, axis=0)
 
 
-def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+def _sweep_kernel(table_ref, len_ref, *rest,
                   scale: float, bs: int, n_j: int, n_heads: int,
-                  pack: int, chunk: int, quant: bool):
+                  pack: int, chunk: int, quant: bool, group: int = 1,
+                  windowed: bool = False):
     """One program per slot ``b``: it walks the slot's live blocks in
     chunks of ``chunk`` table entries, copying each chunk's blocks from the
     store (left in HBM) into one of two VMEM buffers while it computes on
@@ -179,20 +180,36 @@ def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     keeps one in ``H``: the MXU does ``H`` times the useful work, the price
     of moving a block's bytes once for all heads. Where a head is narrower
     than the 128 lanes, ``pack`` of them share a store row (see
-    :func:`paged_attend`) and a column stands for ``pack`` heads."""
+    :func:`paged_attend`) and a column stands for ``pack`` heads.
+
+    ``group`` query heads read one KV head (``n_heads`` counts the query's;
+    the store holds ``n_heads // group``): the rows of a group multiply the
+    same K columns, and the head mask keeps one column head in
+    ``n_heads // group``. ``windowed`` adds a scalar-prefetch operand,
+    ``first`` (a slot's first visible position), and the table row becomes
+    a ring: the walk starts at block ``first // bs``, block ``j`` sits in
+    entry ``j % n_j``, and positions before ``first`` are masked like those
+    after the query."""
+    if windowed:
+        first_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if quant:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, par = rest
     else:
         o_ref, k_buf, v_buf, sem, par = rest
     sh, d = q_ref.shape[1], q_ref.shape[2]                 # S * H, D
     kvh = k_buf.shape[2]                                   # bs * H / pack
-    s_len, hp = sh // n_heads, n_heads // pack
+    s_len, hp = sh // n_heads, n_heads // group // pack
     b = pl.program_id(0)
     nxt = jnp.minimum(b + 1, pl.num_programs(0) - 1)
     length = len_ref[b]
 
+    def first_block(slot_b):
+        return first_ref[slot_b] // bs if windowed else 0
+
     def live_blocks(slot_b):
-        return jnp.minimum(pl.cdiv(len_ref[slot_b], bs), n_j)
+        return jnp.minimum(
+            pl.cdiv(len_ref[slot_b], bs) - first_block(slot_b), n_j)
 
     n_chunks = pl.cdiv(live_blocks(b), chunk)
     # the slot after this one has a first chunk to send for
@@ -209,7 +226,10 @@ def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         last = live_blocks(slot_b) - 1
 
         def block(c, carry):
-            blk = table_ref[slot_b, jnp.minimum(i * chunk + c, last)]
+            entry = jnp.minimum(i * chunk + c, last)
+            if windowed:
+                entry = jax.lax.rem(first_block(slot_b) + entry, n_j)
+            blk = table_ref[slot_b, entry]
             for src, dst in pairs:
                 act(pltpu.make_async_copy(src.at[blk], dst.at[buf, c],
                                           sem.at[buf]))
@@ -236,8 +256,10 @@ def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         ri = jax.lax.broadcasted_iota(jnp.int32, (1, sh, 1), 1)
         ci = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kvh), 2)
         ji = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
-        same_head = ri % n_heads // pack == ci % hp        # [1, S*H, kvh]
+        same_head = ri % n_heads // (group * pack) == ci % hp
         k_tok = ji * bs + ci // hp                         # [C, 1, kvh]
+        if windowed:
+            k_tok = k_tok + first_block(b) * bs
         q_tok = (length - s_len) + ri // n_heads           # [1, S*H, 1]
 
         def scales(ref, buf):
@@ -276,7 +298,10 @@ def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             # the softmax scale rides on the scale row where there is
             # one: a row, not the tile's S*H
             s = s * (scales(ks_buf, buf) * scale if quant else scale)
-            keep = same_head & (i * (chunk * bs) + k_tok <= q_tok)
+            k_pos = i * (chunk * bs) + k_tok
+            keep = same_head & (k_pos <= q_tok)
+            if windowed:
+                keep = keep & (k_pos >= first_ref[b])
             s = jnp.where(keep, s, _NEG_BIG)
             m_new = jnp.maximum(
                 m, jnp.max(jnp.max(s, axis=0), axis=-1, keepdims=True))
@@ -308,16 +333,18 @@ def _sweep_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def paged_attend(q, store_k, store_v, table, lengths, *,
                  k_scale=None, v_scale=None, scale: Optional[float] = None,
-                 max_blocks: Optional[int] = None,
+                 max_blocks: Optional[int] = None, first=None,
                  interpret: Optional[bool] = None):
     """Paged-attention decode over the shared block store, fused.
 
     - ``q``: ``[B, S, H, D]`` queries for global positions
       ``lengths[b]-S .. lengths[b]-1`` of each row (``S`` is 1 for
       per-token decode, ``k+1`` for the speculative verify window);
-    - ``store_k``/``store_v``: ``[n_blocks, bs, H, D]`` — the shared
+    - ``store_k``/``store_v``: ``[n_blocks, bs, Hkv, D]`` — the shared
       store, already holding this step's writes (the scatter stays XLA:
-      it moves ``S`` rows; the kernel owns the O(length) read side);
+      it moves ``S`` rows; the kernel owns the O(length) read side).
+      ``Hkv`` divides ``H``: query head ``g`` reads KV head
+      ``g // (H // Hkv)``;
     - ``table``: ``[B, max_blocks]`` int32 block table;
     - ``lengths``: ``[B]`` int32 — valid KV rows per row AFTER the
       write (``pos + S``). Only the ``ceil(lengths[b]/bs)`` blocks they
@@ -327,7 +354,13 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
       the store is int8 (dequant folds into the contractions);
     - ``max_blocks``: optional static cap on the table entries a row can
       have live (callers with static positions pass the batch-max active
-      count); it bounds the sweep's trip count and nothing else.
+      count); it bounds the sweep's trip count and nothing else;
+    - ``first``: ``[B]`` int32, a window layer's rows: the first position
+      row ``b`` sees (positions before it are masked and their blocks not
+      walked). The table row is then a ring: the block of positions
+      ``[j*bs, (j+1)*bs)`` sits in entry ``j % max_blocks`` (the table's
+      width), and the caller keeps ``lengths - first`` within the ring
+      less a block.
 
     Returns ``[B, S, H, D]`` in ``q.dtype`` — position-masked exactly
     like :func:`~chainermn_tpu.parallel.sequence.cached_attention` over
@@ -340,14 +373,20 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = kernels_interpreted()
+    if first is not None:
+        first = jnp.asarray(first, jnp.int32)
+        if n_j != table.shape[1]:
+            raise ValueError("a ring goes round the whole table row: "
+                             "max_blocks cannot cut it")
     return _attend(q, store_k, store_v, jnp.asarray(table, jnp.int32),
-                   jnp.asarray(lengths, jnp.int32), k_scale, v_scale,
+                   jnp.asarray(lengths, jnp.int32), k_scale, v_scale, first,
                    scale=float(scale), n_j=n_j, interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "n_j", "interpret"),
                    inline=True)
-def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
+def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
+            first=None, *,
             scale: float, n_j: int, interpret: bool):
     """:func:`paged_attend` with its defaults filled in. A model calls it
     once a layer with the same shapes: ``jit`` traces the kernel for the
@@ -356,8 +395,12 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
     the caller's own names, so no call stands between a block and its
     kernel."""
     b, s_len, h, d = q.shape
-    n_blocks, bs = store_k.shape[:2]
+    n_blocks, bs, hk = store_k.shape[:3]
+    if h % hk:
+        raise ValueError(f"{h} query heads do not divide over {hk} KV heads")
+    group = h // hk
     quant = k_scale is not None
+    windowed = first is not None
 
     def lanes(x):
         """``x`` zero-padded to whole tiles of 128 lanes: Mosaic copies
@@ -376,8 +419,11 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
     # lanes is padded, at the price of a copy a call: nothing at the served
     # widths (heads of 128, bs*H of 256), the small scale rows of a store
     # sharded down to a few heads, a toy head size that does not divide 128.
-    pack = _LANE // d if _LANE % d == 0 and h % (_LANE // d) == 0 else 1
-    sh, rows, dl = s_len * h, bs * h // pack, pack * d
+    # (grouped heads keep a row each: a row shared by KV heads would mix
+    # the lanes of query heads that read different ones)
+    pack = (_LANE // d if group == 1 and _LANE % d == 0
+            and h % (_LANE // d) == 0 else 1)
+    sh, rows, dl = s_len * h, bs * hk // pack, pack * d
     qf = q.reshape(b, sh, 1, d)
     if pack > 1:
         own = (jnp.arange(sh) % pack)[:, None] == jnp.arange(pack)
@@ -389,11 +435,11 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
     dp = operands[0].shape[-1]
     # from the table's width, not from ``max_blocks``: the cap bounds the
     # trip count and leaves the chunking, so the summation order, alone
-    chunk = chunk_blocks(bs, h // pack, dp, store_k.dtype, quant,
+    chunk = chunk_blocks(bs, hk // pack, dp, store_k.dtype, quant,
                          table.shape[1])
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    qo_spec = pl.BlockSpec((1, sh, dp),
-                           lambda b_, table_ref, len_ref: (b_, 0, 0))
+    qo_spec = pl.BlockSpec((1, sh, dp), lambda b_, *scalars: (b_, 0, 0))
+    scalars = (table, lengths) + ((first,) if windowed else ())
     in_specs = [qo_spec, in_hbm, in_hbm]
     scratch = [pltpu.VMEM((2, chunk, rows, dp), store_k.dtype),
                pltpu.VMEM((2, chunk, rows, dp), store_v.dtype)]
@@ -404,12 +450,13 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
         scratch += [pltpu.VMEM((2, chunk) + operands[-1].shape[1:],
                                jnp.float32)] * 2
     scratch += [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
-    vma = _out_vma(q, store_k, store_v, table, lengths)
+    vma = _out_vma(q, store_k, store_v, *scalars)
     out = pl.pallas_call(
         functools.partial(_sweep_kernel, scale=scale, bs=bs, n_j=n_j,
-                          n_heads=h, pack=pack, chunk=chunk, quant=quant),
+                          n_heads=h, pack=pack, chunk=chunk, quant=quant,
+                          group=group, windowed=windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b,),
             in_specs=in_specs,
             out_specs=qo_spec,
@@ -421,7 +468,7 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale, *,
             # first (one TensorCore a chip on the v5e, so nothing is lost)
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(table, lengths, *operands)
+    )(*scalars, *operands)
     out = out[..., :dl]
     if pack > 1:
         out = jnp.sum(out.reshape(b, sh, pack, d) * own, axis=2)

@@ -897,6 +897,14 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
     the PV product) — the dequantized f32 dense view is never
     materialized, read bytes stay int8-sized.
 
+    A ``'window'`` entry (a static int) marks a window layer: the table
+    row is then a ring (the block of positions ``[j*bs, (j+1)*bs)`` sits
+    in entry ``j % max_blocks``, see :func:`paged_write_kv`) and a query at
+    position ``t`` sees ``t - window < j <= t``. A store with fewer heads
+    than ``q`` holds grouped KV heads (query head ``g`` reads KV head
+    ``g // (H // Hkv)``). Both go through :func:`_paged_gather_attention`
+    or, with ``'use_kernel'``, the kernel's ``first``.
+
     A truthy ``'use_kernel'`` entry routes the read side through the
     fused Pallas kernel (:func:`chainermn_tpu.parallel.paged_kernel.
     paged_attend`): one program per row walks that row's
@@ -912,53 +920,39 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
     recompile. Returns ``(out, new_cache)`` where ``new_cache`` carries
     the updated store (and scales) WITHOUT the table: the table is
     host-managed state threaded in per call."""
-    store_k, store_v = kv_cache["k"], kv_cache["v"]
     table = kv_cache["table"]
     quant = "k_scale" in kv_cache
-    bs = store_k.shape[1]
+    bs = kv_cache["k"].shape[1]
     b, s = q.shape[0], q.shape[1]
     if jnp.ndim(pos_offset) == 0:
         pos_offset = jnp.full((b,), pos_offset, jnp.int32)
-    pos = pos_offset[:, None] + jnp.arange(s)[None, :]        # [B, S]
-    blk = jnp.take_along_axis(table, pos // bs, axis=1).reshape(-1)
-    off = (pos % bs).reshape(-1)
-    valid = kv_cache.get("valid")
-    if valid is not None:
-        # redirect rows past each sequence's valid count into the scratch
-        # block so a clamped table lookup can never clobber a live row
-        rv = (jnp.arange(s)[None, :] < valid[:, None]).reshape(-1)
-        blk = jnp.where(rv, blk, 0)
-        off = jnp.where(rv, off, 0)
-
-    def write(store, scales, rows):
-        rows = rows.reshape((b * s,) + rows.shape[2:])        # [B*S, H, D]
-        if not quant:
-            return store.at[blk, off].set(rows.astype(store.dtype)), None
-        r32 = rows.astype(jnp.float32)
-        # symmetric per-row-per-head scale; the epsilon keeps all-zero
-        # rows (warmup, padding) from dividing by zero
-        sc = jnp.maximum(jnp.max(jnp.abs(r32), axis=-1) / 127.0, 1e-8)
-        q8 = jnp.clip(jnp.round(r32 / sc[..., None]), -127, 127)
-        return (store.at[blk, off].set(q8.astype(jnp.int8)),
-                scales.at[blk, off].set(sc))
-
-    new_k, new_ks = write(store_k, kv_cache.get("k_scale"), k)
-    new_v, new_vs = write(store_v, kv_cache.get("v_scale"), v)
+    new_cache = paged_write_kv(kv_cache, k, v, pos_offset)
+    new_k, new_v = new_cache["k"], new_cache["v"]
+    new_ks, new_vs = new_cache.get("k_scale"), new_cache.get("v_scale")
 
     lengths = kv_cache.get("lengths")
     if lengths is None:
         lengths = pos_offset + s                              # post-write
     m_used = table.shape[1]
-    if not isinstance(lengths, jax.core.Tracer):
+    window = kv_cache.get("window")
+    if window is None and not isinstance(lengths, jax.core.Tracer):
         # concrete positions: tighten the span to the batch-max active
         # block count — the masked tail is provably never read
         m_used = max(1, min(m_used, -(-int(jnp.max(lengths)) // bs)))
 
     if kv_cache.get("use_kernel"):
         from chainermn_tpu.parallel.paged_kernel import paged_attend
+        # a window layer's rows start at their first visible position and
+        # go round the whole table row; any other's are cut at ``m_used``
+        where = ({"max_blocks": m_used} if window is None else
+                 {"first": jnp.maximum(lengths - s + 1 - window, 0)})
         out = paged_attend(q, new_k, new_v, table, lengths,
                            k_scale=new_ks, v_scale=new_vs, scale=scale,
-                           max_blocks=m_used)
+                           **where)
+    elif window is not None or q.shape[2] != new_k.shape[2]:
+        out = _paged_gather_attention(
+            q, new_k, new_ks, new_v, new_vs, table, pos_offset,
+            window=window, scale=scale)
     else:
         flat = table[:, :m_used].reshape(-1)                  # [B*m]
 
@@ -977,11 +971,117 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
                                             pos_offset, scale=scale)
         else:
             out = cached_attention(q, kbuf, vbuf, pos_offset, scale=scale)
+    return out, new_cache
+
+
+def paged_write_kv(kv_cache, k, v, pos_offset):
+    """The write side of :func:`paged_update_cache_and_attend` alone: the
+    ``S`` new K/V rows of each batch row scattered through its table into
+    the store (quantized where the store is int8), ``{'k', 'v'[,
+    'k_scale', 'v_scale']}`` handed back without the table. A prefill
+    that attends its own fresh K/V (a flash kernel over the prompt) calls
+    this for the store and nothing else.
+
+    ``kv_cache['valid']`` (``[B]``) sends the rows past each sequence's
+    count to the scratch block. ``kv_cache['window']`` (a window layer)
+    makes the table row a ring: the block of positions ``[j*bs, (j+1)*bs)``
+    sits in entry ``j % max_blocks``; with ``valid`` beside it only the
+    last ``max_blocks`` blocks of the ``valid[b]`` rows are written, the
+    others go to scratch: two blocks of one call that share an entry would
+    leave it to the scatter's order which of them stays."""
+    store_k, store_v = kv_cache["k"], kv_cache["v"]
+    table = kv_cache["table"]
+    quant = "k_scale" in kv_cache
+    bs = store_k.shape[1]
+    b, s = k.shape[0], k.shape[1]
+    if jnp.ndim(pos_offset) == 0:
+        pos_offset = jnp.full((b,), pos_offset, jnp.int32)
+    pos = pos_offset[:, None] + jnp.arange(s)[None, :]        # [B, S]
+    ring = table.shape[1] if "window" in kv_cache else None
+    entry = pos // bs if ring is None else (pos // bs) % ring
+    blk = jnp.take_along_axis(table, entry, axis=1).reshape(-1)
+    off = (pos % bs).reshape(-1)
+    valid = kv_cache.get("valid")
+    if valid is not None:
+        # redirect rows past each sequence's valid count into the scratch
+        # block so a clamped table lookup can never clobber a live row
+        rv = jnp.arange(s)[None, :] < valid[:, None]
+        if ring is not None:
+            newest = (pos_offset + valid - 1) // bs
+            rv = rv & (pos // bs > newest[:, None] - ring)
+        rv = rv.reshape(-1)
+        blk = jnp.where(rv, blk, 0)
+        off = jnp.where(rv, off, 0)
+
+    def write(store, scales, rows):
+        rows = rows.reshape((b * s,) + rows.shape[2:])        # [B*S, H, D]
+        if not quant:
+            return store.at[blk, off].set(rows.astype(store.dtype)), None
+        r32 = rows.astype(jnp.float32)
+        # symmetric per-row-per-head scale; the epsilon keeps all-zero
+        # rows (warmup, padding) from dividing by zero
+        sc = jnp.maximum(jnp.max(jnp.abs(r32), axis=-1) / 127.0, 1e-8)
+        q8 = jnp.clip(jnp.round(r32 / sc[..., None]), -127, 127)
+        return (store.at[blk, off].set(q8.astype(jnp.int8)),
+                scales.at[blk, off].set(sc))
+
+    new_k, new_ks = write(store_k, kv_cache.get("k_scale"), k)
+    new_v, new_vs = write(store_v, kv_cache.get("v_scale"), v)
     new_cache = {"k": new_k, "v": new_v}
     if quant:
         new_cache["k_scale"] = new_ks
         new_cache["v_scale"] = new_vs
-    return out, new_cache
+    return new_cache
+
+
+def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
+                            pos_offset, *, window=None,
+                            scale: Optional[float] = None):
+    """The XLA read path for what the plain one does not cover: grouped KV
+    heads (``q [B, S, H, D]`` over a store of ``Hkv`` heads, query head
+    ``g`` reading ``g // (H // Hkv)``) and a window layer's rows, whose
+    blocks go round the table row and which see the ``window`` positions
+    up to their own. Every table entry is gathered; an entry's
+    block is the newest one that maps to it at the query's position. The
+    reference the kernel is tested against, and the path an engine without
+    the kernel serves from: it forms ``[B, Hkv, G, S, entries * bs]``
+    scores."""
+    b, s, h, d = q.shape
+    bs, hk = store_k.shape[1], store_k.shape[2]
+    g = h // hk
+    if scale is None:
+        scale = d ** -0.5
+    n = table.shape[1]
+    flat = table.reshape(-1)
+
+    def gather(x):
+        rows = jnp.take(x, flat, axis=0)
+        return rows.reshape((b, n * bs) + rows.shape[2:])
+
+    kbuf, vbuf = gather(store_k), gather(store_v)
+    qg = q.reshape(b, s, hk, g, d)
+    sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kbuf.astype(q.dtype),
+                    preferred_element_type=jnp.float32) * scale
+    if k_scale is not None:
+        sc = sc * jnp.moveaxis(gather(k_scale), 2, 1)[:, :, None, None, :]
+    q_pos = pos_offset[:, None] + jnp.arange(s)[None, :]      # [B, S]
+    entry = (jnp.arange(n * bs) // bs)[None, None, :]         # [1, 1, K]
+    row = (jnp.arange(n * bs) % bs)[None, None, :]
+    cur = (q_pos // bs)[:, :, None]                           # [B, S, 1]
+    if window is None:
+        k_pos = entry * bs + row
+        mask = k_pos <= q_pos[:, :, None]
+    else:
+        k_pos = (cur - (cur - entry) % n) * bs + row
+        mask = ((k_pos >= 0) & (k_pos <= q_pos[:, :, None])
+                & (k_pos > q_pos[:, :, None] - window))
+    sc = jnp.where(mask[:, None, None], sc, _NEG_BIG)
+    p = jax.nn.softmax(sc, axis=-1)
+    if v_scale is not None:
+        p = p * jnp.moveaxis(gather(v_scale), 2, 1)[:, :, None, None, :]
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, vbuf.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, h, d).astype(q.dtype)
 
 
 def update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,

@@ -170,6 +170,110 @@ class ChunkedPrefill:
         return self.chunks[self.next_idx][0]
 
 
+class _KVKind:
+    """Host-side block state of one kind of KV cache (an entry of the
+    model's ``kv_cache_spec()``): its pool, the per-slot tables into its
+    store, and the growth reserved for each slot. A kind that keeps every
+    token holds ``ceil(tokens / block_size)`` blocks a slot; a window kind
+    at most the window and one block, its table row a ring: the block of
+    positions ``[j*bs, (j+1)*bs)`` sits in table entry ``j % width``, the
+    block a request writes next takes the place of one that has left its
+    window, and nothing has to be freed while it runs."""
+
+    def __init__(self, kind, n_blocks: Optional[int], block_size: int,
+                 n_slots: int, cache_len: int) -> None:
+        self.kind = kind
+        self.name = kind.name
+        self.window = kind.window
+        self.bs = block_size
+        # tokens a slot can have resident, and the table entries for them
+        # (the last block may straddle the span: its tail stays masked)
+        self.span = cache_len if kind.window is None else min(
+            cache_len, kind.window + block_size)
+        self.width = -(-self.span // block_size)
+        self.n_blocks = int(n_blocks if n_blocks is not None
+                            else n_slots * self.width + 1)
+        self.pool = BlockPool(self.n_blocks, reserve_scratch=True)
+        # the trie allocates from the pool (evicting idle prefixes first);
+        # a window kind's stays empty and is its allocator only
+        self.index = PrefixCacheIndex(self.n_blocks, block_size,
+                                      pool=self.pool)
+        self.tables = np.zeros((n_slots, self.width), np.int32)
+        self.slot_blocks: list[list[int]] = [[] for _ in range(n_slots)]
+        # slots holding each block, and how many blocks have a holder:
+        # the ``blocks_live`` gauge, kept where a slot gains and drops
+        # blocks so that reading it walks nothing
+        self.holders = [0] * self.n_blocks
+        self.live = 0
+        # worst-case growth blocks each active slot may still append
+        # (admission reserves them; append_block draws them down) — what
+        # makes block-budget admission preemption-free in the no-fault case
+        self.reserved = np.zeros((n_slots,), np.int64)
+
+    def blocks_for(self, tokens: int) -> int:
+        """Blocks a slot holds once its sequence is ``tokens`` long."""
+        return -(-min(tokens, self.span) // self.bs)
+
+    def entry(self, block: int) -> int:
+        """Table entry of the block of positions ``[block*bs, ...)``."""
+        return block if self.window is None else block % self.width
+
+    def takes(self, slot: int, blocks) -> None:
+        """The slot's table gains ``blocks`` (admission, import, append)."""
+        self.slot_blocks[slot].extend(blocks)
+        for block in blocks:
+            if self.holders[block] == 0:
+                self.live += 1
+            self.holders[block] += 1
+
+    def drops(self, slot: int, blocks=None) -> None:
+        """The slot's table gives ``blocks`` up (rollback), or all it
+        holds (release)."""
+        if blocks is None:
+            blocks, self.slot_blocks[slot] = self.slot_blocks[slot], []
+        else:
+            for block in blocks:
+                self.slot_blocks[slot].remove(block)
+        for block in blocks:
+            self.holders[block] -= 1
+            if self.holders[block] == 0:
+                self.live -= 1
+
+    def free_slot(self, slot: int) -> None:
+        """Give the slot's block references back: exclusively-owned blocks
+        free immediately, trie-shared ones stay resident for the next hit
+        (the store, not the slot, owns cached prefixes)."""
+        for block in self.slot_blocks[slot]:
+            self.pool.decref(block)
+        self.drops(slot)
+        self.reserved[slot] = 0
+        self.tables[slot, :] = 0
+
+    def reset(self) -> None:
+        self.index.clear()
+        self.pool.reset()
+        self.tables[:] = 0
+        self.slot_blocks = [[] for _ in range(len(self.slot_blocks))]
+        self.holders = [0] * self.n_blocks
+        self.live = 0
+        self.reserved[:] = 0
+
+    def admittable(self) -> int:
+        return (self.pool.free_blocks + self.index.evictable_blocks()
+                - int(self.reserved.sum()))
+
+    def stats(self) -> dict:
+        return {
+            "kv_blocks": self.n_blocks,
+            "layers": len(self.kind.layers),
+            "window": self.window,
+            "blocks_in_use": self.pool.used_blocks,
+            "blocks_live": self.live,
+            "blocks_free": self.pool.free_blocks,
+            "blocks_reserved": int(self.reserved.sum()),
+        }
+
+
 class EngineStateError(RuntimeError):
     """A device-program failure left the engine's donated buffers in an
     unknown state — containment is impossible; the scheduler must fail all
@@ -233,6 +337,11 @@ class ServingEngine:
         capacity; set smaller to oversubscribe slots against the real
         (short-request) working set — block-budget admission plus
         preemption keep it safe.
+    kv_window_blocks : int, optional
+        Paged mode, models with window layers only: total blocks of the
+        window layers' store (their own pool, scratch block included).
+        Default ``n_slots * ceil((window + kv_block_size) /
+        kv_block_size) + 1``: every slot's whole ring.
     kv_block_size : int
         Paged mode: tokens per block. Smaller blocks waste fewer rows on
         ragged tails but widen the tables. Default 16.
@@ -288,6 +397,7 @@ class ServingEngine:
                  prefix_min_insert_blocks: int = 1,
                  paged: bool = False,
                  kv_blocks: Optional[int] = None,
+                 kv_window_blocks: Optional[int] = None,
                  kv_block_size: int = 16,
                  kv_quant: str = "none",
                  paged_kernel: bool = False,
@@ -301,11 +411,33 @@ class ServingEngine:
                 "serving decode does not support sequence-sharded models: "
                 "rebuild with sequence_axis=None for inference"
             )
-        if model.moe_experts and model.moe_impl != "gshard":
+        if getattr(model, "moe_experts", 0) and model.moe_impl != "gshard":
             raise ValueError(
                 "serving decode supports MoE only via moe_impl='gshard' — "
                 "rebuild the model with moe_impl='gshard' (same params)"
             )
+        # what KV state the model keeps, by layer kind; what follows holds
+        # for one kind that keeps every token, and a model with window
+        # layers is refused the options that are not built for it yet
+        spec = model.kv_cache_spec()
+        self._windowed = any(k.window is not None for k in spec)
+        if self._windowed:
+            for bad, what in (
+                    (prefix_cache_blocks, "prefix reuse across requests "
+                     "(prefix_cache_blocks): the trie indexes one pool"),
+                    (not paged, "paged=False: window layers live in a "
+                     "block store of their own"),
+                    (speculative is not None, "speculative decoding: a "
+                     "verify window continues a sequence at an offset"),
+                    (decode_window != 1, "decode_window > 1"),
+                    (model.tensor_axis is not None, "tensor_axis")):
+                if bad:
+                    raise ValueError(
+                        "not supported for a model with window layers: "
+                        + what)
+        elif kv_window_blocks is not None:
+            raise ValueError("kv_window_blocks sizes the window layers' "
+                             "pool and this model has none")
         if model.tensor_axis is not None and comm is None:
             raise ValueError(
                 "tensor-parallel serving needs comm= (the decode programs "
@@ -457,30 +589,22 @@ class ServingEngine:
                 raise ValueError(
                     f"kv_block_size must be >= 1, got {kv_block_size}")
             self.kv_block_size = int(kv_block_size)
-            # table width: blocks covering a full-length slot (the last
-            # block may straddle cache_len — its tail rows stay masked)
-            self._n_max = -(-self.cache_len // self.kv_block_size)
-            if kv_blocks is None:
-                kv_blocks = self.n_slots * self._n_max + 1
-            self.kv_blocks = int(kv_blocks)
-            self._pool = BlockPool(self.kv_blocks, reserve_scratch=True)
-            self.prefix_cache = PrefixCacheIndex(
-                self.kv_blocks, self.kv_block_size, pool=self._pool)
+            # a pool, tables and a budget per kind of KV state; the first
+            # kind's are what the single-kind paths below (chunked
+            # prefill, migration, speculation) know as _pool, _tables, ...
+            self._kv = [
+                _KVKind(kind, kv_blocks if kind.window is None
+                        else kv_window_blocks, self.kv_block_size,
+                        self.n_slots, self.cache_len)
+                for kind in spec]
+            self.kv_blocks = self._kv[0].n_blocks
+            # prefix reuse runs on the one pool of a model whose layers
+            # are all of a kind; with window layers nothing is inserted
+            # or matched
+            if len(self._kv) == 1:
+                self.prefix_cache = self._kv[0].index
             self._min_insert = max(1, int(prefix_min_insert_blocks))
             self._n_prog_blocks = self._n_max   # match cap for planning
-            self._tables = np.zeros((self.n_slots, self._n_max), np.int32)
-            self._slot_blocks: list[list[int]] = [
-                [] for _ in range(self.n_slots)]
-            # slots holding each block, and how many blocks have a holder:
-            # the ``blocks_live`` gauge, kept where a slot gains and drops
-            # blocks so that reading it walks nothing
-            self._block_holders = [0] * self.kv_blocks
-            self._blocks_live = 0
-            # worst-case growth blocks each active slot may still append
-            # (admission reserves them; append_block draws them down) —
-            # what makes block-budget admission preemption-free in the
-            # no-fault case
-            self._slot_reserved = np.zeros((self.n_slots,), np.int64)
             # multi-token rounds write up to _write_horizon rows past the
             # commit frontier (a verify window's k drafts, or a decode
             # window's n-1 extra steps); admission reserves the matching
@@ -625,13 +749,22 @@ class ServingEngine:
     def prefix_enabled(self) -> bool:
         return self.prefix_cache is not None
 
+    # the first kind's block state under the names the single-kind paths
+    # (and the tests) know
+    _pool = property(lambda self: self._kv[0].pool)
+    _tables = property(lambda self: self._kv[0].tables)
+    _slot_blocks = property(lambda self: self._kv[0].slot_blocks)
+    _slot_reserved = property(lambda self: self._kv[0].reserved)
+    _n_max = property(lambda self: self._kv[0].width)
+
     @property
     def migration_supported(self) -> bool:
         """KV block migration needs the paged store AND a single-device
         layout: under TP the rows live head-sharded across the mesh and
         the host-bounce gather/scatter pair is not built (documented
         limitation — export raises, the router decodes in place)."""
-        return self.paged and self.model.tensor_axis is None
+        return (self.paged and self.model.tensor_axis is None
+                and not self._windowed)
 
     # ------------------------------------------------------------------ #
     # program construction                                                #
@@ -681,12 +814,10 @@ class ServingEngine:
                         sc[kk] = jnp.concatenate(
                             [rows, sc[kk][:, span:]], axis=1)
             pos = starts[:, None] + jnp.arange(bucket)[None, :]
-            logits, slot_c = model.apply(params, tokens, pos,
-                                         kv_caches=slot_c)
-            # each row's logits at its last PROMPT token, not a padded row
-            lg = jax.vmap(
-                lambda row, i: lax.dynamic_slice_in_dim(row, i, 1, 0)[0]
-            )(logits, last_idx)
+            # each row's logits at its last PROMPT token, not a padded
+            # row: only that position goes through the head
+            lg, slot_c = model.apply(params, tokens, pos, kv_caches=slot_c,
+                                     logits_at=last_idx)
             if vocab_gather is not None:
                 lg = vocab_gather(lg)
             nxt, keys = jax.vmap(slot_sample)(lg, keys)
@@ -756,13 +887,15 @@ class ServingEngine:
         def body(params, store, table, tokens, starts, last_idx, active,
                  keys):
             with annotate("chainermn.prefill"):
-                caches = [dict(layer, table=table) for layer in store]
+                # a window layer writes a row's real tokens only, the
+                # last ring of them (padding would wrap onto live blocks)
+                valid = (jnp.where(active, last_idx + 1, 0)
+                         if self._windowed else None)
+                caches = self._layer_caches(store, table, valid=valid)
                 pos = starts[:, None] + jnp.arange(bucket)[None, :]
-                logits, new_store = model.apply(params, tokens, pos,
-                                                kv_caches=caches)
-                lg = jax.vmap(
-                    lambda row, i: lax.dynamic_slice_in_dim(row, i, 1, 0)[0]
-                )(logits, last_idx)
+                lg, new_store = model.apply(
+                    params, tokens, pos, kv_caches=caches,
+                    logits_at=last_idx)
                 if vocab_gather is not None:
                     lg = vocab_gather(lg)
                 nxt, keys = jax.vmap(slot_sample)(lg, keys)
@@ -789,8 +922,7 @@ class ServingEngine:
 
         def body(params, store, table, tokens, pos, active, keys):
             with annotate("chainermn.decode"):
-                caches = [dict(layer, table=table, **extra)
-                          for layer in store]
+                caches = self._layer_caches(store, table, **extra)
                 lg, new_store = model.apply(params, tokens[:, None],
                                             pos[:, None], kv_caches=caches)
                 lg = lg[:, 0]
@@ -820,8 +952,8 @@ class ServingEngine:
 
         def body(params, store, table, tokens, pos, valid, active):
             with annotate("chainermn.spec_verify"):
-                caches = [dict(layer, table=table, valid=valid, **extra)
-                          for layer in store]
+                caches = self._layer_caches(store, table, valid=valid,
+                                            **extra)
                 posm = pos[:, None] + jnp.arange(window)[None, :]
                 lg, new_store = model.apply(params, tokens, posm,
                                             kv_caches=caches)
@@ -857,9 +989,8 @@ class ServingEngine:
                     store, tok, keys, out = carry
                     p = pos + i
                     valid = (active & (p < cache_len)).astype(jnp.int32)
-                    caches = [dict(layer, table=table, valid=valid,
-                                   **extra)
-                              for layer in store]
+                    caches = self._layer_caches(store, table, valid=valid,
+                                                **extra)
                     lg, store = model.apply(params, tok[:, None],
                                             p[:, None], kv_caches=caches)
                     lg = lg[:, 0]
@@ -909,10 +1040,38 @@ class ServingEngine:
         return body
 
     def _init_paged_store(self, local_heads: Optional[int] = None):
-        return init_paged_kv_caches(self.model, self.kv_blocks,
-                                    self.kv_block_size,
-                                    local_heads=local_heads,
-                                    quant=self.kv_quant)
+        return init_paged_kv_caches(
+            self.model, tuple(kv.n_blocks for kv in self._kv),
+            self.kv_block_size, local_heads=local_heads,
+            quant=self.kv_quant)
+
+    def _layer_caches(self, store, tables, valid=None, **extra):
+        """Inside a program: the cache dict of every layer, its store
+        beside its kind's table. ``tables`` is what :meth:`_table_args`
+        builds, one dict a kind; ``valid`` goes to every layer where the program caps a
+        row's writes for all of them, and otherwise (a prefill) to window
+        layers alone; ``extra`` are static entries for all layers."""
+        caches = [None] * len(store)
+        for kv, ops in zip(self._kv, tables):
+            static = dict(extra)
+            if kv.window is not None:
+                static["window"] = kv.window
+            if valid is not None and (kv.window is not None
+                                      or not self._windowed):
+                static["valid"] = valid
+            for i in kv.kind.layers:
+                caches[i] = dict(store[i], **ops, **static)
+        return caches
+
+    def _table_args(self, rows: Optional[int] = None) -> tuple:
+        """The per-kind table operand of a program: the decode step's
+        (every slot's row), or all-scratch tables of ``rows`` rows for a
+        prefill to fill in."""
+        out = []
+        for kv in self._kv:
+            out.append({"table": jnp.asarray(kv.tables) if rows is None
+                        else np.zeros((rows, kv.width), np.int32)})
+        return tuple(out)
 
     def _insert_body(self):
         """Prefix insert: copy each NEW full block's rows out of the donor
@@ -1242,12 +1401,13 @@ class ServingEngine:
             )
         if self.paged:
             need = self.blocks_needed(prompt_len, max_new_tokens)
-            if need > self._pool.capacity:
-                raise ValueError(
-                    f"request needs {need} KV blocks worst-case but the "
-                    f"pool holds {self._pool.capacity} — raise kv_blocks "
-                    "or shrink the request"
-                )
+            for kv, n in zip(self._kv, need):
+                if n > kv.pool.capacity:
+                    raise ValueError(
+                        f"request needs {n} KV blocks worst-case but the "
+                        f"{kv.name} pool holds {kv.pool.capacity} — raise "
+                        "kv_blocks or shrink the request"
+                    )
 
     def warmup(self) -> None:
         """Compile every device program once, on dummy no-op inputs (all
@@ -1265,7 +1425,7 @@ class ServingEngine:
         if self.paged:
             # all-scratch tables: every warmup write lands in the scratch
             # block, no allocation and no real KV touched
-            tab = jnp.zeros((k, self._n_max), jnp.int32)
+            tab = self._table_args(rows=k)
             for b in self.prefill_buckets:
                 with self._watched(f"serving warmup prefill[{b}]"):
                     self._store, _, _ = self._prefill_fns[b](
@@ -1298,8 +1458,7 @@ class ServingEngine:
                 # the program's shapes never depend on it)
                 with self._watched("serving warmup spec_verify"):
                     self._store, _ = self._spec_fn(
-                        self.params, self._store,
-                        jnp.asarray(self._tables),
+                        self.params, self._store, self._table_args(),
                         jnp.zeros((self.n_slots, self._spec.k + 1),
                                   jnp.int32),
                         jnp.asarray(self._pos),
@@ -1464,34 +1623,53 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
 
     def _paged_alloc_slot(self, plan: AdmitPlan, slot: int) -> list:
-        """Allocate the blocks a plan's prefill writes into ([start,
-        len(prompt)) — shared prefix blocks are referenced, not copied),
-        write the slot's table mirror, and reserve the worst-case decode
-        growth. Raises ``RuntimeError`` when the pool (plus trie
-        eviction) cannot cover it — the scheduler's block-budget gate
-        makes that unreachable in the no-fault case."""
+        """Allocate, in every kind's pool, the blocks a plan's prefill
+        writes into ([start, len(prompt)) — shared prefix blocks are
+        referenced, not copied; a window kind takes the last ring of the
+        prompt's blocks), write the slot's table mirrors, and reserve the
+        worst-case decode growth. Returns the ids per kind. Raises
+        ``RuntimeError`` when a pool (plus trie eviction) cannot cover it
+        — the scheduler's block-budget gate makes that unreachable in the
+        no-fault case — with nothing left allocated."""
         bs = self.kv_block_size
         plen = len(plan.prompt)
+        n_prompt = -(-plen // bs)
         shared = list(plan.match.block_ids) if plan.match is not None else []
-        need_now = -(-plen // bs) - len(shared)
-        new = self.prefix_cache.alloc_blocks(need_now)
-        if len(new) < need_now:
-            for block in new:
-                self._pool.decref(block)
-            raise RuntimeError(
-                f"kv block pool exhausted: slot {slot} needs {need_now} "
-                f"blocks, {len(new)} allocatable (free="
-                f"{self._pool.free_blocks})"
-            )
-        for block in shared:
-            self._pool.incref(block)    # the slot co-owns its prefix
-        ids = shared + new
-        self._tables[slot, :] = 0
-        self._tables[slot, : len(ids)] = ids
-        self._slot_reserved[slot] = (
-            -(-(plen + plan.max_new) // bs) - (-(-plen // bs))
-            + self._spec_headroom)
-        return ids
+        got: list[list] = []
+        try:
+            for kv in self._kv:
+                most = kv.blocks_for(plen + plan.max_new)
+                held = min(n_prompt, most)
+                need_now = held - len(shared)
+                new = kv.index.alloc_blocks(need_now)
+                if len(new) < need_now:
+                    for block in new:
+                        kv.pool.decref(block)
+                    raise RuntimeError(
+                        f"kv block pool exhausted: slot {slot} needs "
+                        f"{need_now} {kv.name} blocks, {len(new)} "
+                        f"allocatable (free={kv.pool.free_blocks})"
+                    )
+                for block in shared:
+                    kv.pool.incref(block)    # the slot co-owns its prefix
+                ids = shared + new
+                kv.tables[slot, :] = 0
+                for j, block in enumerate(ids, n_prompt - held):
+                    kv.tables[slot, kv.entry(j)] = block
+                kv.reserved[slot] = most - held + self._spec_headroom
+                got.append(ids)
+        except Exception:
+            self._paged_unalloc_slot(slot, got)
+            raise
+        return got
+
+    def _paged_unalloc_slot(self, slot: int, ids_by_kind: list) -> None:
+        """Undo :meth:`_paged_alloc_slot`: nothing was admitted."""
+        for kv, ids in zip(self._kv, ids_by_kind):
+            for block in ids:
+                kv.pool.decref(block)
+            kv.reserved[slot] = 0
+            kv.tables[slot, :] = 0
 
     # graftlint: hot — the paged-path body of admit_batch
     def _paged_admit(self, plans: Sequence[AdmitPlan], *, point: str,
@@ -1523,12 +1701,13 @@ class ServingEngine:
                     starts = np.zeros((k,), np.int32)
                     last = np.zeros((k,), np.int32)
                     active = np.zeros((k,), bool)
-                    table = np.zeros((k, self._n_max), np.int32)
+                    table = self._table_args(rows=k)
                     keys = [jnp.zeros((2,), jnp.uint32)] * k
                     for i, (plan, slot) in enumerate(zip(plans, slots)):
                         ids = self._paged_alloc_slot(plan, slot)
                         alloc_records.append((slot, ids))
-                        table[i, : len(ids)] = ids
+                        for kv, ops in zip(self._kv, table):
+                            ops["table"][i] = kv.tables[slot]
                         suffix = plan.prompt[plan.start:]
                         tokens[i, : len(suffix)] = suffix
                         starts[i] = plan.start
@@ -1536,17 +1715,14 @@ class ServingEngine:
                         active[i] = True
                         keys[i] = plan.rng
                     self._store, firsts, keys_out = self._prefill_fns[bucket](
-                        self.params, self._store, jnp.asarray(table),
+                        self.params, self._store, table,
                         jnp.asarray(tokens), jnp.asarray(starts),
                         jnp.asarray(last), jnp.asarray(active),
                         jnp.stack(keys))
                     firsts = device_fetch(firsts)
             except Exception as e:
                 for slot, ids in alloc_records:   # undo: nothing admitted
-                    for block in ids:
-                        self._pool.decref(block)
-                    self._slot_reserved[slot] = 0
-                    self._tables[slot, :] = 0
+                    self._paged_unalloc_slot(slot, ids)
                 if not self._state_ok():
                     raise EngineStateError(
                         f"admission failed mid-device-call "
@@ -1565,20 +1741,22 @@ class ServingEngine:
             self._pos[slot] = len(plan.prompt)
             self._active[slot] = True
             self._keys = self._keys.at[slot].set(keys_out[len(out)])
-            self._slot_takes(slot, ids)
+            for kv, kind_ids in zip(self._kv, ids):
+                kv.takes(slot, kind_ids)
             self._c_prefills[bucket].inc()
             self._events.emit("prefill", slot=slot,
                               prompt_len=len(plan.prompt), bucket=bucket,
                               cached=plan.start, batch=len(plans),
-                              blocks=len(ids))
+                              blocks=sum(len(i) for i in ids))
             out.append((slot, first))
             if self._drafter is not None:
                 self._drafter.on_admit(slot, plan.prompt, first)
             # zero-copy trie insert: the slot's blocks already hold the
             # prompt's KV — adopting them IS the cache insert
-            if (self.prefix_cache.missing_blocks(plan.prompt)
+            if (self.prefix_cache is not None
+                    and self.prefix_cache.missing_blocks(plan.prompt)
                     >= self._min_insert):
-                self.prefix_cache.insert_shared(plan.prompt, ids)
+                self.prefix_cache.insert_shared(plan.prompt, ids[0])
         self.peak_active = max(self.peak_active, self.active_slots)
         self._guard.check()
         return out
@@ -1598,8 +1776,8 @@ class ServingEngine:
         ``cache_len`` (``bucket_for``'s ``start + b <= cache_len``
         constraint; an out-of-range bucket would clamp table lookups onto
         live blocks). ``None`` means: admit unchunked."""
-        if not self.paged:
-            return None
+        if not self.paged or self._windowed:
+            return None     # a window layer's prefill takes whole prompts
         chunk_tokens = int(chunk_tokens)
         if chunk_tokens < 1:
             return None
@@ -1705,7 +1883,7 @@ class ServingEngine:
                 if final:
                     keys[0] = st.rng
                 self._store, nxt, keys_out = self._prefill_fns[bucket](
-                    self.params, self._store, jnp.asarray(table),
+                    self.params, self._store, ({"table": table},),
                     jnp.asarray(tokens), jnp.asarray(starts),
                     jnp.asarray(last), jnp.asarray(active),
                     jnp.stack(keys))
@@ -1819,6 +1997,12 @@ class ServingEngine:
                 self._store = self._kv_scatter_fns[1](
                     self._store, jnp.asarray(one), rows, jnp.int32(1))
 
+    def _refuse_migration(self) -> None:
+        if self._windowed:
+            raise ValueError(
+                "not supported for a model with window layers: KV "
+                "migration (a payload carries one store's blocks)")
+
     def export_slot_kv(self, slot: int,
                        ctx: Optional[dict] = None, *,
                        fused: bool = True) -> dict:
@@ -1835,6 +2019,7 @@ class ServingEngine:
         continue the request token-exactly via :meth:`import_slot_kv`.
         Read-only: the slot stays active here; the caller releases it
         only after the import commits."""
+        self._refuse_migration()
         if not self.migration_supported:
             raise RuntimeError(
                 "KV migration needs paged=True on a single-device engine "
@@ -1888,7 +2073,7 @@ class ServingEngine:
         bs = self.kv_block_size
         need = (n + max(0, -(-(pos + int(max_new)) // bs) - n)
                 + self._spec_headroom)
-        return need <= self.kv_blocks_admittable()
+        return bool((need <= self.kv_blocks_admittable()).all())
 
     def import_slot_kv(self, payload: dict, *,
                        prompt: Optional[np.ndarray] = None,
@@ -1906,6 +2091,7 @@ class ServingEngine:
         becomes ground truth here, not router belief). Returns the slot.
         Raises ``RuntimeError`` (layout/budget) with the engine intact —
         the caller's fallback is decoding in place at the source."""
+        self._refuse_migration()
         if not self.migration_supported:
             raise RuntimeError(
                 "KV migration needs paged=True on a single-device engine")
@@ -2036,7 +2222,7 @@ class ServingEngine:
                 return False
         if static_only:
             return True
-        return self._warm and n <= self.kv_blocks_admittable()
+        return self._warm and bool((n <= self.kv_blocks_admittable()).all())
 
     def import_prefix_kv(self, payload: dict,
                          ctx: Optional[dict] = None) -> int:
@@ -2091,29 +2277,29 @@ class ServingEngine:
         return adopted
 
     def blocks_needed(self, prompt_len: int, max_new: int,
-                      start: int = 0) -> int:
-        """Worst-case NEW blocks a request admits with: blocks covering
-        ``[start, prompt_len + max_new)`` (``start`` = cached-prefix
-        tokens, whose blocks are shared, not allocated). The scheduler's
+                      start: int = 0) -> np.ndarray:
+        """Worst-case NEW blocks a request admits with, one count per kind
+        of KV state: blocks covering ``[start, prompt_len + max_new)``
+        (``start`` = cached-prefix tokens, whose blocks are shared, not
+        allocated), a window kind's capped at its ring. The scheduler's
         block-budget admission compares this against
-        :meth:`kv_blocks_admittable`. Multi-token rounds add
+        :meth:`kv_blocks_admittable`, kind by kind. Multi-token rounds add
         ``ceil(write_horizon / block_size)`` headroom: a verify window
         writes up to ``k`` draft rows past the commit frontier, and those
         writes must never find the pool dry mid-round."""
         bs = self.kv_block_size
-        return (-(-(prompt_len + max_new) // bs) - start // bs
-                + self._spec_headroom)
+        return np.array(
+            [kv.blocks_for(prompt_len + max_new) - start // bs
+             + self._spec_headroom for kv in self._kv], np.int64)
 
-    def kv_blocks_admittable(self) -> int:
-        """Blocks an admission may claim without ever starving a decode:
-        free pool blocks, plus trie blocks eviction could reclaim, minus
-        the growth already reserved by active slots."""
-        return (self._pool.free_blocks
-                + self.prefix_cache.evictable_blocks()
-                - int(self._slot_reserved.sum()))
+    def kv_blocks_admittable(self) -> np.ndarray:
+        """Blocks an admission may claim without ever starving a decode,
+        per kind: free pool blocks, plus trie blocks eviction could
+        reclaim, minus the growth already reserved by active slots."""
+        return np.array([kv.admittable() for kv in self._kv], np.int64)
 
     def _horizon_block_range(self, slot: int) -> range:
-        """Table indices the slot's next round may write: blocks covering
+        """Blocks the slot's next round may write: those covering
         ``[pos, pos + write_horizon]`` clipped to ``cache_len``. Horizon
         0 (the legacy per-token path) is exactly the next write's block."""
         bs = self.kv_block_size
@@ -2126,64 +2312,56 @@ class ServingEngine:
     def slot_needs_block(self, slot: int) -> bool:
         """True when a write inside the slot's next decode round crosses
         into a block it has not allocated yet (a table entry in the
-        horizon span still points at scratch). Multi-token rounds
-        (speculative window / decode_window) widen the span checked."""
+        horizon span still points at scratch), in any kind's table.
+        Multi-token rounds (speculative window / decode_window) widen the
+        span checked."""
         if not self.paged or not self._active[slot]:
             return False
-        return any(self._tables[slot, i] == 0
-                   for i in self._horizon_block_range(slot))
+        blocks = self._horizon_block_range(slot)
+        return any(kv.tables[slot, kv.entry(j)] == 0
+                   for kv in self._kv for j in blocks)
 
     def append_block(self, slot: int) -> bool:
         """Lazily allocate the slot's next block (evicting idle trie
         prefixes if the free list is dry) — the FIRST unallocated entry
-        in the next round's write span. Returns False when the pool is
-        truly exhausted — the scheduler then preempts the lowest-priority
-        request and retries. Carries the ``serving.kv_append`` fault
-        cut-point: an injected failure here is contained by preempting
-        ONLY this slot (no engine restart)."""
+        in the next round's write span, in each kind's table that has
+        one. Returns False when a pool is truly exhausted — the scheduler
+        then preempts the lowest-priority request and retries. Carries
+        the ``serving.kv_append`` fault cut-point: an injected failure
+        here is contained by preempting ONLY this slot (no engine
+        restart)."""
         inject(SERVING_KV_APPEND, slot=slot, pos=int(self._pos[slot]))
-        idx = next((i for i in self._horizon_block_range(slot)
-                    if self._tables[slot, i] == 0), None)
-        if idx is None:
-            return True   # span fully allocated — nothing to do
-        got = self.prefix_cache.alloc_blocks(1)
-        if not got:
-            return False
-        block = got[0]
-        self._tables[slot, idx] = block
-        self._slot_takes(slot, [block])
-        if self._slot_reserved[slot] > 0:
-            self._slot_reserved[slot] -= 1
-        self._c_appends.inc()
-        self._events.emit("kv_append", slot=slot, block=block,
-                          pos=int(self._pos[slot]))
+        blocks = self._horizon_block_range(slot)
+        for kv in self._kv:
+            idx = next((kv.entry(j) for j in blocks
+                        if kv.tables[slot, kv.entry(j)] == 0), None)
+            if idx is None:
+                continue      # span fully allocated — nothing to do
+            got = kv.index.alloc_blocks(1)
+            if not got:
+                return False
+            block = got[0]
+            kv.tables[slot, idx] = block
+            kv.takes(slot, [block])
+            if kv.reserved[slot] > 0:
+                kv.reserved[slot] -= 1
+            self._c_appends.inc()
+            self._events.emit("kv_append", slot=slot, block=block,
+                              pos=int(self._pos[slot]))
         return True
 
     def _slot_takes(self, slot: int, blocks) -> None:
-        """The slot's table gains ``blocks`` (admission, import, append)."""
-        self._slot_blocks[slot].extend(blocks)
-        for block in blocks:
-            if self._block_holders[block] == 0:
-                self._blocks_live += 1
-            self._block_holders[block] += 1
+        self._kv[0].takes(slot, blocks)
 
     def _slot_drops(self, slot: int, blocks=None) -> None:
-        """The slot's table gives ``blocks`` up (rollback), or all it
-        holds (release)."""
-        if blocks is None:
-            blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
-        else:
-            for block in blocks:
-                self._slot_blocks[slot].remove(block)
-        for block in blocks:
-            self._block_holders[block] -= 1
-            if self._block_holders[block] == 0:
-                self._blocks_live -= 1
+        self._kv[0].drops(slot, blocks)
 
     def slot_block_count(self, slot: int) -> int:
         """Blocks the slot's table currently references (0 in dense
         mode) — the per-request block-count series at retirement."""
-        return len(self._slot_blocks[slot]) if self.paged else 0
+        if not self.paged:
+            return 0
+        return sum(len(kv.slot_blocks[slot]) for kv in self._kv)
 
     def slot_block_shares(self, slot: int) -> float:
         """Refcount-weighted block count the slot holds RIGHT NOW (0.0
@@ -2193,8 +2371,12 @@ class ServingEngine:
         ledger integrates it into per-tenant KV block-seconds."""
         if not self.paged:
             return 0.0
-        return sum(1.0 / max(self._pool.refs(b), 1)
-                   for b in self._slot_blocks[slot])
+        if self.prefix_cache is None:
+            # blocks are shared through the trie alone: without one every
+            # block has the one holder, and counting them walks nothing
+            return float(sum(len(kv.slot_blocks[slot]) for kv in self._kv))
+        return sum(1.0 / max(kv.pool.refs(b), 1)
+                   for kv in self._kv for b in kv.slot_blocks[slot])
 
     def kv_pool_stats(self) -> tuple[int, int, int]:
         """(blocks in use, blocks free, blocks live) — the scheduler
@@ -2204,25 +2386,30 @@ class ServingEngine:
         keeps until evicted (so it reads near the pool's size on a busy
         server whatever is live). Live = blocks some slot's table
         references, a running count."""
-        return (self._pool.used_blocks, self._pool.free_blocks,
-                self._blocks_live)
+        return (sum(kv.pool.used_blocks for kv in self._kv),
+                sum(kv.pool.free_blocks for kv in self._kv),
+                sum(kv.live for kv in self._kv))
 
     def kv_stats(self) -> dict:
         """Paged-store occupancy/config block for bench records (empty
         dict in dense mode)."""
         if not self.paged:
             return {}
+        first = self._kv[0].stats()
         return {
             "kv_blocks": self.kv_blocks,
             "kv_block_size": self.kv_block_size,
             "kv_quant": self.kv_quant,
-            # off the free list, trie-held (evictable) prompts included
-            "blocks_in_use": self._pool.used_blocks,
-            # referenced by some live slot's table
-            "blocks_live": self._blocks_live,
-            "blocks_free": self._pool.free_blocks,
-            "blocks_reserved": int(self._slot_reserved.sum()),
+            # of the first kind's pool, as before there were kinds: off
+            # the free list, trie-held (evictable) prompts included;
+            "blocks_in_use": first["blocks_in_use"],
+            # referenced by some live slot's table;
+            "blocks_live": first["blocks_live"],
+            "blocks_free": first["blocks_free"],
+            "blocks_reserved": first["blocks_reserved"],
             "peak_active": self.peak_active,
+            # and the same of every kind's, by the spec's names
+            "kinds": {kv.name: kv.stats() for kv in self._kv},
         }
 
     def flush_inserts(self) -> None:
@@ -2306,7 +2493,7 @@ class ServingEngine:
         and the host-side slot mirror. Warm-up, every decode call and
         :meth:`decode_program_text` build them here, so they cannot drift
         apart."""
-        kv = ((self._store, jnp.asarray(self._tables)) if self.paged
+        kv = ((self._store, self._table_args()) if self.paged
               else (self.caches,))
         return (self.params, *kv, jnp.asarray(self._token),
                 jnp.asarray(self._pos), jnp.asarray(self._active),
@@ -2421,7 +2608,7 @@ class ServingEngine:
                 annotate("chainermn.serving_spec_verify"):
             inject(SERVING_SPEC_VERIFY, active=int(self._active.sum()), k=k)
             with annotate("chainermn.serving_decode_args"):
-                args = (jnp.asarray(self._tables), jnp.asarray(tokens),
+                args = (self._table_args(), jnp.asarray(tokens),
                         jnp.asarray(self._pos), jnp.asarray(valid),
                         jnp.asarray(self._active))
             self._store, g = self._spec_fn(self.params, self._store, *args)
@@ -2546,11 +2733,8 @@ class ServingEngine:
             # give the slot's block references back: exclusively-owned
             # blocks free immediately, trie-shared ones stay resident for
             # the next hit (the store, not the slot, owns cached prefixes)
-            for block in self._slot_blocks[slot]:
-                self._pool.decref(block)
-            self._slot_drops(slot)
-            self._slot_reserved[slot] = 0
-            self._tables[slot, :] = 0
+            for kv in self._kv:
+                kv.free_slot(slot)
             # a half-prefilled chunked slot releases the same way: its
             # staged ids ARE _slot_blocks, so cancel/preempt/deadline
             # mid-chunk leaks nothing (replay reproduces the tokens from
@@ -2582,20 +2766,16 @@ class ServingEngine:
                 self.model, self.n_slots, self.cache_len))
             if self.prefix_cache is not None:
                 self._store = self._place(self._init_store())
-        if self.prefix_cache is not None:
-            self.prefix_cache.clear()
         if self.paged:
-            # trie dropped above; now drop the slot tables' references and
-            # reset the pool wholesale — a stale table pinning blocks of a
-            # dead store would leak capacity forever (and a stale ENTRY
-            # would read KV that no longer exists)
-            self._pool.reset()
-            self._tables[:] = 0
-            self._slot_blocks = [[] for _ in range(self.n_slots)]
-            self._block_holders = [0] * self.kv_blocks
-            self._blocks_live = 0
-            self._slot_reserved[:] = 0
+            # drop the trie, the slot tables' references and reset the
+            # pool wholesale — a stale table pinning blocks of a dead
+            # store would leak capacity forever (and a stale ENTRY would
+            # read KV that no longer exists)
+            for kv in self._kv:
+                kv.reset()
             self._chunking.clear()
+        elif self.prefix_cache is not None:
+            self.prefix_cache.clear()
         self._pending_inserts = []
         self._token[:] = 0
         self._pos[:] = 0
@@ -2720,8 +2900,9 @@ class ServingEngine:
         trie is live on this engine."""
         active = self.active_slots
         if self.paged:
-            free = self._pool.free_blocks
-            kv_free_frac = free / max(self._pool.capacity, 1)
+            kv_free_frac = min(
+                kv.pool.free_blocks / max(kv.pool.capacity, 1)
+                for kv in self._kv)
         else:
             kv_free_frac = len(self.free_slots) / max(self.n_slots, 1)
         return {

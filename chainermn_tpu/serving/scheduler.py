@@ -890,13 +890,15 @@ class FCFSScheduler:
         # until retirements return blocks (FCFS order preserved)
         budget = None
         if paged:
+            # one count per kind of KV state the model keeps: every
+            # kind's pool has to cover the request
             budget = eng.kv_blocks_admittable()
             need = eng.blocks_needed(len(head.prompt),
                                      head.max_new_tokens, plan.start)
-            if need > budget:
+            if (need > budget).any():
                 self._defer_admission(head, plan, need, budget)
                 return []
-            budget -= need
+            budget = budget - need
         # chunked prefill: a long suffix admits as a staged chunk
         # schedule instead of one monolithic device call — the same
         # block-budget gate above already cleared its worst-case growth.
@@ -934,7 +936,8 @@ class FCFSScheduler:
         for rank, (_, _, req, p) in enumerate(scored):
             need = (eng.blocks_needed(len(req.prompt), req.max_new_tokens,
                                       p.start) if paged else 0)
-            if rank < cap - 1 and (budget is None or need <= budget):
+            if rank < cap - 1 and (budget is None
+                                   or (need <= budget).all()):
                 with self._lock:
                     try:
                         self._queue.remove(req)   # lost a cancel() race?
@@ -945,7 +948,7 @@ class FCFSScheduler:
                 self._span_to_admit(req)
                 group.append((req, p))
                 if budget is not None:
-                    budget -= need
+                    budget = budget - need
             else:
                 eng.cancel_plan(p)
         return group
@@ -991,8 +994,10 @@ class FCFSScheduler:
         with self._lock:
             req.state = RequestState.QUEUED
             self._queue.appendleft(req)
-        self._events.emit("kv_admit_defer", req=req.id, need=need,
-                          available=available, **self._trace_label(req))
+        self._events.emit("kv_admit_defer", req=req.id,
+                          need=[int(n) for n in need],
+                          available=[int(n) for n in available],
+                          **self._trace_label(req))
 
     def _span_to_admit(self, req: Request) -> None:
         """Queue wait is over: close the request's ``queue`` span and open
@@ -1030,8 +1035,11 @@ class FCFSScheduler:
         commit each member. Returns first tokens emitted."""
         reqs = [r for r, _ in group]
         plans = [p for _, p in group]
+        # (a paged engine admits by its plan, which holds the request's
+        # token budget: the blocks reserved for its growth)
         legacy = (len(group) == 1 and plans[0].match is None
-                  and not self.engine.prefix_enabled)
+                  and not self.engine.prefix_enabled
+                  and not getattr(self.engine, "paged", False))
         ctx = {"reqs": [r.id for r in reqs]}
         traces = [r.trace.trace_id for r in reqs if r.trace.enabled]
         if traces:
