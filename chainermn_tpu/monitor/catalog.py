@@ -64,6 +64,8 @@ METRIC_NAMES = frozenset({
     "share_payload_cache_hits_total",
     "prefill_chunks_total",
     "prefill_batch_size",
+    "prefill_rows_filled_total",
+    "prefill_rows_run_total",
     "prefix_cache_evictions_total",
     "prefix_cache_hits_total",
     "prefix_cache_inserted_blocks_total",

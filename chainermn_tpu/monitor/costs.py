@@ -1,9 +1,9 @@
 """Per-request resource attribution and tenant cost accounting.
 
 Every shared mechanism in the serving path deliberately blurs per-request
-cost: a bucketed prefill runs ``prefill_batch`` padded rows for the whole
-group in one dispatch, a decode round advances every slot (idle rows ride
-along masked), speculative verify burns device time on drafts that get
+cost: a bucketed prefill runs all of its bucket's padded rows for the
+whole group in one dispatch, a decode round advances every slot (idle rows
+ride along masked), speculative verify burns device time on drafts that get
 rejected, shared prefix blocks are held by several requests at once, and
 a preemption throws away work that must be replayed. This module is the
 ledger that un-blurs it — splitting each *measured* device interval into

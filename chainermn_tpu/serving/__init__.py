@@ -9,8 +9,10 @@ This package is the traffic-facing counterpart — the ROADMAP's
 - :class:`~chainermn_tpu.serving.engine.ServingEngine` — mechanism: a
   fixed pool of cache slots in one persistent static-shape KV cache, a
   small fixed family of compiled programs (bucketed batched ``prefill``
-  — one program per padded-length bucket admitting up to
-  ``prefill_batch`` requests per call — the all-slots ``decode_step``,
+  — one program per padded-length bucket; ``prefill_batch`` is its rows
+  at the smallest bucket, and a program holds at most ``prefill_batch x
+  prefill_buckets[0]`` tokens, so longer buckets have fewer rows
+  (``prefill_rows``) — the all-slots ``decode_step``,
   and the prefix-copy pair), zero recompiles after :meth:`warmup`,
   tensor-parallel via ``comm.shard_map``;
 - :class:`~chainermn_tpu.serving.prefix_cache.PrefixCacheIndex` — prefix

@@ -7,12 +7,18 @@ request before admitting the next. This engine owns a fixed pool of
 (:func:`~chainermn_tpu.models.transformer.init_kv_caches`-backed) and a
 small fixed family of compiled device programs:
 
-- ``prefill`` (one program per **bucket**): run up to ``prefill_batch``
-  requests' (padded) prompt suffixes through the model in ONE call, each
-  batch row writing K/V into its OWN slot at its OWN start position (the
-  per-row ``[B, T]`` position form of ``TransformerLM.__call__`` over the
-  per-slot ``update_cache_and_attend``) and sampling its first token —
-  admission cost is one batched suffix prefill, amortized over the group;
+- ``prefill`` (one program per **bucket**): run up to
+  ``prefill_rows(bucket)`` requests' (padded) prompt suffixes through the
+  model in ONE call, each batch row writing K/V into its OWN slot at its
+  OWN start position (the per-row ``[B, T]`` position form of
+  ``TransformerLM.__call__`` over the per-slot
+  ``update_cache_and_attend``) and sampling its first token — admission
+  cost is one batched suffix prefill, amortized over the group. A program
+  holds a budget of tokens, not a count of rows: ``prefill_batch`` is the
+  rows at the smallest bucket, and a larger bucket's program has
+  ``prefill_batch * prefill_buckets[0] // bucket`` rows (at least one),
+  so no program runs more than ``prefill_batch x prefill_buckets[0]``
+  padded tokens and a long prompt admitted alone pays for no empty rows;
 - ``decode_step``: advance ALL slots one token per call, each at its OWN
   sequence position; retired/free slots ride along masked by ``jnp.where``
   so shapes never change and nothing recompiles;
@@ -306,9 +312,13 @@ class ServingEngine:
         covering it. Default ``(prefill_len,)``. When both are given,
         ``max(prefill_buckets)`` must equal ``prefill_len``.
     prefill_batch : int
-        Batch dimension of every bucket's prefill program: up to this many
-        requests admit per device call (rows beyond the group ride along
-        masked). Clamped to ``n_slots``. Default 1 (the PR-1 shape).
+        Rows at the smallest bucket: a program holds at most
+        ``prefill_batch x prefill_buckets[0]`` padded tokens, so bucket
+        ``b``'s program has :meth:`prefill_rows` ``= max(1, prefill_batch
+        * prefill_buckets[0] // b)`` rows, and up to that many requests
+        admit per device call (rows beyond the group ride along masked).
+        With one bucket it is that program's batch dimension. Clamped to
+        ``n_slots``. Default 1 (the PR-1 shape).
     prefix_cache_blocks / prefix_block_size : int
         ``prefix_cache_blocks > 0`` enables ref-counted prefix KV reuse: a
         device block store of that many ``prefix_block_size``-token blocks
@@ -490,6 +500,11 @@ class ServingEngine:
         self.prefill_len = int(prefill_len)
         self.prefill_buckets = buckets
         self.prefill_batch = min(int(prefill_batch), self.n_slots)
+        # a program's rows follow from its bucket: the token budget of the
+        # smallest bucket's program, spread over longer rows
+        self._prefill_rows = {
+            b: max(1, self.prefill_batch * buckets[0] // b)
+            for b in buckets}
         self.cache_len = int(cache_len)
         self._comm = comm
         self._sample = _sampler(float(temperature), int(top_k), float(top_p))
@@ -749,6 +764,12 @@ class ServingEngine:
     def prefix_enabled(self) -> bool:
         return self.prefix_cache is not None
 
+    def prefill_rows(self, bucket: int) -> int:
+        """Rows of ``bucket``'s prefill program, the most requests one
+        admission group of that bucket holds: ``prefill_batch`` at the
+        smallest bucket, fewer as the rows get longer."""
+        return self._prefill_rows[bucket]
+
     # the first kind's block state under the names the single-kind paths
     # (and the tests) know
     _pool = property(lambda self: self._kv[0].pool)
@@ -782,7 +803,7 @@ class ServingEngine:
         garbage span they splice sits entirely under rows their own
         prefill overwrites or the causal mask hides."""
         model, sample = self.model, self._sample
-        k = self.prefill_batch
+        k = self.prefill_rows(bucket)
         prefix = self.prefix_cache is not None
         span = self._n_prog_blocks * self.prefix_cache.block_size \
             if prefix else 0
@@ -1420,16 +1441,16 @@ class ServingEngine:
             return
         if self.active_slots:
             raise RuntimeError("warmup needs an idle engine")
-        k = self.prefill_batch
-        zeros_i = jnp.zeros((k,), jnp.int32)
         if self.paged:
             # all-scratch tables: every warmup write lands in the scratch
             # block, no allocation and no real KV touched
-            tab = self._table_args(rows=k)
             for b in self.prefill_buckets:
+                k = self.prefill_rows(b)
+                zeros_i = jnp.zeros((k,), jnp.int32)
                 with self._watched(f"serving warmup prefill[{b}]"):
                     self._store, _, _ = self._prefill_fns[b](
-                        self.params, self._store, tab,
+                        self.params, self._store,
+                        self._table_args(rows=k),
                         jnp.zeros((k, b), jnp.int32), zeros_i, zeros_i,
                         jnp.zeros((k,), bool),
                         jnp.zeros((k, 2), jnp.uint32))
@@ -1466,11 +1487,13 @@ class ServingEngine:
                         jnp.asarray(self._active))
                 self._drafter.warmup()
         else:
-            extra = ()
-            if self.prefix_cache is not None:
-                extra = (self._store,
-                         jnp.zeros((k, self._n_prog_blocks), jnp.int32))
             for b in self.prefill_buckets:
+                k = self.prefill_rows(b)
+                zeros_i = jnp.zeros((k,), jnp.int32)
+                extra = ()
+                if self.prefix_cache is not None:
+                    extra = (self._store, jnp.zeros(
+                        (k, self._n_prog_blocks), jnp.int32))
                 with self._watched(f"serving warmup prefill[{b}]"):
                     self.caches, _, _ = self._prefill_fns[b](
                         self.params, self.caches,
@@ -1489,7 +1512,10 @@ class ServingEngine:
         self._guard.check()
         self._events.emit("serving_warmup",
                           buckets=list(self.prefill_buckets),
-                          prefill_batch=k, paged=self.paged,
+                          prefill_batch=self.prefill_batch,
+                          prefill_rows=[self.prefill_rows(b)
+                                        for b in self.prefill_buckets],
+                          paged=self.paged,
                           prefix=self.prefix_cache is not None)
 
     def prefill(self, prompt: np.ndarray, rng,
@@ -1525,23 +1551,24 @@ class ServingEngine:
         a request; a store-corrupting one resets the prefix cache)."""
         if not plans:
             return []
-        if len(plans) > self.prefill_batch:
-            raise ValueError(
-                f"group of {len(plans)} exceeds prefill_batch="
-                f"{self.prefill_batch}"
-            )
-        if len(plans) > len(self.free_slots):
-            raise RuntimeError("no free slot (scheduler admitted too many)")
         buckets = {p.bucket for p in plans}
         if len(buckets) != 1:
             raise ValueError(
                 f"admission group mixes buckets {sorted(buckets)} — one "
                 "compiled program per call"
             )
+        bucket = plans[0].bucket
+        k = self.prefill_rows(bucket)
+        if len(plans) > k:
+            raise ValueError(
+                f"group of {len(plans)} exceeds the {k} rows of bucket "
+                f"{bucket}'s program (prefill_batch={self.prefill_batch} "
+                f"rows at bucket {self.prefill_buckets[0]})"
+            )
+        if len(plans) > len(self.free_slots):
+            raise RuntimeError("no free slot (scheduler admitted too many)")
         if self.paged:
             return self._paged_admit(plans, point=point, ctx=ctx)
-        bucket = plans[0].bucket
-        k = self.prefill_batch
         if self._pending_inserts:
             self.flush_inserts()   # before slots are picked: never insert
         slots = sorted(self.free_slots)[:len(plans)]  # deterministic pick
@@ -1683,7 +1710,7 @@ class ServingEngine:
         group; one that consumed the donated store re-raises as
         :class:`EngineStateError`."""
         bucket = plans[0].bucket
-        k = self.prefill_batch
+        k = self.prefill_rows(bucket)
         slots = sorted(self.free_slots)[:len(plans)]  # deterministic pick
         n_cached = sum(p.match is not None for p in plans)
         alloc_records: list[tuple[int, list]] = []
@@ -1862,7 +1889,7 @@ class ServingEngine:
         st = self._chunking[slot]
         frontier, clen, bucket = st.chunks[st.next_idx]
         final = st.next_idx == len(st.chunks) - 1
-        k = self.prefill_batch
+        k = self.prefill_rows(bucket)
         try:
             with self._watched("serving chunk_prefill", **(ctx or {})), \
                     annotate("chainermn.serving_chunk_prefill"):

@@ -89,6 +89,11 @@ class ServingMetrics:
         # covered (0.0 on a miss — so the mean IS the amortized discount,
         # and the >0 fraction is the hit rate)
         self._h_batch = reg.histogram("prefill_batch_size", labels)
+        # rows the prefill programs ran and rows that held a request, by
+        # bucket (prefill_rows_run_total, prefill_rows_filled_total): a
+        # bucket's program runs all of its rows whatever the group's size
+        self._labels = labels
+        self._c_rows: dict[int, tuple] = {}
         self._h_cached = reg.histogram("cached_prefix_frac", labels)
         self._g_queue = reg.gauge("serving_queue_depth_now", labels)
         self._g_active = reg.gauge("serving_active_slots", labels)
@@ -167,10 +172,26 @@ class ServingMetrics:
                           **({} if cached_frac is None
                              else {"cached_frac": round(cached_frac, 4)}))
 
-    def record_admission(self, batch_size: int) -> None:
-        """One admission device call admitted ``batch_size`` requests —
-        the batched-prefill occupancy series."""
+    def record_admission(self, batch_size: int, rows_run: int,
+                         bucket: int) -> None:
+        """One admission device call admitted ``batch_size`` requests in
+        ``bucket``'s program of ``rows_run`` rows — the batched-prefill
+        occupancy series."""
         self._h_batch.observe(batch_size)
+        self.record_prefill_rows(batch_size, rows_run, bucket)
+
+    def record_prefill_rows(self, filled: int, run: int,
+                            bucket: int) -> None:
+        """One prefill program of ``bucket`` ran ``run`` rows, ``filled``
+        of them holding a request (an admission group, or one chunk)."""
+        if bucket not in self._c_rows:
+            labels = dict(self._labels, prefill_bucket=str(bucket))
+            self._c_rows[bucket] = (
+                self._registry.counter("prefill_rows_filled_total", labels),
+                self._registry.counter("prefill_rows_run_total", labels))
+        c_filled, c_run = self._c_rows[bucket]
+        c_filled.inc(filled)
+        c_run.inc(run)
 
     def record_token(self, t_prev_token: float, t_token: float) -> None:
         self._h_tpot.observe(t_token - t_prev_token)
@@ -401,6 +422,10 @@ class ServingMetrics:
             t = np.asarray(batch, np.float64)
             out["prefill_batch_size_mean"] = round(float(t.mean()), 3)
             out["prefill_batch_size_max"] = int(t.max())
+        if self._c_rows:
+            filled = sum(f.value for f, _ in self._c_rows.values())
+            run = sum(r.value for _, r in self._c_rows.values())
+            out["prefill_fill_share"] = round(filled / run, 4)
         for hist, prefix in ((self._h_queue, "queue_depth"),
                              (self._h_occ, "slot_occupancy")):
             samples = hist.samples

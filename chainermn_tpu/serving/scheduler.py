@@ -861,13 +861,15 @@ class FCFSScheduler:
         """Pop the next admission group: the queue head anchors it (FCFS —
         no starvation), then companions whose (prefix-discounted) padded
         suffix lands in the SAME bucket join, companions sharing the
-        head's cached prefix first, until the group hits the engine's
-        ``prefill_batch`` or the free-slot count. Returns ``[(req, plan),
+        head's cached prefix first, until the group hits the rows of that
+        bucket's program (``engine.prefill_rows``: ``prefill_batch`` at
+        the smallest bucket, fewer at longer ones) or the free-slot
+        count; a head whose bucket has one row returns before any queued
+        request is planned. Returns ``[(req, plan),
         ...]``; every selected request is moved to PREFILL, every
         unselected candidate's plan is cancelled (match unpinned)."""
         eng = self.engine
         paged = getattr(eng, "paged", False)
-        cap = min(eng.prefill_batch, len(eng.free_slots))
         with self._lock:
             head = self._pop_head_locked()
         if head is None:
@@ -913,6 +915,7 @@ class FCFSScheduler:
                 self._begin_chunked(head, plan, chunks)
                 return []
         group = [(head, plan)]
+        cap = min(eng.prefill_rows(plan.bucket), len(eng.free_slots))
         if cap <= 1:
             return group
         with self._lock:
@@ -1079,18 +1082,21 @@ class FCFSScheduler:
                 raise
             return 0  # engine restarted: keep serving the queue
         t_pre1 = time.perf_counter()
+        rows_run = self.engine.prefill_rows(plans[0].bucket)
         if self.costs is not None:
             # one shared device call, split by token share: the compiled
-            # program always runs the full prefill_batch x bucket grid,
-            # so empty rows and intra-row padding book as `padding`
+            # program always runs all of its bucket's rows (prefill_batch
+            # at the smallest bucket, at most prefill_batch x
+            # prefill_buckets[0] tokens a program), so empty rows and
+            # intra-row padding book as `padding`
             self.costs.record_prefill(
                 t_pre1 - t_pre0, bucket=plans[0].bucket,
-                batch_rows=self.engine.prefill_batch,
+                batch_rows=rows_run,
                 members=[(req.id, req.tenant,
                           len(req.prompt) - plan.start)
                          for req, plan in group])
         emitted = 0
-        self.metrics.record_admission(len(group))
+        self.metrics.record_admission(len(group), rows_run, plans[0].bucket)
         for (req, plan), (slot, first) in zip(group, results):
             now = time.perf_counter()
             # the shared batched device call, attributed to every member
@@ -1264,13 +1270,15 @@ class FCFSScheduler:
                 raise
             return 0
         t1 = time.perf_counter()
+        rows_run = self.engine.prefill_rows(bucket)
+        self.metrics.record_prefill_rows(1, rows_run, bucket)
         if self.costs is not None:
-            # each chunk is one full prefill_batch x bucket device call
-            # with a single occupied row — the empty rows and the
-            # intra-row padding book as `padding`, same as a batch of 1
+            # each chunk is one device call of its bucket's rows (at most
+            # prefill_batch x prefill_buckets[0] tokens) with a single
+            # occupied row — the empty rows and the intra-row padding
+            # book as `padding`, same as a batch of 1
             self.costs.record_prefill(
-                t1 - t0, bucket=bucket,
-                batch_rows=self.engine.prefill_batch,
+                t1 - t0, bucket=bucket, batch_rows=rows_run,
                 members=[(req.id, req.tenant, clen)])
         req.trace.add_span("prefill_chunk", t0, t1, bucket=bucket,
                            chunk=idx, of=total, tokens=clen, slot=slot)

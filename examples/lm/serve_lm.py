@@ -34,8 +34,9 @@ start). See README "Fault tolerance".
 
 And the admission fast path (PR 5): ``--prefill-buckets`` pads prompts to
 a small ladder of bucketed lengths instead of one ``--prefill-len``,
-``--prefill-batch N`` admits up to N same-bucket requests per compiled
-prefill call, and ``--prefix-blocks N`` turns on ref-counted prefix KV
+``--prefill-batch N`` admits up to N smallest-bucket requests per compiled
+prefill call (a longer bucket's program has N x smallest bucket / bucket
+rows), and ``--prefix-blocks N`` turns on ref-counted prefix KV
 reuse — with ``--shared-prefix M`` every burst prompt shares an M-token
 system prompt, so admissions prefill only their ragged tails (the prefix
 stats print at the end: hit rate, evictions, store occupancy).
@@ -194,8 +195,10 @@ def main() -> None:
                          "padding waste for one extra compile per bucket "
                          "(empty: single prefill-len bucket)")
     ap.add_argument("--prefill-batch", type=int, default=1,
-                    help="admit up to this many same-bucket requests per "
-                         "prefill device call (batched admission)")
+                    help="rows of the smallest bucket's prefill program; "
+                         "a program holds at most this x the smallest "
+                         "bucket in tokens, so longer buckets admit fewer "
+                         "requests per device call")
     ap.add_argument("--prefix-blocks", type=int, default=0,
                     help="enable ref-counted prefix KV reuse with this "
                          "many device store blocks: requests sharing a "
