@@ -18,9 +18,8 @@ around them off the critical path, in three pieces:
   serialize + CRC footer + atomic rename + GC on a writer thread.
 
 Wired end to end by :func:`chainermn_tpu.training.fit` and
-``resilience.resilient_fit(async_save=True)``; proven by
-``bench.py --mode pipeline`` (pipelined wall/step ~= max(step, loader)
-instead of step + loader).
+``resilience.resilient_fit(async_save=True)``: a pipelined step costs
+about max(step, loader) of wall time instead of step + loader.
 """
 
 from chainermn_tpu.dataflow.dispatch import LossWindow, device_fetch

@@ -118,8 +118,7 @@ def parse_hlo_collectives(hlo: str) -> dict[str, Any]:
 # Memoized lowered-HLO text per (jitted fn, abstract arg shapes): the AOT
 # ``lower().compile()`` below does not share the jit executable cache, so
 # without this every collective_stats call paid one full extra XLA compile
-# of a function the jit cache had already built (bench.py measured it twice
-# per sweep cell). Keyed by id() but guarded by a weakref identity check so
+# of a function the jit cache had already built. Keyed by id() but guarded by a weakref identity check so
 # a recycled id can never serve another function's HLO.
 _HLO_MEMO_MAX = 64
 _hlo_memo: "dict[tuple, tuple]" = {}
@@ -172,8 +171,8 @@ def collective_stats(fn: Callable, *args, **kwargs) -> dict[str, Any]:
     would count what it memcpy'd): under XLA the program IS the ground
     truth. The AOT ``lower().compile()`` does not share the jit executable
     cache, so the lowered HLO text is memoized per (jitted fn, abstract
-    shapes): repeated calls — bench sweep cells, the monitor — pay the
-    extra XLA compile once, not every time.
+    shapes): repeated calls pay the extra XLA compile once, not every
+    time.
     """
     import jax
 

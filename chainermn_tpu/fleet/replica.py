@@ -15,7 +15,7 @@ being taken inside the scheduler. The supervisor then:
    SAME compiled programs — zero recompiles across the restart) while the
    replica reports ``RESTARTING``;
 3. past ``max_restarts`` — or on a hard :class:`ReplicaKilled` poison
-   (the bench continuity probe) — **quarantines**: the replica stops
+   — **quarantines**: the replica stops
    accepting work and its thread exits; the fleet's capacity shrinks by
    one replica instead of the service dying.
 
@@ -74,8 +74,8 @@ _STATE_CODE = {
 
 class ReplicaKilled(RuntimeError):
     """Hard-kill poison: the replica fails terminally (no restart budget
-    consulted — straight to quarantine). The bench continuity probe and
-    the kill-one-replica tests use this to simulate a dead worker."""
+    consulted — straight to quarantine). The kill-one-replica tests use
+    this to simulate a dead worker."""
 
 
 class ReplicaHang(RuntimeError):

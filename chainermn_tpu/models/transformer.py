@@ -215,8 +215,8 @@ class TransformerLM(nn.Module):
     # (jax.checkpoint via nn.remat): stored-for-backward activations drop
     # from ~12 tensors/block to the block BOUNDARY only, trading ~1/3 more
     # forward FLOPs for O(n_layers * B*T*d) less HBM — the standard TPU
-    # memory lever for long context / large token batches (e.g. the
-    # 220M-param bench model at T=2048 B=32 stores ~18 GB without remat:
+    # memory lever for long context / large token batches (e.g. a
+    # 220M-param model at T=2048 B=32 stores ~18 GB without remat:
     # past a 16 GB v5e chip; with it, well inside). Training only —
     # kv_caches decode has no backward and ignores it.
     remat: bool = False

@@ -10,8 +10,7 @@ four pillars:
   histograms with labels, JSON :func:`snapshot`, Prometheus-style
   :func:`exposition`, and cross-rank :func:`aggregate` (fleet-wide p50/p99
   on rank 0 over the communicator's object transport, merged with the
-  ``latency_report`` field convention so records stay ``BENCH_*.json``-
-  compatible).
+  ``latency_report`` field convention).
 - **Events** (:class:`EventLog`): a bounded ring of structured events
   (step start/end, prefill/decode, slot admit/retire, compile, watchdog
   arm/fire) dumped automatically — last N events + per-device
@@ -50,9 +49,9 @@ four pillars:
   text), ``/traces`` (Chrome JSON), ``/slo``, ``/events``,
   ``/timeseries``, and ``/health``.
 
-The per-step hot-path cost is a few dict/deque operations (<2% step time
-even on millisecond CPU steps — asserted by ``bench.py --mode monitor``);
-everything heavier happens at reporting or failure time.
+The per-step hot-path cost is a few dict/deque operations; everything
+heavier happens at reporting or failure time. What it costs a served decode
+step on the chip is the benchmark's ``host_gap_account_ms.decode``.
 
 Usage::
 
@@ -126,8 +125,7 @@ def emit(kind: str, **fields) -> None:
 
 def snapshot(memory: bool = True) -> dict:
     """JSON-able snapshot of the default registry (refreshing the
-    device-memory gauges first unless ``memory=False``) — the block every
-    ``bench.py`` mode embeds in its record."""
+    device-memory gauges first unless ``memory=False``)."""
     if memory:
         record_memory_gauges(get_registry())
     return get_registry().snapshot()

@@ -1,7 +1,7 @@
 """Canonical metric-name and event-kind catalog.
 
 Metric and event names are wire protocol: dashboards, the ``/metrics``
-scrape endpoint, the SLO engine, and bench baselines all key on them. A
+scrape endpoint and the SLO engine all key on them. A
 typo forks the time series silently. Every literal name passed to
 ``MetricsRegistry.counter/gauge/histogram`` or ``EventLog.emit`` must
 appear here; graftlint's consistency checker fails the build on a name

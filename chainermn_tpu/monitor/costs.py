@@ -27,8 +27,8 @@ per-tenant shares by explicit rules:
 - **migrate** — the host-bounce handover that moves a request's KV
   blocks from a prefill-tier replica to a decode-tier one is device+PCIe
   time spent on exactly one request; the whole measured interval books
-  to its tenant as ``migrate`` (overhead, not goodput — the bench's
-  crossover math weighs it against the decode stalls it deletes).
+  to its tenant as ``migrate`` (overhead, not goodput: to be weighed
+  against the decode stalls the handover deletes).
 - **KV block-seconds** — the integral of blocks held over wall time; a
   shared prefix block held by ``r`` requests contributes ``1/r`` per
   holder (the live refcount split), so the pool's occupancy always sums
@@ -509,8 +509,8 @@ class ShareOfTotal:
 
 class NoisyNeighborDetector(Detector):
     """Edge-triggered detector that NAMES its tenant: wraps either a
-    fixed threshold (``threshold=`` — deterministic, what the bench
-    scenario uses on a share series) or a z-score drift check on the
+    fixed threshold (``threshold=`` — deterministic, for a share
+    series) or a z-score drift check on the
     tenant's device-seconds rate. On the rising edge it emits a
     ``noisy_neighbor`` event carrying ``tenant=`` on top of the base
     class's ``detector_fired``."""
@@ -575,9 +575,9 @@ def standard_tenant_sensors(tenant: str, instance: str, *,
     ``:rate`` series the collector builds automatically.
 
     The detector watches, in order of preference: the device share
-    against ``share_threshold`` (deterministic — the two-tenant bench
-    contract), the useful rate against ``rate_threshold``, or z-score
-    drift of the useful rate (the open-world default).
+    against ``share_threshold`` (deterministic), the useful rate against
+    ``rate_threshold``, or z-score drift of the useful rate (the
+    open-world default).
     """
     tag = tag if tag is not None else f"{tenant}@{instance}"
     dev_rate = tenant_device_key(instance, tenant, "useful") + ":rate"

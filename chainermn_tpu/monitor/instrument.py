@@ -13,7 +13,7 @@ callable with the whole telemetry spine: step start/end events, a step
 counter + step-time histogram in the registry, recompile detection, a
 profiler annotation, and periodic device-memory gauges. Attribute access
 delegates to the wrapped function, so ``.lower()`` / ``._cache_size()``
-callers (bench AOT path, ``collective_stats``) see no difference.
+callers (an AOT compile, ``collective_stats``) see no difference.
 """
 
 from __future__ import annotations

@@ -11,8 +11,7 @@ a JSON-able :meth:`MetricsRegistry.snapshot`, Prometheus-style text
 Histograms keep a bounded reservoir of raw samples and report through the
 same percentile convention as :func:`chainermn_tpu.extensions.profiling.
 latency_report` (``mean/p50/p99``, ``_s``-suffixed for seconds-valued
-series), so registry snapshots stay field-compatible with the
-``BENCH_*.json`` records the earlier rounds accumulated.
+series).
 """
 
 from __future__ import annotations

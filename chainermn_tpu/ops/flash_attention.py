@@ -74,9 +74,10 @@ def _pick_block(t: int, preferred: int = 1024) -> int:
     hardware minimum, so the result can exceed ``preferred``.
 
     The 1024 default is measured, not guessed: the round-5 on-chip sweep
-    (scripts/flash_tune.py -> scripts/flash_tune.jsonl, v5e, bf16 fwd+bwd,
-    causal) is monotonic in block size at both T=4096 and T=8192 —
-    28.3 TFLOP/s at block 1024 vs 18.0 (512) / 6.7 (128) at T=8192.
+    (the record is scripts/flash_tune.jsonl; its script, flash_tune.py, is
+    in git at 28e6de9; v5e, bf16 fwd+bwd, causal) is monotonic in block
+    size at both T=4096 and T=8192 — 28.3 TFLOP/s at block 1024 vs 18.0
+    (512) / 6.7 (128) at T=8192.
     Per-cell fixed work (mask iota, scratch flush, grid bookkeeping)
     amortizes over more MXU work, and VMEM per cell stays O(block) —
     ~3 MB at block 1024, d=64, far under the ~128 MB budget. The
@@ -138,6 +139,21 @@ def _default_block(t: int) -> int:
     not assumed. Kept as a function: the tuning boundary lives in one
     place if on-chip long-T data ever disagrees."""
     return 1024
+
+
+def _pick_blocks(tq: int, tk: int, block_q: Optional[int],
+                 block_k: Optional[int]) -> tuple[int, int]:
+    """The q and k blocks of a call: ``None`` asks for the default, any
+    other request must be a positive block (0 is not "unset")."""
+
+    def pick(name, t, want):
+        if want is None:
+            want = _default_block(t)
+        elif want < 1:
+            raise ValueError(f"{name} must be positive, got {want}")
+        return _pick_block(t, want)
+
+    return pick("block_q", tq, block_q), pick("block_k", tk, block_k)
 
 
 def _out_vma(*xs) -> frozenset:
@@ -669,8 +685,7 @@ def flash_attention(
         scale = d ** -0.5
     if interpret is None:
         interpret = kernels_interpreted()
-    bq = _pick_block(tq, block_q or _default_block(tq))
-    bk = _pick_block(tk, block_k or _default_block(tk))
+    bq, bk = _pick_blocks(tq, tk, block_q, block_k)
     if bq < min(8, tq) or bk < min(8, tk):
         # awkward lengths (no usable divisor): blockwise degenerates below
         # hardware tile minimums — use the XLA path, same semantics
@@ -766,8 +781,7 @@ def flash_fwd_with_lse(q, k, v, *, causal=False, scale=None, q_offset=0,
         scale = d ** -0.5
     if interpret is None:
         interpret = kernels_interpreted()
-    bq = _pick_block(tq, block_q or _default_block(tq))
-    bk = _pick_block(tk, block_k or _default_block(tk))
+    bq, bk = _pick_blocks(tq, tk, block_q, block_k)
     _check_blocks(bq, bk, tq, tk)
     qf, kf, vf = _fold_args(b, h, d, q, k, v)
     out, lse = _fwd(qf, kf, vf,
@@ -797,8 +811,7 @@ def flash_block_grads(q, k, v, do, lse, delta, *, causal=False, scale=None,
         scale = d ** -0.5
     if interpret is None:
         interpret = kernels_interpreted()
-    bq = _pick_block(tq, block_q or _default_block(tq))
-    bk = _pick_block(tk, block_k or _default_block(tk))
+    bq, bk = _pick_blocks(tq, tk, block_q, block_k)
     _check_blocks(bq, bk, tq, tk)
     qf, kf, vf, dof = _fold_args(b, h, d, q, k, v, do)
     lsef = lse.reshape(b * h, tq)

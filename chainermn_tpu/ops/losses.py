@@ -36,13 +36,13 @@ import jax.numpy as jnp
 _DEFAULT_CHUNK = 4096
 
 
-def _pad_to_multiple(x, n, axis=0):
+def _pad_to_multiple(x, n, axis=0, value=0):
     pad = (-x.shape[axis]) % n
     if pad == 0:
         return x, 0
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return jnp.pad(x, widths), pad
+    return jnp.pad(x, widths, constant_values=value), pad
 
 
 def _tile_logits(h_c, kernel, bias):
@@ -92,7 +92,9 @@ def _ce_bwd(chunk, res, g):
     v = kernel.shape[1]
     h_p, _ = _pad_to_multiple(hidden, chunk)
     t_p, _ = _pad_to_multiple(targets, chunk)
-    lse_p, _ = _pad_to_multiple(lse, chunk)
+    # a pad row's logits are the bias alone: with lse = +inf its p is
+    # exactly 0, where lse = 0 gave exp(bias), inf past ~88, and inf * 0
+    lse_p, _ = _pad_to_multiple(lse, chunk, value=jnp.inf)
     # padded tokens carry zero cotangent -> contribute nothing anywhere
     g_p, _ = _pad_to_multiple(g.astype(jnp.float32), chunk)
     n_chunks = h_p.shape[0] // chunk

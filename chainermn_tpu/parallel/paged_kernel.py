@@ -63,7 +63,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -475,38 +474,4 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
     return out.reshape(b, s_len, h, d)
 
 
-def bytes_read_model(lengths, *, block_size: int, max_blocks: int,
-                     n_heads: int, head_dim: int, n_layers: int = 1,
-                     kv_quant: str = "none") -> dict:
-    """Per-decode-step KV bytes-READ model (PERF.md "Paged-decode
-    kernel"): what one step's attention streams from the store, XLA
-    gather path vs fused kernel, summed over rows and layers.
-
-    The XLA path gathers every row's full ``max_blocks`` table span and
-    — when int8 — materializes the dequantized f32 dense view (counted
-    as its write + read back through the attention contractions). The
-    kernel streams ``ceil(len/bs)`` blocks per row in storage dtype and
-    never builds the view. Host-side arithmetic on host values: this is
-    the cost MODEL the bench record carries next to measured tokens/s,
-    not a measurement."""
-    lengths = np.asarray(lengths, np.int64)
-    row_elems = n_heads * head_dim
-    esize = 1 if kv_quant == "int8" else 4
-    kv_rows_xla = int(lengths.size) * max_blocks * block_size
-    kv_rows_kern = int(
-        np.sum(-(-np.maximum(lengths, 0) // block_size)) * block_size)
-    per_row_scale = n_heads * 4 if kv_quant == "int8" else 0
-    # k + v, per layer
-    xla = 2 * kv_rows_xla * (row_elems * esize + per_row_scale)
-    kern = 2 * kv_rows_kern * (row_elems * esize + per_row_scale)
-    if kv_quant == "int8":
-        # the f32 dense view: written once, read back by the einsums
-        xla += 2 * 2 * kv_rows_xla * row_elems * 4
-    return {
-        "xla_bytes": int(xla * n_layers),
-        "kernel_bytes": int(kern * n_layers),
-        "read_amplification": round(xla / max(kern, 1), 3),
-    }
-
-
-__all__ = ["bytes_read_model", "kernel_supported", "paged_attend"]
+__all__ = ["kernel_supported", "paged_attend"]

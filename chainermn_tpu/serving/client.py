@@ -5,7 +5,7 @@ This is the process-local front of the serving stack (engine = mechanism,
 scheduler = policy, client = thread + API). A network front would sit
 where this class sits — the scheduler surface is already
 submission-threaded — but in-process is the tier-1-testable core and what
-``bench.py --mode serving`` and ``examples/lm/serve_lm.py`` drive.
+the benchmark's serving harness and ``examples/lm/serve_lm.py`` drive.
 
 Usage::
 

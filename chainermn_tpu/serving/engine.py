@@ -2418,8 +2418,9 @@ class ServingEngine:
                 sum(kv.live for kv in self._kv))
 
     def kv_stats(self) -> dict:
-        """Paged-store occupancy/config block for bench records (empty
-        dict in dense mode)."""
+        """The paged stores' configuration and occupancy (blocks live,
+        reserved and in use, per layer kind under ``kinds``); an empty
+        dict in dense mode."""
         if not self.paged:
             return {}
         first = self._kv[0].stats()
@@ -2731,8 +2732,8 @@ class ServingEngine:
         return win
 
     def spec_stats(self) -> dict:
-        """Cumulative speculative counters for the bench record (empty
-        dict when speculation is off)."""
+        """Cumulative speculative counters (empty dict when speculation
+        is off)."""
         if self._spec is None:
             return {}
         prop = self._spec_proposed_total
@@ -2872,7 +2873,7 @@ class ServingEngine:
         """Executable counts of the prefill family (summed over buckets)
         and the decode program — the zero-recompile invariant is
         ``{'prefill': len(buckets), 'decode': 1}`` after warmup, asserted
-        by tests and reported by the serving benchmark."""
+        by tests and logged by the benchmark's serving harness."""
         return {
             "prefill": sum(int(fn._cache_size())
                            for fn in self._prefill_fns.values()),
@@ -2916,7 +2917,7 @@ class ServingEngine:
 
     def prefix_stats(self) -> dict:
         """The prefix cache's hit/eviction/occupancy numbers (empty dict
-        when disabled) — embedded in the serving bench record."""
+        when disabled)."""
         return self.prefix_cache.stats() if self.prefix_cache else {}
 
     def occupancy(self) -> dict:

@@ -226,7 +226,7 @@ class PrefixCacheIndex:
         self._c_evictions = reg.counter("prefix_cache_evictions_total")
         self._c_inserted = reg.counter("prefix_cache_inserted_blocks_total")
         # per-instance stats (the registry counters are process-cumulative;
-        # tests and bench want THIS cache's numbers)
+        # ``stats()`` reports THIS cache's numbers)
         self.hits = 0
         self.misses = 0
         self.evictions = 0

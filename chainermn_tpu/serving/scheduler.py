@@ -394,7 +394,7 @@ class FCFSScheduler:
         # per-tenant resource ledger (PR 17): splits every measured
         # device interval across the requests that shared it. Pure
         # host-side dict arithmetic — default ON; ``cost_accounting=
-        # False`` strips even that (the bench's overhead baseline).
+        # False`` strips even that.
         self.costs: Optional[CostLedger] = None
         if cost_accounting:
             self.costs = CostLedger(instance=self.metrics.instance)

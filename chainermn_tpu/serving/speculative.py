@@ -28,8 +28,8 @@ Two drafters ship behind one interface (:class:`SpeculativeConfig`):
   tokens that followed it, falling back to the shared prefix trie
   (:meth:`~chainermn_tpu.serving.prefix_cache.PrefixCacheIndex.
   ngram_continuation`) and finally to repeating the last token. Zero
-  extra weights, zero extra device programs — strongest exactly on the
-  repetitive / shared-system-prompt workloads ``bench.py`` models.
+  extra weights, zero extra device programs — strongest on repetitive
+  and shared-system-prompt workloads.
 - ``'draft'`` — :class:`DraftModelDrafter`, a small ``TransformerLM``
   decoding ``k`` greedy tokens per window against its own dense slot
   caches (two extra compiled programs: one full-prompt prefill, one

@@ -4,8 +4,8 @@ The reference's per-step control flow lives in Chainer's Trainer/Updater
 (SURVEY.md S1: ChainerMN only wraps the optimizer hook, S3.2). In the TPU
 rebuild the equivalent "hot loop contract" is a single jitted SPMD program:
 forward + backward + cross-rank gradient mean + optimizer update + BN-stat
-sync, built here once and reused by bench.py, the examples, and
-``__graft_entry__``.
+sync, built here once and reused by ``chip_smoke.py``, the benchmark's
+training harness, the examples and ``__graft_entry__``.
 """
 
 from __future__ import annotations

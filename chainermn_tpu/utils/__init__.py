@@ -22,9 +22,9 @@ def enable_compilation_cache() -> str:
     sets nothing in code: whoever placed the cache (a machine that keeps one
     between runs) keeps control of it. Otherwise the cache goes to
     ``.jax_cache/`` at the root of the checkout. Call before the first
-    compile; every entry point that compiles for the chip (``chip_smoke.py``,
-    ``bench.py``, the examples, ``scripts/``) calls this and nothing else
-    names a cache directory.
+    compile; ``chip_smoke.py`` and the examples call this, and the benchmark's
+    harness (``benchmarks/harness/common.py``) places the same directory by
+    the same rule. Nothing else names a cache directory.
     """
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:

@@ -278,8 +278,7 @@ def lm_model(sz: Sizes, **kw):
 
 def check_flash_parity(sz: Sizes, on_tpu: bool) -> dict:
     """``flash_attention`` against ``full_attention``, forward and backward,
-    bf16 causal at the model's head shape — the tolerance
-    ``scripts/onchip_flash.py`` holds the compiled kernels to."""
+    bf16 causal at the model's head shape, to bf16's tolerance."""
     import jax
     import jax.numpy as jnp
 

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """AOT FLOPs/bytes accounting for the on-chip LM cells, chip-free.
 
-AOT-compile the EXACT ``jit_lm_train_step`` program for the onchip_lm cell
-shapes against an abstract v5e, with the tracing decisions the chip makes
+AOT-compile the EXACT ``jit_lm_train_step`` program for the LM cell shapes
+below against an abstract v5e, with the tracing decisions the chip makes
 (Mosaic kernels, ``check_vma=True``), and read the compiler's own cost
 accounting and memory analysis.
 
@@ -28,7 +28,7 @@ import time
 _HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(_HERE, "lm_roofline_aot.jsonl")
 
-# (seq_len, batch, attention, remat[, fused_ce]) — the onchip_lm cells plus
+# (seq_len, batch, attention, remat[, fused_ce]) — the LM cells plus
 # the B=16 T=2048 remat probe (token-batch lever).
 CELLS = [
     (2048, 8, "flash", False),

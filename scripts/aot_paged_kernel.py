@@ -7,9 +7,8 @@ decode, the speculative verify window (S=k+1), both ``kv_quant`` modes,
 and a serving-sized store — recording Mosaic lowering success and the
 executable's peak-bytes analysis per cell. The PERF.md discipline: a
 kernel claim that "lowers and fits" must be machine-checked on every
-kernel change without burning a chip window; the measured tokens/s
-numbers come from the driver's real-chip ``bench.py --mode serving``
-run, which this artifact de-risks.
+kernel change without burning a chip window; measured tokens/s come
+from the benchmark's serving cells (``benchmarks/run.py``) on the chip.
 
 Emits one JSON record per cell to scripts/aot_paged_kernel.jsonl.
 """

@@ -32,11 +32,4 @@ if [ "$default_scope" -eq 1 ] && [ -f SANITIZER.json ]; then
         --runtime-report SANITIZER.json || status=1
 fi
 
-# cross-check the committed bench trajectory against the per-round
-# artifacts (BENCH_TRAJECTORY.json must be a faithful rebuild, and the
-# newest successful round must sit inside the prior rounds' tolerance
-# bands) — same stance as the sanitizer runtime report above.
-if [ "$default_scope" -eq 1 ] && [ -f BENCH_TRAJECTORY.json ]; then
-    python scripts/bench_compare.py --check || status=1
-fi
 exit $status

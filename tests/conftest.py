@@ -2,8 +2,8 @@
 
 The reference tests distributed semantics with multiple MPI ranks on one box
 (SURVEY.md S4). The TPU analog is a forced-CPU 8-device mesh: full collective
-semantics, no TPU needed. ``bench.py`` and ``__graft_entry__.py`` do NOT do
-this — they must see the real chip. The config update below forces the CPU
+semantics, no TPU needed. ``chip_smoke.py``, ``benchmarks/run.py`` and
+``__graft_entry__.py`` do NOT do this — they must see the real chip. The config update below forces the CPU
 even on a machine that holds a chip.
 """
 

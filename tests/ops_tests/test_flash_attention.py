@@ -187,3 +187,23 @@ def test_default_block():
     assert _default_block(8192) == 1024
     assert _default_block(16384) == 1024
     assert _default_block(131072) == 1024
+
+
+@pytest.mark.parametrize("entry", ["flash_attention", "flash_fwd_with_lse",
+                                   "flash_block_grads"])
+def test_an_explicit_zero_block_is_refused_not_taken_for_unset(entry):
+    """``block_q or default`` read an explicit 0 as unset and ran the
+    default block; only ``None`` asks for the default."""
+    import importlib
+
+    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    q, k, v = _qkv(jax.random.PRNGKey(9), t=16)
+    b, t, h, _ = q.shape
+    stat = jnp.zeros((b, h, t), jnp.float32)      # an lse or a delta
+    args = {"flash_attention": (q, k, v),
+            "flash_fwd_with_lse": (q, k, v),
+            "flash_block_grads": (q, k, v, q, stat, stat)}[entry]
+    with pytest.raises(ValueError, match="block_q must be positive"):
+        getattr(fa, entry)(*args, block_q=0)
+    with pytest.raises(ValueError, match="block_k must be positive"):
+        getattr(fa, entry)(*args, block_k=0)

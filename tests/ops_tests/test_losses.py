@@ -91,3 +91,28 @@ def test_weighted_cotangent():
         np.asarray(jax.grad(loss_chunked)(hidden)),
         np.asarray(jax.grad(loss_oracle)(hidden)),
         rtol=2e-5, atol=2e-5)
+
+
+def test_pad_rows_stay_out_of_the_gradients_under_a_large_bias():
+    """``n % chunk != 0``: a pad row's logits are the bias alone, so a
+    bias entry past ~88 made ``exp(bias - 0)`` inf on it and ``inf * 0``
+    poisoned ``dkernel``/``dbias``/``dhidden``. The gradients are finite
+    and the unchunked reference's."""
+    chunk = 8
+    hidden, kernel, bias, targets = _setup(jax.random.PRNGKey(4),
+                                           n=chunk + 3)
+    bias = bias.at[5].set(100.0)
+
+    def loss_chunked(h, k, b):
+        return chunked_softmax_cross_entropy(h, k, b, targets,
+                                             chunk_size=chunk).mean()
+
+    def loss_oracle(h, k, b):
+        return _oracle(h, k, b, targets).mean()
+
+    g_c = jax.grad(loss_chunked, argnums=(0, 1, 2))(hidden, kernel, bias)
+    g_o = jax.grad(loss_oracle, argnums=(0, 1, 2))(hidden, kernel, bias)
+    for a, b_, name in zip(g_c, g_o, ["hidden", "kernel", "bias"]):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)

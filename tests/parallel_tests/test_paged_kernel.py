@@ -27,7 +27,6 @@ from jax.sharding import PartitionSpec as P
 import chainermn_tpu
 from chainermn_tpu.parallel import paged_kernel
 from chainermn_tpu.parallel.paged_kernel import (
-    bytes_read_model,
     chunk_blocks,
     kernel_supported,
     paged_attend,
@@ -461,23 +460,6 @@ def test_kernel_supported_env_kill_switch(monkeypatch):
     ok, why = kernel_supported()
     assert not ok and "CHAINERMN_TPU_NO_PAGED_KERNEL" in why
     assert "CHAINERMN_TPU_NO_PAGED_KERNEL" not in os.environ or True
-
-
-def test_bytes_read_model_shapes_and_direction():
-    """The cost model the bench record carries: the kernel streams
-    ``ceil(len/bs)*bs`` rows per row in storage dtype; the XLA path
-    streams the full span (plus the f32 dense view when int8). Exact
-    small-case arithmetic, then the direction invariants."""
-    m = bytes_read_model([4], block_size=4, max_blocks=2, n_heads=1,
-                         head_dim=2, n_layers=1, kv_quant="none")
-    # xla: 2 (k+v) * 2*4 rows * 2 elems * 4B = 128; kernel: 1 block = 64
-    assert m == {"xla_bytes": 128, "kernel_bytes": 64,
-                 "read_amplification": 2.0}
-    m8 = bytes_read_model([5, 16, 1], block_size=4, max_blocks=8,
-                          n_heads=4, head_dim=8, n_layers=2,
-                          kv_quant="int8")
-    assert m8["kernel_bytes"] < m8["xla_bytes"]
-    assert m8["read_amplification"] > 4.0   # int8 dense view dominates
 
 
 @pytest.mark.parametrize("quant", [False, True])

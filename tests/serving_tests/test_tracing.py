@@ -1,9 +1,9 @@
 """Request-scoped tracing through the serving stack: span-tree coverage
-and Chrome export (the acceptance scenario), tracing ON vs OFF parity on
-the same warm engine (token-identical, dispatch-count-identical, zero
-recompiles — the <2% monitor budget kept dispatch-based, not wall-clock),
-forced retention of shed requests, the SLO deadline-miss storm, and
-watchdog fires carrying request/trace identity."""
+and Chrome export (the acceptance scenario), each monitor (the tracer, the
+cost ledger, a running collector) ON vs OFF on the same warm engine, dense
+and paged (token-identical, dispatch-count-identical, zero recompiles:
+counts, not wall clock), forced retention of shed requests, the SLO
+deadline-miss storm, and watchdog fires carrying request/trace identity."""
 
 import io
 import json
@@ -20,6 +20,7 @@ from chainermn_tpu.monitor import get_event_log, get_registry
 from chainermn_tpu.monitor.events import EventLog
 from chainermn_tpu.monitor.registry import MetricsRegistry
 from chainermn_tpu.monitor.slo import LatencyObjective, SLOEngine
+from chainermn_tpu.monitor.timeseries import Collector
 from chainermn_tpu.monitor.trace import Tracer
 from chainermn_tpu.resilience import FaultInjector
 from chainermn_tpu.serving import (
@@ -47,15 +48,27 @@ def warm_engine(lm_and_params):
     return engine
 
 
-def _workload(sched, n=4, max_new=4):
-    """Deterministic burst: same prompts/rngs/budgets every call."""
+@pytest.fixture(scope="module")
+def warm_paged_engine(lm_and_params):
+    lm, params = lm_and_params
+    engine = ServingEngine(lm, params, n_slots=2, prefill_len=6,
+                           cache_len=24, paged=True, kv_block_size=2)
+    engine.warmup()
+    return engine
+
+
+def _workload(sched, n=4, max_new=4, after_step=lambda: None):
+    """Deterministic burst: same prompts/rngs/budgets every call;
+    ``after_step`` runs between the scheduler's steps."""
     rng = np.random.RandomState(0)
     reqs = []
     for i in range(n):
         prompt = rng.randint(1, 17, 1 + i % 4).astype(np.int32)
         reqs.append(sched.submit(prompt, max_new,
                                  rng=jax.random.PRNGKey(100 + i)))
-    sched.run_until_idle()
+    while sched.has_work:
+        sched.step()
+        after_step()
     return reqs
 
 
@@ -97,30 +110,71 @@ def test_request_span_tree_covers_lifecycle(warm_engine):
     assert json.dumps(cp)
 
 
-def test_tracing_on_vs_off_parity_and_dispatch_counts(warm_engine):
-    """Tracing must not change a single token OR a single device call:
-    the same warm engine serves the identical workload with tracing off
-    then on, and tokens, prefill/decode dispatch counters, executable
-    counts, and the zero-recompile invariant all match (dispatch-count
-    assertions, not wall-clock — the CPU-mesh-stable form of the <2%
-    overhead budget)."""
-    reg = get_registry()
-    c_decode = reg.counter("serving_decode_steps_total",
-                           {"engine": "serving"})
-    counts_before = warm_engine.compile_counts_detailed()
+def _dispatched():
+    """Prefill programs by bucket and decode steps dispatched so far in
+    this process, from the registry's counters."""
+    return {k: v for k, v in get_registry().snapshot()["counters"].items()
+            if k.startswith(("serving_prefills_total",
+                             "serving_decode_steps_total"))}
 
-    def run(tracer):
-        sched = FCFSScheduler(warm_engine, tracer=tracer)
-        d0 = c_decode.value
+
+def _serve(engine, monitor, on):
+    """The scripted workload through a fresh scheduler with one monitor
+    off or on; on, the monitor is seen at work, so ON is not a second
+    OFF."""
+    if monitor == "tracer":
+        tracer = Tracer(sample=1 if on else 0, ring=32)
+        reqs = _workload(FCFSScheduler(engine, tracer=tracer))
+        assert len(tracer.finished(kind="serving")) == (len(reqs) if on
+                                                        else 0)
+    elif monitor == "cost_ledger":
+        sched = FCFSScheduler(engine, cost_accounting=on)
         reqs = _workload(sched)
-        return [tuple(r.tokens) for r in reqs], c_decode.value - d0
+        if on:
+            assert sched.costs.report()["device_time"]["dispatches"] > 0
+        else:
+            assert sched.costs is None
+    elif on:                                   # a collector over the registry
+        collector = Collector(cadence_s=0.25)
+        reqs = _workload(
+            FCFSScheduler(engine),
+            after_step=lambda: collector.tick(
+                now=collector.cadence_s * (collector.ticks + 1)))
+        assert collector.ticks > 0
+        assert any(name.startswith("serving_decode_steps_total")
+                   for name in collector.store.names())
+    else:
+        reqs = _workload(FCFSScheduler(engine))
+    return reqs
 
-    toks_off, decodes_off = run(Tracer(sample=0))
-    toks_on, decodes_on = run(Tracer(sample=1, ring=32))
+
+@pytest.mark.parametrize("substrate", ["dense", "paged"])
+@pytest.mark.parametrize("monitor", ["tracer", "cost_ledger", "collector"])
+def test_monitoring_on_vs_off_parity_and_dispatch_counts(
+        monitor, substrate, request):
+    """A monitor must not change a single token OR a single device call:
+    the same warm engine serves the identical workload with the monitor
+    off then on, and tokens, prefill/decode dispatch counters, executable
+    counts, and the zero-recompile invariant all match (dispatch counts,
+    not wall clock)."""
+    engine = request.getfixturevalue(
+        {"dense": "warm_engine", "paged": "warm_paged_engine"}[substrate])
+    counts_before = engine.compile_counts_detailed()
+
+    def run(on):
+        before = _dispatched()
+        reqs = _serve(engine, monitor, on)
+        after = _dispatched()
+        return ([tuple(r.tokens) for r in reqs],
+                {k: after[k] - before.get(k, 0) for k in after})
+
+    toks_off, dispatched_off = run(False)
+    toks_on, dispatched_on = run(True)
     assert toks_on == toks_off                 # token-for-token parity
-    assert decodes_on == decodes_off           # zero extra device calls
-    assert warm_engine.compile_counts_detailed() == counts_before
-    assert warm_engine.recompiles == {}        # invariant held live
+    assert sum(dispatched_off.values()) > 0
+    assert dispatched_on == dispatched_off     # zero extra device calls
+    assert engine.compile_counts_detailed() == counts_before
+    assert engine.recompiles == {}             # invariant held live
 
 
 def test_tracing_off_records_nothing(warm_engine):

@@ -12,7 +12,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 from chainermn_tpu import create_communicator, utils  # noqa: E402
 
@@ -88,9 +87,3 @@ def test_cache_default_is_one_path_in_the_checkout(monkeypatch):
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 
-
-def test_chip_peak_is_keyed_by_exact_kind():
-    assert bench.chip_peak("TPU v5 lite") == 197e12
-    for kind in ("TPU v5", "TPU v5p", "cpu", "tpu v5 lite"):
-        with pytest.raises(KeyError, match="device_kind"):
-            bench.chip_peak(kind)
