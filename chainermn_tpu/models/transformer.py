@@ -27,7 +27,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from chainermn_tpu.parallel.moe import ExpertParallelMLP
-from chainermn_tpu.parallel.sequence import sequence_parallel_attention
+from chainermn_tpu.parallel.sequence import (
+    paged_scale_shape,
+    sequence_parallel_attention,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -367,7 +370,13 @@ def init_paged_kv_caches(model, n_blocks, block_size: int, *,
     :func:`~chainermn_tpu.parallel.sequence.paged_update_cache_and_attend`).
     ``quant='int8'`` stores int8 rows plus per-row-per-head f32
     ``'k_scale'``/``'v_scale'`` arrays (``x ≈ x_q * scale`` — ~2x less KV
-    memory per resident token; dequantized inside the attention gather).
+    memory per resident token; dequantized inside the attention), held as
+    ``paged_scale_shape(n_blocks, block_size, heads)``, ``[n_blocks, 1,
+    W]``: a block's scales in one row of whole lanes, row ``t`` of head
+    ``h`` in column ``t * heads + h``. It is the one shape that the write
+    (``paged_kernel.write_scale_rows``) and the decode kernel both take as
+    it lies; as ``[n_blocks, block_size, heads]`` every program that wrote
+    a row relaid the whole array three times (PERF.md §6, PR 32).
     Tensor-parallel decode passes ``local_heads=n_heads // tp_size``."""
     if quant not in ("none", "int8"):
         raise ValueError(f"quant must be 'none' or 'int8', got {quant!r}")
@@ -380,8 +389,9 @@ def init_paged_kv_caches(model, n_blocks, block_size: int, *,
         d = {"k": jnp.zeros((n, block_size, h, dh), dt),
              "v": jnp.zeros((n, block_size, h, dh), dt)}
         if quant == "int8":
-            d["k_scale"] = jnp.zeros((n, block_size, h), jnp.float32)
-            d["v_scale"] = jnp.zeros((n, block_size, h), jnp.float32)
+            shape = paged_scale_shape(n, block_size, h)
+            d["k_scale"] = jnp.zeros(shape, jnp.float32)
+            d["v_scale"] = jnp.zeros(shape, jnp.float32)
         return d
 
     layers = {i: layer(n, local_heads or kind.kv_heads, kind.head_dim)

@@ -13,7 +13,14 @@ path, one program per batch row (slot):
   entries, starting one copy per block (K rows, V rows and, for an int8
   store, the two scale rows) from ``store[table[b, j]]`` into one of two
   VMEM buffers and computing on one chunk while the next is on its way.
-  The dense per-sequence view never exists;
+  The dense per-sequence view never exists. The scale arrays are held as
+  ``[n_blocks, 1, W]``: a block's ``bs * H`` scales in one row of whole
+  lanes, in the kernel's own column order, an array the chip tiles row by
+  row. They reach the kernel untouched, a block's scales are one contiguous
+  copy, and :func:`write_scale_rows` writes them in the same shape, so a
+  decode program holds no operation on a whole scale array (as
+  ``[n_blocks, bs, H]`` each was relaid three times a layer, 42% of a
+  step's device time);
 - **a trip count from ``lengths``**: the walk takes
   ``cdiv(cdiv(lengths[b], bs), C)`` steps, so a dead table tail costs
   nothing; in the last chunk the entries past the last live block copy
@@ -46,8 +53,10 @@ Shapes are the serving decode family: ``S = 1`` (per-token decode), the
 (``S = k+1``); ``lengths = pos + S`` per row. The ``valid`` scratch
 redirect affects only WRITES (handled XLA-side before the kernel runs);
 the attention itself is position-masked identically to
-:func:`cached_attention`. Off TPU the kernel runs in Pallas interpret
-mode (the same code path CPU tier-1 tests pin);
+:func:`cached_attention`. One write is a kernel's own: the scales of the
+rows a call stores (:func:`write_scale_rows`), because XLA writes a row of
+such an array only after relaying all of it. Off TPU the kernels run in
+Pallas interpret mode (the same code path CPU tier-1 tests pin);
 ``scripts/aot_paged_kernel.py`` lowers it for the chip without one, and
 PERF.md §5 and §6 hold what it takes on the chip. A model's layers call
 :func:`paged_attend` with one set of shapes, and the kernel is traced and
@@ -83,7 +92,9 @@ def kernel_supported() -> tuple[bool, str]:
     with ``paged_kernel=True`` call this once at construction and fall
     back to the XLA path (emitting the ``paged_kernel_fallback`` event)
     instead of failing warmup — the kernel is an optimization, never a
-    capability."""
+    capability. (It is the READ that falls back: an int8 store's scales are
+    written by :func:`write_scale_rows` whatever this says, interpreted
+    off the chip like every kernel here.)"""
     if os.environ.get("CHAINERMN_TPU_NO_PAGED_KERNEL"):
         return False, "disabled by CHAINERMN_TPU_NO_PAGED_KERNEL"
     try:  # pragma: no cover - import failure is environment-specific
@@ -349,8 +360,14 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
       write (``pos + S``). Only the ``ceil(lengths[b]/bs)`` blocks they
       reach are copied or computed on; a table entry past them is never
       looked through, whatever it holds;
-    - ``k_scale``/``v_scale``: ``[n_blocks, bs, H]`` f32, present iff
-      the store is int8 (dequant folds into the contractions);
+    - ``k_scale``/``v_scale``: ``[n_blocks, 1, W]`` f32
+      (:func:`~chainermn_tpu.parallel.sequence.paged_scale_shape`), present
+      iff the store is int8 (dequant folds into the contractions): a
+      block's ``bs * Hkv`` scales in one row of whole lanes, row ``t`` of
+      head ``h`` in column ``t * Hkv + h``, the order the kernel's columns
+      have. The arrays go to the kernel as they are and it copies a block's
+      row out of HBM: any other shape costs a pass over the whole array a
+      call;
     - ``max_blocks``: optional static cap on the table entries a row can
       have live (callers with static positions pass the batch-max active
       count); it bounds the sweep's trip count and nothing else;
@@ -408,16 +425,16 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
         return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
     # heads fold into the ROW dimension (free contiguous reshapes) so
-    # every copy and every operand is a full 2D tile; the scales flatten
-    # to row vectors for the same reason. The store stays where it is and
-    # the kernel copies the blocks it needs. A head narrower than the 128
-    # lanes shares a row with its neighbours (``pack`` heads a row, a free
-    # view again): the query then sits in its own head's lanes of a row of
-    # zeros, the scales go in as ``pack`` rows a block, and each output row
-    # is read from its head's lanes. Only what neither view brings to whole
-    # lanes is padded, at the price of a copy a call: nothing at the served
-    # widths (heads of 128, bs*H of 256), the small scale rows of a store
-    # sharded down to a few heads, a toy head size that does not divide 128.
+    # every copy and every operand is a full 2D tile; the scales are held
+    # as row vectors of whole lanes, a block a row, for the same reason and
+    # go in untouched. The store stays where it is and the kernel copies
+    # the blocks it needs. A head narrower than the 128 lanes shares a row
+    # with its neighbours (``pack`` heads a row, a free view again): the
+    # query then sits in its own head's lanes of a row of zeros, the scales
+    # go in as ``pack`` rows a block, and each output row is read from its
+    # head's lanes. Only what no view brings to whole lanes is padded or
+    # regrouped, at the price of a copy a call: nothing at the served
+    # widths (heads of 128), the scales and rows of a toy head size.
     # (grouped heads keep a row each: a row shared by KV heads would mix
     # the lanes of query heads that read different ones)
     pack = (_LANE // d if group == 1 and _LANE % d == 0
@@ -444,8 +461,12 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
                pltpu.VMEM((2, chunk, rows, dp), store_v.dtype)]
     if quant:
         in_specs += [in_hbm, in_hbm]
-        operands += [lanes(sc.reshape(n_blocks, rows, pack).swapaxes(1, 2))
-                     for sc in (k_scale, v_scale)]
+        if pack > 1:
+            # column t*H + h to row h % pack, column (t*H + h) // pack
+            k_scale, v_scale = (
+                lanes(sc[:, 0, :rows * pack].reshape(n_blocks, rows, pack)
+                      .swapaxes(1, 2)) for sc in (k_scale, v_scale))
+        operands += [k_scale, v_scale]
         scratch += [pltpu.VMEM((2, chunk) + operands[-1].shape[1:],
                                jnp.float32)] * 2
     scratch += [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
@@ -474,4 +495,104 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
     return out.reshape(b, s_len, h, d)
 
 
-__all__ = ["kernel_supported", "paged_attend"]
+def _scale_rows_kernel(blk_ref, lo_ref, hi_ref, fresh_k, fresh_v, ks_in,
+                       vs_in, ks_out, vs_out, k_buf, v_buf, sem):
+    """One program per ``rows`` touched blocks: their scale rows come out
+    of the two arrays (left in HBM; ``*_out`` are ``*_in``, aliased), the
+    columns ``[lo, hi)`` of each take the fresh scales, and the rows go
+    back. All of a program's copies of one direction are in flight
+    together."""
+    rows, _, w = fresh_k.shape
+    base = pl.program_id(0) * rows
+    arrays = ((ks_in, ks_out, k_buf, fresh_k), (vs_in, vs_out, v_buf, fresh_v))
+
+    def copies(act, back: bool):
+        def row(r, carry):
+            blk = blk_ref[base + r]
+            for src, dst, buf, _ in arrays:
+                act(pltpu.make_async_copy(buf.at[r], dst.at[blk], sem.at[1])
+                    if back else
+                    pltpu.make_async_copy(src.at[blk], buf.at[r], sem.at[0]))
+            return carry
+
+        jax.lax.fori_loop(0, rows, row, None)
+
+    copies(lambda cp: cp.start(), False)
+    copies(lambda cp: cp.wait(), False)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (1, 1, w), 2)
+
+    def patch(r, carry):
+        keep = (ci >= lo_ref[base + r]) & (ci < hi_ref[base + r])
+        for _, _, buf, fresh in arrays:
+            at = pl.ds(r, 1)
+            buf[at] = jnp.where(keep, fresh[at], buf[at])
+        return carry
+
+    jax.lax.fori_loop(0, rows, patch, None)
+    copies(lambda cp: cp.start(), True)
+    copies(lambda cp: cp.wait(), True)
+
+
+def write_scale_rows(k_scale, v_scale, blocks, lo, hi, fresh_k, fresh_v, *,
+                     interpret: Optional[bool] = None):
+    """The write side of an int8 store's scales, in place: rows
+    ``blocks [R]`` of ``k_scale``/``v_scale`` (``[n_blocks, 1, W]``) take
+    ``fresh_k``/``fresh_v`` (``[R, 1, W]``) in their columns
+    ``[lo[r], hi[r])`` and keep what they held elsewhere. The arrays stay
+    in HBM and are handed back aliased: a call moves ``2 * R`` rows each
+    way and nothing else. XLA can do the same with a gather and a scatter,
+    but not in this layout, which is the one the decode kernel copies a
+    block's scales out of with one contiguous copy: it relays the whole
+    array to write a row of it.
+
+    A block listed twice gets one of its writers' rows (callers list only
+    the scratch block more than once); ``blocks`` must lie inside the
+    arrays, a copy has no bounds to drop it at."""
+    if interpret is None:
+        interpret = kernels_interpreted()
+    return _write_rows(k_scale, v_scale,
+                       *(jnp.asarray(x, jnp.int32) for x in (blocks, lo, hi)),
+                       fresh_k, fresh_v, interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+def _write_rows(k_scale, v_scale, blocks, lo, hi, fresh_k, fresh_v, *,
+                interpret: bool):
+    """:func:`write_scale_rows` with its default filled in, traced once
+    for all the layers of a model and lowered once a program, as
+    :func:`_attend` is and for its reason."""
+    r, _, w = fresh_k.shape
+    # rows a program: its two buffers and two double-buffered operands,
+    # each row a tile of 8 sublanes in VMEM, 6 of the scoped 16 MiB at
+    # most (128 rows at the served 256 columns: a decode step's in one)
+    rows = max(1, min(r, 2 ** 20 // _tile_bytes(1, w, jnp.float32)))
+    pad = -r % rows
+    if pad:
+        # the scratch block again, no column of it
+        blocks, lo, hi = (jnp.pad(x, (0, pad)) for x in (blocks, lo, hi))
+        fresh_k, fresh_v = (jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+                            for x in (fresh_k, fresh_v))
+    row = pl.BlockSpec((rows, 1, w), lambda i, *_: (i, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    vma = _out_vma(k_scale, v_scale, blocks, lo, hi, fresh_k, fresh_v)
+    return pl.pallas_call(
+        _scale_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=((r + pad) // rows,),
+            in_specs=[row, row, in_hbm, in_hbm],
+            out_specs=[in_hbm, in_hbm],
+            scratch_shapes=[pltpu.VMEM((rows, 1, w), jnp.float32)] * 2
+            + [pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+                   for x in (k_scale, v_scale)],
+        # operands count from the first scalar: 5 and 6 are the arrays
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(blocks, lo, hi, fresh_k, fresh_v, k_scale, v_scale)
+
+
+__all__ = ["kernel_supported", "paged_attend", "write_scale_rows"]

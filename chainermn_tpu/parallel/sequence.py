@@ -848,6 +848,35 @@ def _dequant_cached_attention(q, k8, k_sc, v8, v_sc, pos_offset, *,
     return out.astype(q.dtype)
 
 
+def paged_scale_shape(n_blocks: int, block_size: int, heads: int) -> tuple:
+    """Shape of an int8 block store's scale arrays, ``[n_blocks, 1, W]``: a
+    block's ``block_size * heads`` scales in ONE row (row ``t`` of the
+    block, head ``h`` in column ``t * heads + h``) brought to whole tiles
+    of 128 lanes, zeros after them. The chip tiles such an array row by
+    row, so a block's scales are contiguous: the decode kernel copies them
+    out, and :func:`~chainermn_tpu.parallel.paged_kernel.write_scale_rows`
+    writes them, as they lie. Padded in the store itself the pad costs no
+    pass a call (nor memory: the tiled layout holds a narrower row in whole
+    lanes anyway)."""
+    return (n_blocks, 1, -(-block_size * heads // 128) * 128)
+
+
+def fold_block_scales(sc):
+    """``[n, bs, H]`` per-row-per-head scales as rows of a scale array:
+    ``[n, 1, W]``, as :func:`paged_scale_shape` has it."""
+    n, bs, h = sc.shape
+    width = paged_scale_shape(n, bs, h)[2]
+    return jnp.pad(sc.reshape(n, 1, bs * h),
+                   ((0, 0), (0, 0), (0, width - bs * h)))
+
+
+def unfold_block_scales(gathered, bs: int, h: int):
+    """Rows gathered from a scale array, ``[n, 1, W]``, as ``[n, bs, H]``.
+    Only ever what was gathered: reshaping the array itself would copy it
+    whole."""
+    return gathered[:, 0, :bs * h].reshape(-1, bs, h)
+
+
 def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
                                   scale: Optional[float] = None):
     """The paged twin of :func:`update_cache_and_attend`: K/V live in a
@@ -866,10 +895,15 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
       convention a reserved scratch block): the position mask hides every
       row at positions beyond the query, exactly like the dense path's
       stale-rows argument;
-    - optional ``'k_scale'``/``'v_scale'``: ``[n_blocks, block_size, H]``
-      f32 — present iff the store is int8-quantized. Each resident row
-      carries one symmetric scale per head (``x ≈ x_q * scale``); writes
-      quantize, the attention gather dequantizes in-program.
+    - optional ``'k_scale'``/``'v_scale'``: f32 of :func:`paged_scale_shape`,
+      ``[n_blocks, 1, W]`` — present iff the store is int8-quantized. Each
+      resident row carries one symmetric scale per head
+      (``x ≈ x_q * scale``), row ``t`` of a block's head ``h`` in
+      column ``t * H + h``: a block's scales are ONE row of whole lanes,
+      the shape in which the write (the touched blocks' rows patched in
+      place) and the decode kernel (one contiguous copy a block) take the
+      array as it lies, so no program relays it. Writes quantize, the
+      attention dequantizes in-program.
     - optional ``'valid'``: ``[B]`` int32 — per-row count of *leading*
       query positions whose K/V rows should actually land in the store.
       Rows ``j >= valid[b]`` are redirected into the scratch block
@@ -911,8 +945,10 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
     ``ceil(len/bs)`` live blocks in chunks, copying them from the store
     itself while it computes, with the dequant and the online softmax in
     the same pass; the table's dead tail is neither copied nor looked
-    through. The scatter (write side) is XLA on every path — it moves
-    ``S`` rows, the kernel owns the O(length) read. ``'use_kernel'``
+    through. The scatter of the rows (write side) is XLA on every path — it
+    moves ``S`` rows, the kernel owns the O(length) read; an int8 store's
+    scales are written by a kernel of their own on every path
+    (:func:`paged_write_kv`). ``'use_kernel'``
     must be a static Python bool (it selects a trace, it is not an
     operand).
 
@@ -961,8 +997,9 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
             rows = rows.reshape((b, -1) + rows.shape[2:])
             if not quant:
                 return rows.astype(q.dtype), None
-            sc = jnp.take(scales, flat, axis=0)    # [B*m, bs, H]
-            return rows, sc.reshape((b, -1) + sc.shape[2:])
+            sc = unfold_block_scales(jnp.take(scales, flat, axis=0),
+                                     *store.shape[1:3])
+            return rows, sc.reshape(rows.shape[:3])
 
         kbuf, ksc = gather(new_k, new_ks)
         vbuf, vsc = gather(new_v, new_vs)
@@ -980,7 +1017,10 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
     the store (quantized where the store is int8), ``{'k', 'v'[,
     'k_scale', 'v_scale']}`` handed back without the table. A prefill
     that attends its own fresh K/V (a flash kernel over the prompt) calls
-    this for the store and nothing else.
+    this for the store and nothing else. An int8 store's scales go in
+    through :func:`~chainermn_tpu.parallel.paged_kernel.write_scale_rows`
+    on every path (interpreted off the chip): the only in-place write of
+    their layout there is.
 
     ``kv_cache['valid']`` (``[B]``) sends the rows past each sequence's
     count to the scratch block. ``kv_cache['window']`` (a window layer)
@@ -1013,7 +1053,8 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
         blk = jnp.where(rv, blk, 0)
         off = jnp.where(rv, off, 0)
 
-    def write(store, scales, rows):
+    def rows_of(store, rows):
+        """The store with the call's rows in it, and the rows' scales."""
         rows = rows.reshape((b * s,) + rows.shape[2:])        # [B*S, H, D]
         if not quant:
             return store.at[blk, off].set(rows.astype(store.dtype)), None
@@ -1022,15 +1063,57 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
         # rows (warmup, padding) from dividing by zero
         sc = jnp.maximum(jnp.max(jnp.abs(r32), axis=-1) / 127.0, 1e-8)
         q8 = jnp.clip(jnp.round(r32 / sc[..., None]), -127, 127)
-        return (store.at[blk, off].set(q8.astype(jnp.int8)),
-                scales.at[blk, off].set(sc))
+        return store.at[blk, off].set(q8.astype(jnp.int8)), sc
 
-    new_k, new_ks = write(store_k, kv_cache.get("k_scale"), k)
-    new_v, new_vs = write(store_v, kv_cache.get("v_scale"), v)
+    new_k, k_sc = rows_of(store_k, k)
+    new_v, v_sc = rows_of(store_v, v)
     new_cache = {"k": new_k, "v": new_v}
-    if quant:
-        new_cache["k_scale"] = new_ks
-        new_cache["v_scale"] = new_vs
+    if not quant:
+        return new_cache
+
+    # the scales go in by the block: of those a sequence's S rows touch (its
+    # first block and the ``n_t - 1`` after it) the rows written now take
+    # the fresh scales, the others keep theirs. Row ``i`` of the call sits
+    # in touched block ``(lead + i) // bs`` at row ``(lead + i) % bs``, so
+    # what a block takes is one run of its rows, ``[lo, hi)``
+    h = k.shape[2]
+    lead = (pos_offset % bs)[:, None]                         # [B, 1]
+    n_t = (s + bs - 2) // bs + 1
+    n_rows = s if valid is None else jnp.minimum(valid, s)[:, None]
+    first = jnp.arange(n_t)[None, :] * bs - lead     # call's row at row 0
+    lo = jnp.clip(-first, 0, bs)                              # [B, n_t]
+    hi = jnp.clip(n_rows - first, 0, bs)
+    t_entry = pos_offset[:, None] // bs + jnp.arange(n_t)[None, :]
+    if ring is not None:
+        if valid is not None:
+            # of the valid rows the last ring of blocks alone (as above)
+            hi = jnp.where(t_entry > newest[:, None] - ring, hi, lo)
+        t_entry = t_entry % ring
+    # a touched block that takes no row (a prompt's padding, the blocks a
+    # ring has dropped) or lies outside the store (a clamped lookup, whose
+    # rows the scatter above drops) is the scratch block
+    t_blk = jnp.take_along_axis(table, t_entry, axis=1)
+    t_blk = jnp.where((hi > lo) & (t_blk > 0) & (t_blk < store_k.shape[0]),
+                      t_blk, 0)
+
+    def fresh(sc):
+        """The call's scales ``[B*S, H]`` at each row of the touched
+        blocks (the call's nearest where none sits): ``[B*n_t, 1, W]``."""
+        sc = sc.reshape(b, s, h)
+        if s == 1:
+            sc = jnp.broadcast_to(sc, (b, n_t * bs, h))
+        else:
+            at = jnp.clip(jnp.arange(n_t * bs)[None, :] - lead, 0, s - 1)
+            sc = jnp.take_along_axis(sc, at[:, :, None], axis=1)
+        return fold_block_scales(sc.reshape(b * n_t, bs, h))
+
+    # XLA writes a row of these arrays only after relaying all of them (a
+    # pass over every array of every layer, a decode step: PERF.md §6, PR
+    # 32), so on every path the rows go in through the kernel
+    from chainermn_tpu.parallel.paged_kernel import write_scale_rows
+    new_cache["k_scale"], new_cache["v_scale"] = write_scale_rows(
+        kv_cache["k_scale"], kv_cache["v_scale"], t_blk.reshape(-1),
+        (lo * h).reshape(-1), (hi * h).reshape(-1), fresh(k_sc), fresh(v_sc))
     return new_cache
 
 
@@ -1058,12 +1141,16 @@ def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
         rows = jnp.take(x, flat, axis=0)
         return rows.reshape((b, n * bs) + rows.shape[2:])
 
+    def scales(x):
+        gathered = unfold_block_scales(jnp.take(x, flat, axis=0), bs, hk)
+        return gathered.reshape(b, n * bs, hk)
+
     kbuf, vbuf = gather(store_k), gather(store_v)
     qg = q.reshape(b, s, hk, g, d)
     sc = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kbuf.astype(q.dtype),
                     preferred_element_type=jnp.float32) * scale
     if k_scale is not None:
-        sc = sc * jnp.moveaxis(gather(k_scale), 2, 1)[:, :, None, None, :]
+        sc = sc * jnp.moveaxis(scales(k_scale), 2, 1)[:, :, None, None, :]
     q_pos = pos_offset[:, None] + jnp.arange(s)[None, :]      # [B, S]
     entry = (jnp.arange(n * bs) // bs)[None, None, :]         # [1, 1, K]
     row = (jnp.arange(n * bs) % bs)[None, None, :]
@@ -1078,7 +1165,7 @@ def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
     sc = jnp.where(mask[:, None, None], sc, _NEG_BIG)
     p = jax.nn.softmax(sc, axis=-1)
     if v_scale is not None:
-        p = p * jnp.moveaxis(gather(v_scale), 2, 1)[:, :, None, None, :]
+        p = p * jnp.moveaxis(scales(v_scale), 2, 1)[:, :, None, None, :]
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p, vbuf.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
     return out.reshape(b, s, h, d).astype(q.dtype)

@@ -1249,11 +1249,16 @@ class ServingEngine:
             )
         shard = NamedSharding(comm.mesh, P(None, None, axis))
         if self.paged:
-            # the store's head axis (2) shards like the dense caches';
-            # quant scale arrays are [N, bs, H] so the same spec splits
-            # their heads too, and the tiny tables stay replicated
+            # the store's head axis (2) shards like the dense caches', and
+            # the tiny tables stay replicated. A rank's scale arrays are
+            # [N, 1, W] of ITS heads (column t * H_local + h), so the same
+            # spec splits them, and the whole array is the ranks' side by
+            # side along the columns: made so here (all zeros), and only
+            # ever read inside shard_map
             self.caches = None
-            self._store = jax.device_put(self._init_paged_store(), shard)
+            local = self._init_paged_store(self.model.n_heads // n_tp)
+            self._store = jax.device_put(jax.tree.map(
+                lambda x: jnp.concatenate([x] * n_tp, axis=2), local), shard)
             return
         self.caches = jax.device_put(
             init_kv_caches(self.model, self.n_slots, self.cache_len), shard)

@@ -400,7 +400,10 @@ def check_paged_read_paths(sz: Sizes, n_heads: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from chainermn_tpu.parallel.sequence import paged_update_cache_and_attend
+    from chainermn_tpu.parallel.sequence import (
+        fold_block_scales,
+        paged_update_cache_and_attend,
+    )
 
     b, bs = sz.n_slots, sz.kv_block
     d = sz.d_model // sz.n_heads
@@ -408,13 +411,17 @@ def check_paged_read_paths(sz: Sizes, n_heads: int) -> dict:
     n_blocks = b * n_max + 1
     rng = np.random.RandomState(2)
     rows, heads = (n_blocks, bs, n_heads, d), (n_blocks, bs, n_heads)
+
+    def scales():
+        """A scale for every row and head, as the store holds them: a
+        block a row."""
+        sc = rng.uniform(0.001, 0.02, heads).astype(np.float32)
+        return fold_block_scales(jnp.asarray(sc))
+
     store = {
         "k": jnp.asarray(rng.randint(-127, 128, rows).astype(np.int8)),
         "v": jnp.asarray(rng.randint(-127, 128, rows).astype(np.int8)),
-        "k_scale": jnp.asarray(
-            rng.uniform(0.001, 0.02, heads).astype(np.float32)),
-        "v_scale": jnp.asarray(
-            rng.uniform(0.001, 0.02, heads).astype(np.float32)),
+        "k_scale": scales(), "v_scale": scales(),
         # every row owns a shuffled span of the pool; block 0 is scratch
         "table": jnp.asarray(1 + rng.permutation(b * n_max).astype(
             np.int32).reshape(b, n_max)),

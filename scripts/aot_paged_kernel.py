@@ -41,6 +41,7 @@ def main():
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from chainermn_tpu.parallel import paged_kernel as pk
+    from chainermn_tpu.parallel.sequence import paged_scale_shape
 
     # smallest valid v5e topology is 2x2; the kernel is a single-device
     # program, so the call is wrapped in a fully-replicated shard_map —
@@ -75,8 +76,9 @@ def main():
                 jax.ShapeDtypeStruct((b,), jnp.int32, sharding=repl),
             ]
             if quant == "int8":
-                avals += [jax.ShapeDtypeStruct((n_blocks, bs, h),
-                                               jnp.float32, sharding=repl)] * 2
+                avals += [jax.ShapeDtypeStruct(
+                    paged_scale_shape(n_blocks, bs, h), jnp.float32,
+                    sharding=repl)] * 2
 
             def fn(q, sk, sv, table, lengths, *scales):
                 def body(q, sk, sv, table, lengths, *scales):
@@ -94,6 +96,8 @@ def main():
             rec = {"cell": label, "kv_quant": quant, "batch": b,
                    "window": s, "heads": h, "head_dim": d,
                    "block_size": bs, "max_blocks": m}
+            if quant == "int8":
+                rec["scale_shape"] = list(avals[-1].shape)
             t0 = time.time()
             try:
                 c = jax.jit(fn).lower(*avals).compile()

@@ -1,0 +1,68 @@
+"""Compiles for the chip without one (the TPU's own compiler, a described
+``v5e:2x2``): what interpret mode cannot see. ISSUE 32's own evidence had
+lowered the INTERPRETED paged kernel and so passed a copy that Mosaic
+refuses; these hold the decode layer and the scale write to the real
+lowering at the served widths, and count the passes over a scale array.
+
+One file, the topology in a fixture: only the worker that runs these tests
+loads the TPU's library (the on-chip-measurement guide, section 2)."""
+
+import importlib.util
+import pathlib
+
+import jax
+import pytest
+
+from chainermn_tpu import ops
+
+SCRIPT = (pathlib.Path(__file__).resolve().parents[2]
+          / "scripts" / "aot_decode_writes.py")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to hold
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def count(topo):
+    """``scripts/aot_decode_writes.py``'s count for one store and program,
+    the kernels traced as the chip traces them (and at the chip's default
+    matmul precision, not the suite's ``highest``)."""
+    spec = importlib.util.spec_from_file_location("aot_decode_writes", SCRIPT)
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    ops.set_kernels_interpreted(False)
+    try:
+        with jax.default_matmul_precision("default"):
+            yield lambda store, program: next(script.records(
+                topo, [s for s in script.STORES if s[0] == store],
+                (program,)))[0]
+    finally:
+        ops.set_kernels_interpreted(None)
+
+
+@pytest.mark.parametrize("store", ["cell1", "cell3_window"])
+def test_decode_layer_passes_over_no_scale_array(count, store):
+    """Write and kernel at a cell's shapes, the store donated: two Mosaic
+    calls (the scale write, the sweep) and not one operation, relayout or
+    staging copy, over a whole scale array or int8 store."""
+    rec = count(store, "decode")
+    assert rec["mosaic_calls"] == 2
+    assert rec["whole_scale_array_ops"] == 0
+    assert rec["whole_int8_store_ops"] == 0
+
+
+def test_prefill_write_relays_no_scale_array(count):
+    """One row of cell 1's largest bucket: what is left over a scale array
+    is the compiler's own staging copies (asynchronous, its choice program
+    by program), never a relayout in the program."""
+    rec = count("cell1", "prefill_write")
+    assert rec["mosaic_calls"] == 1
+    assert rec["whole_scale_array_ops"] == rec["async_staging"]
+    assert rec["whole_int8_store_ops"] == 0

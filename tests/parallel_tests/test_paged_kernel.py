@@ -34,9 +34,16 @@ from chainermn_tpu.parallel.paged_kernel import (
 from chainermn_tpu.parallel.sequence import (
     _dequant_cached_attention,
     cached_attention,
+    fold_block_scales,
     paged_update_cache_and_attend,
     update_cache_and_attend,
 )
+
+
+def fold(sc):
+    """``[n, bs, H]`` scales (a bf16 store has none) as the store holds
+    them: a block a row."""
+    return None if sc is None else fold_block_scales(sc)
 
 
 def _stores(b, h, d, bs, n_max, *, quant=False, seed=0):
@@ -100,7 +107,8 @@ def test_kernel_int8_matches_xla_dequant_path():
     _, _, k8, v8, ksc, vsc, table = _stores(b, h, d, bs, n_max, quant=True)
     lengths = jnp.asarray([2, 9, 20], jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(8), (b, 2, h, d), jnp.float32)
-    got = paged_attend(q, k8, v8, table, lengths, k_scale=ksc, v_scale=vsc)
+    got = paged_attend(q, k8, v8, table, lengths,
+                       k_scale=fold(ksc), v_scale=fold(vsc))
     # dense dequant reference over the full span (mask hides the tail)
     kd = (k8.astype(jnp.float32) * ksc[..., None])[table.reshape(-1)]
     vd = (v8.astype(jnp.float32) * vsc[..., None])[table.reshape(-1)]
@@ -134,7 +142,7 @@ def _empty_paged(b, h, d, bs, n_max, quant):
     n_blocks = b * n_max + 1
     if quant:
         z = jnp.zeros((n_blocks, bs, h, d), jnp.int8)
-        sc = jnp.zeros((n_blocks, bs, h), jnp.float32)
+        sc = fold(jnp.zeros((n_blocks, bs, h), jnp.float32))
         cache = {"k": z, "v": z, "k_scale": sc, "v_scale": sc}
     else:
         z = jnp.zeros((n_blocks, bs, h, d), jnp.float32)
@@ -274,7 +282,7 @@ def test_lengths_on_every_edge_of_a_chunk(quant):
     lengths = jnp.asarray(edges, jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(31), (b, 1, h, d), jnp.float32)
     got = np.asarray(paged_attend(q, sk, sv, table, lengths,
-                                  k_scale=ksc, v_scale=vsc))
+                                  k_scale=fold(ksc), v_scale=fold(vsc)))
     if quant:
         want = _xla_int8_ref(q, sk, sv, ksc, vsc, table, lengths)
     else:
@@ -294,7 +302,8 @@ def test_served_shape_int8_bf16_query():
     lengths = jnp.asarray([8 * bs + 5, 23 * bs], jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(32), (b, 1, h, d),
                           jnp.bfloat16)
-    got = paged_attend(q, k8, v8, table, lengths, k_scale=ksc, v_scale=vsc)
+    got = paged_attend(q, k8, v8, table, lengths,
+                       k_scale=fold(ksc), v_scale=fold(vsc))
     assert got.dtype == jnp.bfloat16
     want = np.asarray(_xla_int8_ref(q, k8, v8, ksc, vsc, table, lengths),
                       np.float32)
@@ -313,7 +322,8 @@ def test_heads_narrower_than_a_lane_row_share_one(h, d, quant):
                                                   quant=quant)
     lengths = jnp.asarray([2, bs + 3, n_max * bs], jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(37), (b, 2, h, d), jnp.float32)
-    got = paged_attend(q, sk, sv, table, lengths, k_scale=ksc, v_scale=vsc)
+    got = paged_attend(q, sk, sv, table, lengths,
+                       k_scale=fold(ksc), v_scale=fold(vsc))
     if quant:
         want = _xla_int8_ref(q, sk, sv, ksc, vsc, table, lengths)
     else:
@@ -379,7 +389,13 @@ def test_head_sharded_store_four_local_heads(quant):
     _, _, sk, sv, ksc, vsc, table = _stores(b, h, d, bs, n_max, quant=quant)
     lengths = jnp.asarray([bs + 1, n_max * bs - 3], jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(35), (b, 1, h, d), jnp.float32)
-    scales = (ksc, vsc) if quant else ()
+    scales = (fold(ksc), fold(vsc)) if quant else ()
+    # a device's scale array folds ITS heads: the whole array is the
+    # devices' side by side along the columns
+    by_rank = tuple(
+        jnp.concatenate([fold(sc[:, :, r * 4:(r + 1) * 4])
+                         for r in range(comm.size)], axis=2)
+        for sc in ((ksc, vsc) if quant else ()))
 
     def attend(q, sk, sv, tb, ln, *sc):
         kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
@@ -387,10 +403,10 @@ def test_head_sharded_store_four_local_heads(quant):
 
     hspec = P(None, None, comm.axis_name)
     f = jax.jit(comm.shard_map(
-        attend, in_specs=(hspec, hspec, hspec, P(), P()) + (hspec,) * len(
-            scales), out_specs=hspec))
+        attend, in_specs=(hspec, hspec, hspec, P(), P())
+        + (hspec,) * len(scales), out_specs=hspec))
     np.testing.assert_allclose(
-        np.asarray(f(q, sk, sv, table, lengths, *scales)),
+        np.asarray(f(q, sk, sv, table, lengths, *by_rank)),
         np.asarray(attend(q, sk, sv, table, lengths, *scales)),
         atol=5e-6, rtol=5e-6)
 
@@ -416,9 +432,9 @@ def test_poisoned_dead_blocks_are_never_read(quant):
     if quant:
         bad = lambda sc: jnp.where(mark[:, None, None], jnp.inf, sc)
         clean = paged_attend(q, sk, sv, table, lengths,
-                             k_scale=ksc, v_scale=vsc)
+                             k_scale=fold(ksc), v_scale=fold(vsc))
         got = paged_attend(q, sk, sv, table, lengths,
-                           k_scale=bad(ksc), v_scale=bad(vsc))
+                           k_scale=fold(bad(ksc)), v_scale=fold(bad(vsc)))
     else:
         bad = lambda x: jnp.where(mark[:, None, None, None], jnp.nan, x)
         clean = paged_attend(q, sk, sv, table, lengths)
@@ -476,7 +492,7 @@ def test_layers_share_one_trace_and_nothing_wraps_the_kernel(quant):
     def layers(q):
         for _ in range(3):
             q = paged_attend(q, sk, sv, table, lengths,
-                             k_scale=ksc, v_scale=vsc)
+                             k_scale=fold(ksc), v_scale=fold(vsc))
         return q
 
     eqns = jax.make_jaxpr(layers)(q).jaxpr.eqns

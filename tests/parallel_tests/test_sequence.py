@@ -10,9 +10,11 @@ from jax.sharding import PartitionSpec as P
 import chainermn_tpu
 from chainermn_tpu.parallel.sequence import (
     full_attention,
+    paged_scale_shape,
     ring_attention,
     ring_flash_attention,
     ulysses_attention,
+    unfold_block_scales,
     zigzag_flash_attention,
     zigzag_permutation,
     zigzag_positions,
@@ -409,7 +411,7 @@ def _paged_setup(b=3, s=2, h=4, d=8, bs=4, n_max=4, quant="none", seed=3):
         # quantizing too; the engine only ever writes through the quant
         # path, so an empty store + fresh writes is the honest setup)
         z = jnp.zeros((n_blocks, bs, h, d), jnp.int8)
-        sc = jnp.zeros((n_blocks, bs, h), jnp.float32)
+        sc = jnp.zeros(paged_scale_shape(n_blocks, bs, h), jnp.float32)
         paged = {"k": z, "v": z, "k_scale": sc, "v_scale": sc,
                  "table": paged["table"]}
     return q, k, v, pos, dense, paged
@@ -471,7 +473,8 @@ def test_paged_int8_quant_tolerance():
     out_q, new_q = update_cache_and_attend(paged_q, q, k, v, pos)
     # round-trip error bound: |x - x_q*scale| <= scale/2 = max|x|/254
     deq = (np.asarray(new_q["k"], np.float32)
-           * np.asarray(new_q["k_scale"])[..., None])
+           * np.asarray(unfold_block_scales(
+               new_q["k_scale"], *new_q["k"].shape[1:3]))[..., None])
     ref = np.asarray(new_f["k"])
     written = np.abs(ref) > 0
     err = np.abs(deq - ref)[written]

@@ -22,6 +22,7 @@ from chainermn_tpu.ops import flash_attention
 from chainermn_tpu.parallel.moe import DroplessMoE
 from chainermn_tpu.parallel.sequence import (
     full_attention,
+    paged_scale_shape,
     paged_update_cache_and_attend,
     paged_write_kv,
 )
@@ -312,7 +313,8 @@ def test_paged_kernel_with_groups_of_seven_and_a_first_position(store,
                             jnp.int8 if store == "int8" else dt)}
     cache["v"] = cache["k"]
     if store == "int8":
-        cache["k_scale"] = jnp.zeros((n_blocks, bs, hk), jnp.float32)
+        cache["k_scale"] = jnp.zeros(
+            paged_scale_shape(n_blocks, bs, hk), jnp.float32)
         cache["v_scale"] = cache["k_scale"]
     ks, vs = (rng.standard_normal((b, 40, hk, d)).astype(np.float32)
               for _ in range(2))
