@@ -1,3 +1,4 @@
+from chainermn_tpu.models.laguna import LagunaLM
 from chainermn_tpu.models.mlp import MLP
 from chainermn_tpu.models.resnet import (
     AlexNet,
@@ -32,6 +33,7 @@ __all__ = [
     "InceptionBlock",
     "VGG16",
     "KVCacheKind",
+    "LagunaLM",
     "SmallThinkerLM",
     "TransformerBlock",
     "TransformerLM",

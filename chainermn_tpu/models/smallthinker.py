@@ -42,22 +42,85 @@ from chainermn_tpu.models.transformer import KVCacheKind
 from chainermn_tpu.parallel.moe import DroplessMoE
 
 
-def rope(q, k, pos, theta: float):
-    """Rotary embedding over the whole head, rotate-half pairing (entry
-    ``i`` pairs with ``i + D/2``): ``q [B, S, H, D]``, ``k [B, S, Hkv, D]``,
-    ``pos [B, S]``. Angles in float32."""
-    half = q.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None] * inv_freq       # [B, S, D/2]
+def rope_inv_freq(theta: float, rotary_dim: int):
+    """The plain rotary table: entry ``j`` of ``rotary_dim / 2`` turns by
+    ``theta^(-2j / rotary_dim)`` a position."""
+    half = rotary_dim // 2
+    return theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def rope(q, k, pos, inv_freq, factor: float = 1.0):
+    """Rotary embedding with rotate-half pairing over the first ``2 *
+    len(inv_freq)`` entries of each head (entry ``i`` pairs with ``i +
+    len(inv_freq)``; the entries past them pass through): ``q [B, S, H,
+    D]``, ``k [B, S, Hkv, D]``, ``pos [B, S]``, ``inv_freq`` the angle each
+    pair turns by a position, ``factor`` what cos and sin are multiplied
+    by (YaRN's attention factor). Angles in float32."""
+    half = inv_freq.shape[0]
+    ang = pos.astype(jnp.float32)[..., None] * inv_freq       # [B, S, half]
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, :, None]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, :, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
 
     def turn(x):
-        x32 = x.astype(jnp.float32)
+        partial = 2 * half < x.shape[-1]
+        x32 = (x[..., :2 * half] if partial else x).astype(jnp.float32)
         rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
-        return (x32 * cos + rot * sin).astype(x.dtype)
+        out = (x32 * cos + rot * sin).astype(x.dtype)
+        if partial:
+            out = jnp.concatenate([out, x[..., 2 * half:]], -1)
+        return out
 
     return turn(q), turn(k)
+
+
+def attend_through_cache(q, k, v, pos, kv_cache, window: Optional[int]):
+    """Causal attention of ``q [B, S, H, D]`` over ``k, v [B, S, Hkv, D]``
+    as a served decoder block runs it, ``(o [B, S, H, D], new_cache)``:
+    one token a row (``S == 1`` with a cache) is written through the block
+    table and read back by the paged kernel or its XLA twin; whole fresh
+    prompts from position 0 attend their own K/V with the flash kernel,
+    grouped and windowed, and are written to the store beside it where
+    there is one."""
+    from chainermn_tpu.ops import flash_attention
+    from chainermn_tpu.parallel.sequence import (
+        paged_update_cache_and_attend,
+        paged_write_kv,
+    )
+
+    if kv_cache is not None and q.shape[1] == 1:
+        return paged_update_cache_and_attend(kv_cache, q, k, v, pos[:, 0])
+    new_cache = None
+    if kv_cache is not None:
+        new_cache = paged_write_kv(kv_cache, k, v, pos[:, 0])
+    return flash_attention(q, k, v, causal=True, window=window), new_cache
+
+
+def full_and_window_kinds(window_layers, window: int, kv_heads: int,
+                          head_dim: int) -> tuple:
+    """What a model of full and window layers tells the serving engine
+    (``kv_cache_spec()``): two kinds, full layers keep every token, window
+    layers the ``window`` positions a query sees (the engine adds a block,
+    for the one being written). A layer's query heads are not the store's
+    business."""
+    kinds = []
+    for name, flag, span in (("full", False, None), ("window", True, window)):
+        layers = tuple(i for i, w in enumerate(window_layers)
+                       if bool(w) == flag)
+        if layers:
+            kinds.append(KVCacheKind(name, layers, kv_heads, head_dim, span))
+    return tuple(kinds)
+
+
+def token_positions(pos_offset, b: int, t: int):
+    """``[b, t]`` positions from what a model's ``pos_offset`` may be: a
+    scalar first position, a row ``[t]``, or the positions themselves."""
+    if jnp.ndim(pos_offset) == 2:
+        return pos_offset
+    return jnp.broadcast_to(
+        (pos_offset + jnp.arange(t)) if jnp.ndim(pos_offset) == 0
+        else pos_offset, (b, t))
 
 
 class SmallThinkerBlock(nn.Module):
@@ -76,12 +139,6 @@ class SmallThinkerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, pos, kv_cache=None):
-        from chainermn_tpu.ops import flash_attention
-        from chainermn_tpu.parallel.sequence import (
-            paged_update_cache_and_attend,
-            paged_write_kv,
-        )
-
         dt = self.compute_dtype
         b, s, _ = x.shape
         h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
@@ -93,15 +150,9 @@ class SmallThinkerBlock(nn.Module):
         k = dense(hk * dh, "k_proj")(a).reshape(b, s, hk, dh)
         v = dense(hk * dh, "v_proj")(a).reshape(b, s, hk, dh)
         if self.use_rope:
-            q, k = rope(q, k, pos, self.rope_theta)
-        new_cache = None
-        if kv_cache is not None and s == 1:
-            o, new_cache = paged_update_cache_and_attend(
-                kv_cache, q, k, v, pos[:, 0])
-        else:
-            if kv_cache is not None:
-                new_cache = paged_write_kv(kv_cache, k, v, pos[:, 0])
-            o = flash_attention(q, k, v, causal=True, window=self.window)
+            q, k = rope(q, k, pos, rope_inv_freq(self.rope_theta, dh))
+        o, new_cache = attend_through_cache(q, k, v, pos, kv_cache,
+                                            self.window)
         x = x + dense(self.d_model, "o_proj")(o.reshape(b, s, h * dh))
         m = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=dt, name="norm_2")(x)
         y = DroplessMoE(
@@ -141,18 +192,9 @@ class SmallThinkerLM(nn.Module):
     tensor_axis: Optional[str] = None
 
     def kv_cache_spec(self) -> tuple:
-        """Two kinds: full layers keep every token, window layers the
-        ``window`` positions a query sees (the engine adds a block, for the
-        one being written)."""
-        kinds = []
-        for name, flag, window in (("full", 0, None),
-                                   ("window", 1, self.window)):
-            layers = tuple(i for i in range(self.n_layers)
-                           if bool(self.window_layers[i]) == bool(flag))
-            if layers:
-                kinds.append(KVCacheKind(name, layers, self.n_kv_heads,
-                                         self.head_dim, window))
-        return tuple(kinds)
+        return full_and_window_kinds(self.window_layers[:self.n_layers],
+                                     self.window, self.n_kv_heads,
+                                     self.head_dim)
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, kv_caches=None, logits_at=None):
@@ -161,12 +203,7 @@ class SmallThinkerLM(nn.Module):
             raise ValueError("window_layers and rope_layers name every layer")
         dt = self.compute_dtype
         b, t = tokens.shape
-        if jnp.ndim(pos_offset) == 2:
-            pos = pos_offset
-        else:
-            pos = jnp.broadcast_to(
-                (pos_offset + jnp.arange(t)) if jnp.ndim(pos_offset) == 0
-                else pos_offset, (b, t))
+        pos = token_positions(pos_offset, b, t)
         x = nn.Embed(self.vocab_size, self.d_model, dtype=dt,
                      name="embed")(tokens)
         new_caches = []
@@ -192,4 +229,5 @@ class SmallThinkerLM(nn.Module):
         return logits
 
 
-__all__ = ["SmallThinkerBlock", "SmallThinkerLM", "rope"]
+__all__ = ["SmallThinkerBlock", "SmallThinkerLM", "attend_through_cache",
+           "full_and_window_kinds", "rope", "rope_inv_freq", "token_positions"]

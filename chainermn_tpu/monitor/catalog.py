@@ -62,6 +62,8 @@ METRIC_NAMES = frozenset({
     "kv_shares_total",
     "share_payload_cache_evictions_total",
     "share_payload_cache_hits_total",
+    "moe_assignments_local_total",
+    "moe_assignments_total",
     "prefill_chunks_total",
     "prefill_batch_size",
     "prefill_rows_filled_total",

@@ -30,6 +30,8 @@ Usage (inside a step traced over ``comm``'s mesh)::
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -415,24 +417,67 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
     return out[:m] if pad else out
 
 
+_ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
+
+
+class GatedMLP(nn.Module):
+    """``(act(x @ gate) * (x @ up)) @ down`` without biases: a model's dense
+    feed-forward layer, and the shared expert of :class:`DroplessMoE`. Its
+    device operations read ``<name>/gate_proj`` and so on."""
+
+    d_model: int
+    d_ff: int
+    activation: str = "silu"
+    compute_dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False,
+                                         dtype=self.compute_dtype, name=name)
+        g = dense(self.d_ff, "gate_proj")(x)
+        u = dense(self.d_ff, "up_proj")(x)
+        return dense(self.d_model, "down_proj")(
+            _ACTIVATIONS[self.activation](g) * u)
+
+
 class DroplessMoE(nn.Module):
     """Top-k routing over many small gated experts with no capacity and no
-    drop: every token's ``top_k`` assignments are computed, however uneven
-    the routing. Every expert is held here: there is no exchange, so the
+    drop: every token's ``top_k`` assignments to the experts held here are
+    computed, however uneven the routing. There is no exchange, so the
     layer runs on one chip.
 
     ``x [..., d]`` goes through the experts, ``router_in`` (default ``x``)
-    is what the router reads. Routing weights are the softmax over the
-    ``top_k`` selected logits. An expert is ``(act(x @ w_gate) * (x @
-    w_up)) @ w_down`` without biases.
+    is what the router reads. Routing weights are ``weight_scale`` times the
+    softmax over the ``top_k`` selected logits (which is the softmax over
+    all experts, normalised over the selected). An expert is ``(act(x @
+    w_gate) * (x @ w_up)) @ w_down`` without biases, ``act`` being
+    ``activation``.
+
+    ``held = (first, count)`` makes the layer one chip's share of an
+    expert-parallel deployment: the router and the top-k still go over all
+    ``n_experts``, the weights hold experts ``first .. first + count - 1``
+    only, and an assignment to any other expert is neither multiplied nor
+    weighed: the result is this chip's part of the routed sum, to which the
+    other chips' parts would be added. Such assignments sort behind the
+    last group held, where the grouped product (whose grid follows the
+    groups' sizes) visits no tile; their output rows come back undefined and
+    are replaced by zeros before the weighted sum. ``shared_d_ff > 0`` adds
+    a shared expert (:class:`GatedMLP`, the same activation, every token,
+    no routing weight), whole on every chip.
 
     One code path for a prefill of thousands of tokens and a decode step
     of a hundred: assignments are sorted by expert, rows gathered in that
     order, three grouped products (:func:`grouped_matmul`) over the
     experts, and the rows gathered back and summed with their weights.
     Under ``jax.named_scope`` the device operations read ``moe/route``
-    (router, top-k, sort, gather), ``moe/experts`` (the products) and
-    ``moe/combine``.
+    (router, top-k, sort, gather), ``moe/experts`` (the products),
+    ``moe/combine`` and ``moe/shared``.
+
+    With ``held`` set and the collection ``serving_stats`` mutable, the
+    layer sows ``moe_local`` and ``moe_total``, ``[...]`` int32: each
+    token's assignments to experts held here, and all of them
+    (``top_k``); :class:`~chainermn_tpu.serving.ServingEngine` sums them
+    over the real tokens of a program.
 
     Stands beside :class:`ExpertParallelMLP` and :class:`GShardMoE`, which
     drop at a capacity and route top-1/2."""
@@ -442,19 +487,28 @@ class DroplessMoE(nn.Module):
     d_ff: int
     top_k: int
     compute_dtype: jnp.dtype = jnp.bfloat16
+    activation: str = "relu"
+    weight_scale: float = 1.0
+    held: Optional[tuple] = None        # (first, count) of n_experts
+    shared_d_ff: int = 0
 
     @nn.compact
     def __call__(self, x, router_in=None):
         if not 0 < self.top_k <= self.n_experts:
             raise ValueError(f"top_k {self.top_k} of {self.n_experts}")
+        first, count = self.held or (0, self.n_experts)
+        if not (0 <= first and 0 < count and first + count <= self.n_experts):
+            raise ValueError(
+                f"held {self.held} lies outside {self.n_experts} experts")
         dt = self.compute_dtype
         d, f, k, n = self.d_model, self.d_ff, self.top_k, self.n_experts
+        act = _ACTIVATIONS[self.activation]
         init = nn.initializers.normal(d ** -0.5)
         w_router = self.param("router", init, (d, n))
-        w_gate = self.param("w_gate", init, (n, d, f))
-        w_up = self.param("w_up", init, (n, d, f))
+        w_gate = self.param("w_gate", init, (count, d, f))
+        w_up = self.param("w_up", init, (count, d, f))
         w_down = self.param("w_down", nn.initializers.normal(f ** -0.5),
-                            (n, f, d))
+                            (count, f, d))
         lead = x.shape[:-1]
         x = x.reshape(-1, d).astype(dt)
         r_in = x if router_in is None else router_in.reshape(-1, d)
@@ -462,43 +516,71 @@ class DroplessMoE(nn.Module):
         def one_pass(x, r_in):
             t = x.shape[0]
             with jax.named_scope("route"):
-                # the router is 64 columns: float32 at full precision costs
-                # nothing and keeps near-ties where the reference has them
+                # the router is a few dozen columns: float32 at full
+                # precision costs nothing and keeps near-ties where the
+                # reference has them
                 logits = jnp.dot(r_in.astype(jnp.float32),
                                  w_router.astype(jnp.float32),
                                  precision=lax.Precision.HIGHEST)
                 top, idx = lax.top_k(logits, k)                  # [t, k]
                 w = jax.nn.softmax(top, axis=-1)
+                if self.weight_scale != 1.0:
+                    w = w * self.weight_scale
                 expert = idx.reshape(-1)                         # [t * k]
+                if self.held is not None:
+                    # the group of an assignment among those held; what is
+                    # not held sorts behind the last of them
+                    here = (idx >= first) & (idx < first + count)
+                    expert = jnp.where(here.reshape(-1), expert - first,
+                                       count)
                 order = jnp.argsort(expert, stable=True)
                 sizes = jnp.sum(
-                    expert[:, None] == jnp.arange(n)[None, :], axis=0,
+                    expert[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
                 # (indices are a permutation's: no bounds to fill for)
                 rows = x.at[order // k].get(mode="promise_in_bounds")
             with jax.named_scope("experts"):
                 g = grouped_matmul(rows, w_gate.astype(dt), sizes, dt)
                 u = grouped_matmul(rows, w_up.astype(dt), sizes, dt)
-                h = (nn.relu(g) * u).astype(dt)
+                h = (act(g) * u).astype(dt)
                 y = grouped_matmul(h, w_down.astype(dt), sizes, dt)
             with jax.named_scope("combine"):
                 back = jnp.zeros_like(order).at[order].set(
                     jnp.arange(t * k, dtype=order.dtype))
                 y = y.at[back].get(mode="promise_in_bounds",
                                    unique_indices=True).reshape(t, k, d)
-                out = jnp.einsum("tk,tkd->td", w, y.astype(jnp.float32))
-            return out.astype(dt)
+                y = y.astype(jnp.float32)
+                if self.held is not None:
+                    # rows no product wrote hold whatever was there
+                    y = jnp.where(here[..., None], y, 0.0)
+                out = jnp.einsum("tk,tkd->td", w, y)
+            if self.held is None:
+                return out.astype(dt)
+            return out.astype(dt), jnp.sum(here, axis=-1, dtype=jnp.int32)
 
         t = x.shape[0]
         if t > _TOKEN_PASS and t % _TOKEN_PASS == 0:
-            out = lax.map(
-                lambda xr: one_pass(*xr),
-                (x.reshape(-1, _TOKEN_PASS, d),
-                 r_in.reshape(-1, _TOKEN_PASS, d))).reshape(t, d)
+            out = jax.tree_util.tree_map(
+                lambda a: a.reshape((t,) + a.shape[2:]), lax.map(
+                    lambda xr: one_pass(*xr),
+                    (x.reshape(-1, _TOKEN_PASS, d),
+                     r_in.reshape(-1, _TOKEN_PASS, d))))
         else:
             out = one_pass(x, r_in)
+        if self.held is not None:
+            out, local = out
+            if (self.is_mutable_collection("serving_stats")
+                    and not self.is_initializing()):
+                local = local.reshape(lead)
+                self.sow("serving_stats", "moe_local", local)
+                self.sow("serving_stats", "moe_total",
+                         jnp.full_like(local, k))
+        if self.shared_d_ff:
+            out = out + GatedMLP(
+                d_model=d, d_ff=self.shared_d_ff, activation=self.activation,
+                compute_dtype=dt, name="shared")(x)
         return out.reshape(lead + (d,))
 
 
-__all__ = ["DroplessMoE", "ExpertParallelMLP", "GShardMoE",
+__all__ = ["DroplessMoE", "ExpertParallelMLP", "GatedMLP", "GShardMoE",
            "MoeStatsAccumulator", "drop_frac_from_sown", "grouped_matmul"]

@@ -286,6 +286,29 @@ class EngineStateError(RuntimeError):
     in-flight work and warm-restart."""
 
 
+# the collection a served model may sow counters into (parallel/moe.py)
+_STATS = "serving_stats"
+
+
+def _with_moe_counts(nxt, stats, real):
+    """``nxt`` as a paged program hands it back, with two more entries where
+    the model's expert layers sowed their per-token counts (a share of the
+    experts held here, ``DroplessMoE.held``): the assignments of the
+    program's ``real`` tokens ``[B, S]`` to experts held, and all of them,
+    summed over the layers. They ride the fetch the tokens make anyway. A
+    model that sows nothing gets ``nxt`` as it is."""
+    leaves = jax.tree_util.tree_flatten_with_path(stats)[0]
+    if not leaves:
+        return nxt
+
+    def total(name):
+        return sum(jnp.sum(jnp.where(real, leaf, 0)) for path, leaf in leaves
+                   if name in jax.tree_util.keystr(path))
+
+    return jnp.concatenate([nxt, jnp.stack(
+        [total("moe_local"), total("moe_total")]).astype(nxt.dtype)])
+
+
 class ServingEngine:
     """Slot-pool KV-cache decode engine (mechanism only — admission policy,
     EOS retirement, and per-request bookkeeping live in
@@ -592,6 +615,7 @@ class ServingEngine:
         self._c_decode_steps = reg.counter("serving_decode_steps_total",
                                            decode_labels)
         self.peak_active = 0
+        self._moe_counts = np.zeros(2, np.int64)
         self.prefix_cache: Optional[PrefixCacheIndex] = None
         if self.paged:
             if prefix_cache_blocks:
@@ -914,14 +938,16 @@ class ServingEngine:
                          if self._windowed else None)
                 caches = self._layer_caches(store, table, valid=valid)
                 pos = starts[:, None] + jnp.arange(bucket)[None, :]
-                lg, new_store = model.apply(
+                (lg, new_store), stats = model.apply(
                     params, tokens, pos, kv_caches=caches,
-                    logits_at=last_idx)
+                    logits_at=last_idx, mutable=[_STATS])
                 if vocab_gather is not None:
                     lg = vocab_gather(lg)
                 nxt, keys = jax.vmap(slot_sample)(lg, keys)
                 nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
-                return new_store, nxt, keys
+                real = active[:, None] & (
+                    jnp.arange(bucket)[None, :] <= last_idx[:, None])
+                return new_store, _with_moe_counts(nxt, stats, real), keys
 
         return body
 
@@ -944,14 +970,16 @@ class ServingEngine:
         def body(params, store, table, tokens, pos, active, keys):
             with annotate("chainermn.decode"):
                 caches = self._layer_caches(store, table, **extra)
-                lg, new_store = model.apply(params, tokens[:, None],
-                                            pos[:, None], kv_caches=caches)
+                (lg, new_store), stats = model.apply(
+                    params, tokens[:, None], pos[:, None], kv_caches=caches,
+                    mutable=[_STATS])
                 lg = lg[:, 0]
                 if vocab_gather is not None:
                     lg = vocab_gather(lg)
                 nxt, keys = jax.vmap(slot_sample)(lg, keys)
                 nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
-                return new_store, nxt, keys
+                return (new_store,
+                        _with_moe_counts(nxt, stats, active[:, None]), keys)
 
         return body
 
@@ -1751,7 +1779,7 @@ class ServingEngine:
                         jnp.asarray(tokens), jnp.asarray(starts),
                         jnp.asarray(last), jnp.asarray(active),
                         jnp.stack(keys))
-                    firsts = device_fetch(firsts)
+                    firsts = self._take_moe_counts(device_fetch(firsts), k)
             except Exception as e:
                 for slot, ids in alloc_records:   # undo: nothing admitted
                     self._paged_unalloc_slot(slot, ids)
@@ -2563,7 +2591,7 @@ class ServingEngine:
             state, nxt, self._keys = self._decode_fn(*args)
             self._set_kv_state(state)
             with annotate("chainermn.serving_decode_fetch"):
-                nxt = device_fetch(nxt)
+                nxt = self._take_moe_counts(device_fetch(nxt), self.n_slots)
         with annotate("chainermn.serving_decode_post"):
             self._c_decode_steps.inc()
             self._events.emit("decode_step", active=int(self._active.sum()))
@@ -2727,6 +2755,26 @@ class ServingEngine:
         splits accepted-vs-wasted verify work with. Unlike
         :meth:`pop_spec_window` this is NOT cleared on read."""
         return self._last_spec_slots
+
+    def _take_moe_counts(self, fetched, rows: int):
+        """``fetched[:rows]``, the tokens; what a program appended past them
+        (:func:`_with_moe_counts`) is added to the counts
+        :meth:`pop_moe_counts` hands on."""
+        if len(fetched) > rows:
+            self._moe_counts += fetched[rows:rows + 2]
+        return fetched[:rows]
+
+    def pop_moe_counts(self) -> Optional[tuple]:
+        """``(local, total)``: expert assignments of the tokens processed
+        since the last call, to experts held here and in all, cleared on
+        read; ``None`` where no program counted any (a model whose expert
+        layers hold every expert, or none). The scheduler drains it into
+        :class:`~chainermn_tpu.serving.metrics.ServingMetrics`."""
+        if not self._moe_counts[1]:
+            return None
+        local, total = (int(c) for c in self._moe_counts)
+        self._moe_counts[:] = 0
+        return local, total
 
     def pop_spec_window(self) -> Optional[tuple]:
         """``(proposed, accepted, accept_lengths)`` of the last verify

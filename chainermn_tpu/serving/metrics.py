@@ -95,6 +95,8 @@ class ServingMetrics:
         self._labels = labels
         self._c_rows: dict[int, tuple] = {}
         self._h_cached = reg.histogram("cached_prefix_frac", labels)
+        self._c_moe_local = reg.counter("moe_assignments_local_total", labels)
+        self._c_moe_total = reg.counter("moe_assignments_total", labels)
         self._g_queue = reg.gauge("serving_queue_depth_now", labels)
         self._g_active = reg.gauge("serving_active_slots", labels)
         # paged-KV series (PR 7): store occupancy gauges sampled per step,
@@ -192,6 +194,14 @@ class ServingMetrics:
         c_filled, c_run = self._c_rows[bucket]
         c_filled.inc(filled)
         c_run.inc(run)
+
+    def record_moe_assignments(self, local: int, total: int) -> None:
+        """Expert assignments of the tokens some programs processed:
+        ``total`` in all (tokens x sparse layers x top_k), ``local`` of them
+        to experts this engine's model holds (a share of an expert-parallel
+        deployment, ``DroplessMoE.held``)."""
+        self._c_moe_local.inc(local)
+        self._c_moe_total.inc(total)
 
     def record_token(self, t_prev_token: float, t_token: float) -> None:
         self._h_tpot.observe(t_token - t_prev_token)
@@ -426,6 +436,9 @@ class ServingMetrics:
             filled = sum(f.value for f, _ in self._c_rows.values())
             run = sum(r.value for _, r in self._c_rows.values())
             out["prefill_fill_share"] = round(filled / run, 4)
+        if self._c_moe_total.value:
+            out["moe_local_share"] = round(
+                self._c_moe_local.value / self._c_moe_total.value, 4)
         for hist, prefix in ((self._h_queue, "queue_depth"),
                              (self._h_occ, "slot_occupancy")):
             samples = hist.samples

@@ -828,6 +828,10 @@ class FCFSScheduler:
                 window = self.engine.pop_spec_window()
                 if window is not None:
                     self.metrics.record_spec_window(*window)
+            pop = getattr(self.engine, "pop_moe_counts", None)
+            counts = pop() if pop is not None else None
+            if counts is not None:
+                self.metrics.record_moe_assignments(*counts)
             with self._lock:
                 depth = len(self._queue)
                 batch_depth = sum(1 for r in self._queue

@@ -1,18 +1,24 @@
 #!/usr/bin/env python
-"""Chip-free: does the benchmark's comparison for ``st21b-l8-serve-short-long``
-fit one v5e chip at a given ``cache_len``?
+"""Chip-free: does the benchmark's comparison for a serving cell fit one v5e
+chip beside what the harness keeps alive?
+
+    python scripts/aot_st21b_reference_fit.py CONFIG TRAFFIC [cache_len ...]
+    python scripts/aot_st21b_reference_fit.py smallthinker-21b-a3b-l8 \
+        short-and-long 8704 6656
+    python scripts/aot_st21b_reference_fit.py laguna-s-2.1-l5-ep2 \
+        short-and-long-w512
 
 Compiles ``benchmarks/harness/correct.py:_gap_fn`` (the plain reference of
-``smallthinker-21b-a3b-l8`` over one sequence of ``cache_len`` positions, as
-``check_served`` calls it) against the real TPU compiler for an abstract v5e
-target, with the weights as shapes, and prints the compiler's memory
-analysis: arguments (7.93 GB of bfloat16 weights) + temporaries is what the
-chip must hold, of 15.75 GiB; a refusal prints the compiler's message.
-ISSUE 29 named ``cache_len`` 8704 and gave 6656 as the fallback if the
-reference did not fit (PERF.md section 4). Counts only: no time comes from
-here.
-
-    python scripts/aot_st21b_reference_fit.py 8704 6656
+``benchmarks/configs/CONFIG.json`` over one sequence of ``cache_len``
+positions, as ``check_served`` calls it; the default is the ``cache_len`` of
+``benchmarks/traffic/TRAFFIC.json``) against the real TPU compiler for an
+abstract v5e target, with the weights as shapes, and prints the compiler's
+memory analysis: arguments (the bfloat16 weights) + temporaries is what the
+chip must hold, of 15.75 GiB, beside the engine's pools, which the harness
+keeps alive while the reference runs (PERF.md section 7); a refusal prints
+the compiler's message. ISSUE 29 named ``cache_len`` 8704 and gave 6656 as
+the fallback if the reference did not fit (PERF.md section 4). Counts only:
+no time comes from here.
 """
 
 import json
@@ -26,7 +32,7 @@ sys.path.insert(0, _ROOT)
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
-def main(lengths):
+def main(name, traffic, lengths):
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -37,8 +43,9 @@ def main(lengths):
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    name = "smallthinker-21b-a3b-l8"
     cfg = common.load_json("configs", name + ".json")
+    lengths = lengths or [int(common.load_json(
+        "traffic", traffic + ".json")["engine"]["cache_len"])]
     shapes = families.init_shapes(cfg, families.build_model(cfg))
     dtype = families.param_dtype(cfg)
     params = jax.tree_util.tree_map(
@@ -65,4 +72,6 @@ def main(lengths):
 
 
 if __name__ == "__main__":
-    main([int(a) for a in sys.argv[1:]] or [8704, 6656])
+    if len(sys.argv) < 3:
+        raise SystemExit(__doc__)
+    main(sys.argv[1], sys.argv[2], [int(a) for a in sys.argv[3:]])
