@@ -2423,20 +2423,30 @@ class ServingEngine:
             return 0
         return sum(len(kv.slot_blocks[slot]) for kv in self._kv)
 
-    def slot_block_shares(self, slot: int) -> float:
-        """Refcount-weighted block count the slot holds RIGHT NOW (0.0
-        in dense mode): a private block counts 1, a prefix block shared
-        by ``r`` live holders counts ``1/r`` — so summing this over all
-        holders always reproduces the pool's true occupancy. The cost
-        ledger integrates it into per-tenant KV block-seconds."""
+    def slot_block_shares(self) -> np.ndarray:
+        """Refcount-weighted block count every slot holds RIGHT NOW,
+        ``[n_slots]`` (zeros in dense mode): a private block counts 1, a
+        prefix block shared by ``r`` live holders counts ``1/r`` — so
+        summing this over all holders always reproduces the pool's true
+        occupancy. The cost ledger integrates it into per-tenant KV
+        block-seconds, once a step: one pass over the tables, no call a
+        block."""
         if not self.paged:
-            return 0.0
+            return np.zeros(self.n_slots)
         if self.prefix_cache is None:
             # blocks are shared through the trie alone: without one every
             # block has the one holder, and counting them walks nothing
-            return float(sum(len(kv.slot_blocks[slot]) for kv in self._kv))
-        return sum(1.0 / max(kv.pool.refs(b), 1)
-                   for kv in self._kv for b in kv.slot_blocks[slot])
+            return np.sum([[len(held) for held in kv.slot_blocks]
+                           for kv in self._kv], axis=0, dtype=np.float64)
+        # a trie means one kind of KV state, and a slot's table names the
+        # blocks it holds and scratch — but for a chunked prefill's,
+        # staged beside the table until its last chunk
+        tables = self._tables
+        if self._chunking:
+            tables = tables.copy()
+            for slot, st in self._chunking.items():
+                tables[slot, : len(st.ids)] = st.ids
+        return self._pool.shares(tables)
 
     def kv_pool_stats(self) -> tuple[int, int, int]:
         """(blocks in use, blocks free, blocks live) — the scheduler

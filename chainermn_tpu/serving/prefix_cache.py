@@ -32,6 +32,14 @@ Design points:
   least-recently-used ref-zero leaves are evicted (hits refresh the whole
   matched path). Partial allocations are fine — caching a prompt's first
   few blocks is still useful.
+- **Bookkeeping costs what changed, never the size of the trie.** The
+  serving step asks "which block goes" for every block it hands out from
+  a dry pool and "how many could go" for every admission, so neither
+  walks: the ref-zero leaves wait in a heap ordered by ``last_use``
+  whose entries are checked when they surface (:meth:`PrefixCacheIndex.
+  _coldest`), and a per-block count of unpinned subtrees beside the
+  pool's refcounts answers :meth:`evictable_blocks` in one vector
+  operation. Only ``clear()`` visits every node.
 - **Shared-pool (paged) mode.** Pass ``pool=`` to make the trie allocate
   from the same :class:`BlockPool` the engine's decode slots draw from:
   inserts then *adopt* a slot's already-resident blocks
@@ -55,6 +63,7 @@ intentionally not thread-safe).
 
 from __future__ import annotations
 
+import heapq
 import itertools
 from dataclasses import dataclass, field
 from typing import Optional
@@ -110,6 +119,30 @@ class BlockPool:
     def refs(self, block: int) -> int:
         return int(self._refs[block])
 
+    def ref_counts(self) -> np.ndarray:
+        """Every block's holders by block id — a read-only view, for
+        whoever asks about the whole store at once."""
+        view = self._refs.view()
+        view.flags.writeable = False
+        return view
+
+    def shares(self, ids: np.ndarray) -> np.ndarray:
+        """Per row of block ids, ``sum(1 / max(refs(b), 1))`` with the
+        scratch block counting nothing: what each row's holder owes when
+        a block's cost is split between its holders, for all rows in one
+        pass. The floats are those of Python's ``sum`` over the row, in
+        any order: each share is split into a multiple of ``2**-34`` and
+        the rest, both parts add up exactly whatever the order (a row
+        would need 2**18 blocks to round), and the one rounding left is
+        the last addition — the correctly rounded sum, which is also what
+        ``sum``'s compensated loop returns."""
+        inv = 1.0 / np.maximum(self._refs, 1)
+        if self.scratch is not None:
+            inv[self.scratch] = 0.0
+        hi = np.round(inv * 2.0 ** 34) * 2.0 ** -34
+        lo = inv - hi
+        return hi[ids].sum(axis=-1) + lo[ids].sum(axis=-1)
+
     def alloc(self) -> Optional[int]:
         """One free block at refcount 1, or ``None`` when the pool is dry
         (the caller may then evict trie leaves and retry)."""
@@ -144,14 +177,17 @@ class BlockPool:
 class _Node:
     """One cached block: ``block_size`` tokens -> one device store block."""
 
-    __slots__ = ("key", "block", "parent", "children", "refs", "last_use")
+    __slots__ = ("key", "block", "parent", "children", "refs", "pins",
+                 "queued", "last_use")
 
     def __init__(self, key, block, parent):
         self.key = key            # tuple of block_size token ints
         self.block = block        # index into the device block store
-        self.parent = parent
+        self.parent = parent      # None once out of the trie
         self.children: dict = {}
         self.refs = 0             # active matches/insert-plans pinning here
+        self.pins = 0             # refs of this node and every node below
+        self.queued = False       # the eviction heap holds an entry for it
         self.last_use = 0
 
 
@@ -216,6 +252,17 @@ class PrefixCacheIndex:
         self.block_size = int(block_size)
         self._root = _Node(None, -1, None)
         self._clock = itertools.count(1)
+        self._n_nodes = 0
+        # eviction order: ``(last_use, push number, node)``, one entry a
+        # node at most (``_Node.queued``). Every ref-zero leaf has one; an
+        # entry may be stale (the node since pinned, extended, or touched,
+        # so its key is at most the node's ``last_use``): ``_coldest``
+        # sorts that out when the entry reaches the top
+        self._lru: list = []
+        self._pushes = itertools.count()
+        # per block of the store: the trie nodes on it with no pin at or
+        # below them — with the pool's refcounts, ``evictable_blocks()``
+        self._idle = np.zeros(pool.n_blocks, np.int32)
         # single-writer contract (same as BlockPool): the scheduler
         # thread owns all trie mutation; enforced when the sanitizer is on
         self._mut = sanitizer.mutation_guard("PrefixCacheIndex")
@@ -266,7 +313,7 @@ class PrefixCacheIndex:
                 self.misses += 1
                 self._c_misses.inc()
                 return None
-            nodes[-1].refs += 1
+            self._pin(nodes[-1], 1)
             t = next(self._clock)
             for nd in nodes:
                 nd.last_use = t
@@ -336,7 +383,7 @@ class PrefixCacheIndex:
             return
         with self._mut:
             match.released = True
-            match.nodes[-1].refs -= 1
+            self._pin(match.nodes[-1], -1)
 
     # ------------------------------------------------------------------ #
     # insertion                                                           #
@@ -363,10 +410,10 @@ class PrefixCacheIndex:
                 node, i = child, i + 1
             if i >= total:
                 return None
-            node.refs += 1                # pin the attachment point
+            self._pin(node, 1)            # pin the attachment point
             blocks = self.alloc_blocks(total - i)
             if not blocks:
-                node.refs -= 1
+                self._pin(node, -1)
                 return None
         return InsertPlan(
             parent=node,
@@ -381,14 +428,24 @@ class PrefixCacheIndex:
         with self._mut:
             plan.closed = True
             node = plan.parent
-            node.refs -= 1
+            if not self._attached(node):
+                return                    # planned before a clear()
             t = next(self._clock)
+            n = 0
             for key, block in zip(plan.keys, plan.block_ids):
-                child = _Node(key, block, node)
-                child.last_use = t
-                node.children[key] = child
+                child = node.children.get(key)
+                if child is None:
+                    child = self._link(node, key, block, t)
+                    n += 1
+                else:
+                    # cached since the plan by another holder of the same
+                    # prompt: theirs stays, and linking over it would
+                    # leave a chain that nothing can reach or evict
+                    child.last_use = t
+                    self.pool.decref(block)
                 node = child
-            n = len(plan.block_ids)
+            self._pin(plan.parent, -1)
+            self._queue(node)
             self.inserted_blocks += n
         self._c_inserted.inc(n)
         self._events.emit("prefix_insert", blocks=n,
@@ -400,7 +457,7 @@ class PrefixCacheIndex:
             return
         with self._mut:
             plan.closed = True
-            plan.parent.refs -= 1
+            self._pin(plan.parent, -1)
             for block in plan.block_ids:
                 self.pool.decref(block)
 
@@ -429,11 +486,10 @@ class PrefixCacheIndex:
             for j in range(i, total):
                 block = int(block_ids[j])
                 self.pool.incref(block)
-                child = _Node(self._key(tokens, j), block, node)
-                child.last_use = t
-                node.children[child.key] = child
-                node = child
+                node = self._link(node, self._key(tokens, j), block, t)
                 adopted += 1
+            if adopted:
+                self._queue(node)
         if adopted:
             self.inserted_blocks += adopted
             self._c_inserted.inc(adopted)
@@ -445,15 +501,68 @@ class PrefixCacheIndex:
     # eviction / capacity                                                 #
     # ------------------------------------------------------------------ #
 
-    def _evictable(self):
-        """All ref-zero leaves (iterative walk; the store is small)."""
-        out, stack = [], [self._root]
-        while stack:
-            node = stack.pop()
-            stack.extend(node.children.values())
-            if node is not self._root and not node.children and not node.refs:
-                out.append(node)
-        return out
+    def _attached(self, node) -> bool:
+        """False for a node of a trie that ``clear()`` dropped under a
+        holder's match or plan, or that was evicted."""
+        return node.parent is not None or node is self._root
+
+    def _link(self, parent, key, block, t):
+        child = _Node(key, block, parent)
+        child.last_use = t
+        parent.children[key] = child
+        self._idle[block] += 1            # fresh: nothing pins it yet
+        self._n_nodes += 1
+        return child
+
+    def _pin(self, node, by: int) -> None:
+        """``node.refs += by`` and what follows from it: a pin counts at
+        the node and at every ancestor (a pinned tail protects its whole
+        chain), so their blocks leave the idle count with the first pin
+        below them and come back with the last."""
+        node.refs += by
+        if not self._attached(node):
+            return
+        root, at = self._root, node
+        while at is not root:
+            was = at.pins
+            at.pins = was + by
+            if was == 0:
+                self._idle[at.block] -= 1
+            elif at.pins == 0:
+                self._idle[at.block] += 1
+            at = at.parent
+        if by < 0:
+            self._queue(node)
+
+    def _queue(self, node) -> None:
+        """Enter ``node`` into the eviction order if it is a ref-zero
+        leaf — called wherever a node may have become one: the end of a
+        linked chain, an unpin, the eviction of a parent's last child."""
+        if (node is not self._root and not node.children and not node.refs
+                and not node.queued):
+            node.queued = True
+            heapq.heappush(self._lru,
+                           (node.last_use, next(self._pushes), node))
+
+    def _coldest(self):
+        """The least-recently-used ref-zero leaf, the next to be evicted
+        (``None`` when there is none), left at the top of the heap. An
+        entry whose node is no longer a ref-zero leaf is dropped (the
+        node is queued again when it next becomes one); one whose node
+        was touched since is put back under its ``last_use``, which only
+        ever grows, so nothing colder can lie below it."""
+        lru = self._lru
+        while lru:
+            t, _, node = lru[0]
+            if node.children or node.refs:
+                heapq.heappop(lru)
+                node.queued = False
+            elif t != node.last_use:
+                heapq.heapreplace(
+                    lru, (node.last_use, next(self._pushes), node))
+            else:
+                return node
+        return None
 
     def alloc_blocks(self, n: int) -> list:
         """Up to ``n`` blocks from the pool, evicting LRU ref-zero leaves
@@ -467,11 +576,16 @@ class PrefixCacheIndex:
                 if block is not None:
                     out.append(block)
                     continue
-                victims = self._evictable()
-                if not victims:
+                victim = self._coldest()
+                if victim is None:
                     break                  # partial allocation is fine
-                victim = min(victims, key=lambda nd: nd.last_use)
-                del victim.parent.children[victim.key]
+                heapq.heappop(self._lru)
+                parent = victim.parent
+                del parent.children[victim.key]
+                victim.parent = None
+                self._idle[victim.block] -= 1
+                self._n_nodes -= 1
+                self._queue(parent)
                 # may not free the block immediately: a paged decode slot
                 # still referencing it keeps it alive until that slot
                 # retires
@@ -481,10 +595,6 @@ class PrefixCacheIndex:
                 self._events.emit("prefix_evict", block=victim.block,
                                   age=victim.last_use)
         return out
-
-    # kept as the historical internal name (engine/test callers predate
-    # the shared-pool refactor)
-    _alloc = alloc_blocks
 
     def alloc_blocks_atomic(self, n: int) -> Optional[list]:
         """All-or-nothing :meth:`alloc_blocks`: exactly ``n`` blocks, or
@@ -505,22 +615,12 @@ class PrefixCacheIndex:
         list* right now: nodes in fully-unpinned subtrees whose block has
         no other holder (pool refcount 1). The scheduler's block-budget
         admission counts these on top of ``pool.free_blocks`` — a cached
-        but idle prefix is reclaimable capacity, not spent capacity."""
-        pool = self.pool
-
-        def walk(node):
-            unpinned = node is self._root or node.refs == 0
-            count = 0
-            for child in node.children.values():
-                child_ok, child_count = walk(child)
-                count += child_count
-                unpinned = unpinned and child_ok
-            if (node is not self._root and unpinned
-                    and pool.refs(node.block) == 1):
-                count += 1
-            return unpinned, count
-
-        return walk(self._root)[1]
+        but idle prefix is reclaimable capacity, not spent capacity. The
+        pool's refcounts move without the trie hearing of it (a slot
+        retires), so they are read here, against the running ``_idle``."""
+        if not self._n_nodes:
+            return 0
+        return int(self._idle[self.pool.ref_counts() == 1].sum())
 
     def clear(self) -> None:
         """Drop every cached prefix and release every trie-held block —
@@ -532,7 +632,17 @@ class PrefixCacheIndex:
         references (the engine resets the pool itself after dropping the
         slot tables)."""
         with self._mut:
+            # a match or plan that outlives its trie must find its nodes
+            # detached (``_attached``): the one walk of every node
+            stack = [self._root]
+            while stack:
+                node = stack.pop()
+                stack.extend(node.children.values())
+                node.parent = None
             self._root = _Node(None, -1, None)
+            self._n_nodes = 0
+            self._lru.clear()
+            self._idle[:] = 0
             if self._pool_private:
                 self.pool.reset()
 
@@ -558,6 +668,7 @@ class PrefixCacheIndex:
             "misses": self.misses,
             "hit_rate": round(self.hit_rate, 4),
             "evictions": self.evictions,
+            "evictable_blocks": self.evictable_blocks(),
             "inserted_blocks": self.inserted_blocks,
             "used_blocks": self.used_blocks,
             "n_blocks": self.n_blocks,

@@ -790,9 +790,10 @@ class FCFSScheduler:
                 # sampled once per step; shared prefix blocks split by
                 # live refcount so a popular prefix isn't billed N times
                 if self._t_block_sample is not None and rows_snapshot:
+                    shares = self.engine.slot_block_shares().tolist()
                     self.costs.record_block_seconds(
                         t_dec1 - self._t_block_sample,
-                        [(req.tenant, self.engine.slot_block_shares(slot))
+                        [(req.tenant, shares[slot])
                          for slot, req in rows_snapshot])
                 self._t_block_sample = t_dec1
         with annotate("chainermn.serving_deliver"):

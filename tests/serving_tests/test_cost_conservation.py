@@ -148,3 +148,55 @@ def test_migrated_request_books_migrate_kind(rig):
     _assert_conserved(sb)
     # the migrate seconds belong to the request's tenant, not overhead
     assert sa.costs.tenant_device_seconds()["bulk"] > 0.0
+
+
+# --------------------------------------------------------------------- #
+# block shares of all slots in one pass (ISSUE 34)                       #
+# --------------------------------------------------------------------- #
+
+
+def _per_block_shares(engine):
+    """What the ledger's shares are by definition: every block a slot
+    holds, split between its holders, summed block by block."""
+    kv = engine._kv[0]
+    return [sum(1.0 / max(kv.pool.refs(b), 1) for b in held)
+            for held in kv.slot_blocks]
+
+
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["decoding", "staged_chunks"])
+def test_all_slots_block_shares_equal_the_per_block_sums(rig, chunked):
+    """With a trie, ``slot_block_shares()`` reads every slot's share
+    through the tables in one pass. Two live requests on the prefix a
+    retired third left in the trie hold its blocks at a third each, and
+    a chunked prefill's staged blocks are in no table yet: the floats
+    are the per-block sums' to the last bit in both states."""
+    eng = rig.engine
+    sched = FCFSScheduler(eng, chunk_tokens_per_step=2 if chunked else None)
+    pool = eng._kv[0].pool
+    first = sched.submit(np.asarray([15, 16, 15, 16, 15], np.int32), 3,
+                         tenant="quiet")
+    sched.run_until_idle()
+    assert first.state is RequestState.DONE and not eng._kv[0].live
+    if chunked:
+        sched.submit(np.asarray([14, 13, 12, 11, 10, 9], np.int32),
+                     MAX_NEW, tenant="bulk")
+        sched.step()
+        assert eng._chunking                      # staged, not committed
+        (slot,) = eng._chunking
+        assert not eng._tables[slot].any()
+        assert eng.slot_block_shares()[slot] == 3.0
+    else:
+        for tail in (9, 7):
+            sched.submit(np.asarray([15, 16, 15, 16, tail], np.int32),
+                         30, tenant="bulk")
+            sched.step()                          # one admission a step
+        assert len(sched._by_slot) == 2
+        held = eng._kv[0].slot_blocks
+        assert {pool.refs(b) for b in held[0]} == {1, 3}   # thirds
+    want = _per_block_shares(eng)
+    assert eng.slot_block_shares().tolist() == want
+    assert sum(want) > 0.0
+    sched.run_until_idle()
+    assert eng.slot_block_shares().tolist() == [0.0, 0.0]
+    _assert_conserved(sched)

@@ -24,6 +24,7 @@ from chainermn_tpu.serving import (
     PrefixCacheIndex,
     ServingEngine,
 )
+from chainermn_tpu.serving.prefix_cache import BlockPool, _Node
 
 # --------------------------------------------------------------------- #
 # host trie (no jax, sub-millisecond)                                    #
@@ -99,6 +100,317 @@ def test_trie_abort_returns_blocks_and_unpins():
     assert idx.match(np.array([1, 2, 3])) is None  # nothing was linked
     idx.clear()
     assert idx.used_blocks == 0
+
+
+# --------------------------------------------------------------------- #
+# the index against the two walks it replaced (PR 34)                    #
+# --------------------------------------------------------------------- #
+
+
+def walk_evictable(idx):
+    """All ref-zero leaves, by a walk of every node: how the index found
+    its victim before it kept the eviction order itself."""
+    out, stack = [], [idx._root]
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children.values())
+        if node is not idx._root and not node.children and not node.refs:
+            out.append(node)
+    return out
+
+
+def walk_evictable_blocks(idx):
+    """Nodes in fully-unpinned subtrees whose block has no other holder,
+    by the recursive walk ``evictable_blocks()`` used to be."""
+    pool = idx.pool
+
+    def walk(node):
+        unpinned = node is idx._root or node.refs == 0
+        count = 0
+        for child in node.children.values():
+            child_ok, child_count = walk(child)
+            count += child_count
+            unpinned = unpinned and child_ok
+        if (node is not idx._root and unpinned
+                and pool.refs(node.block) == 1):
+            count += 1
+        return unpinned, count
+
+    return walk(idx._root)[1]
+
+
+class WalkIndex(PrefixCacheIndex):
+    """The index as it answered before: both questions by a walk. Driven
+    in lock-step with the real one, it says which block must go when."""
+
+    def alloc_blocks(self, n):
+        out = []
+        while len(out) < n:
+            block = self.pool.alloc()
+            if block is not None:
+                out.append(block)
+                continue
+            victims = walk_evictable(self)
+            if not victims:
+                break
+            victim = min(victims, key=lambda nd: nd.last_use)
+            del victim.parent.children[victim.key]
+            self.pool.decref(victim.block)
+            self.evictions += 1
+        return out
+
+    def evictable_blocks(self):
+        return walk_evictable_blocks(self)
+
+
+def trie_shape(idx):
+    """Every cached path with its block, pins and last use."""
+    out, stack = [], [((), idx._root)]
+    while stack:
+        path, node = stack.pop()
+        for key, child in node.children.items():
+            stack.append((path + key, child))
+            out.append((path + key, child.block, child.refs, child.last_use))
+    return sorted(out)
+
+
+class Driver:
+    """One index under the random walk's operations, with the slots,
+    matches and plans a caller would hold. Each method returns what the
+    caller could observe."""
+
+    def __init__(self, cls, shared, n_blocks=24, block_size=2):
+        if shared:
+            self.pool = BlockPool(n_blocks, reserve_scratch=True)
+            self.idx = cls(n_blocks, block_size, pool=self.pool)
+        else:
+            self.idx = cls(n_blocks, block_size)
+            self.pool = self.idx.pool
+        self.shared = shared
+        self.held, self.matches, self.plans = [], [], []
+
+    def admit(self, tokens, keep_match):
+        """A paged admission: reference the matched prefix, allocate the
+        rest, adopt the prompt's full blocks into the trie."""
+        idx, bs = self.idx, self.idx.block_size
+        m = idx.match(tokens)
+        shared = list(m.block_ids) if m is not None else []
+        new = idx.alloc_blocks_atomic(-(-len(tokens) // bs) - len(shared))
+        if new is None:
+            idx.release(m)
+            return None
+        for block in shared:
+            self.pool.incref(block)
+        if keep_match and m is not None:
+            self.matches.append(m)
+        else:
+            idx.release(m)
+        ids = shared + new
+        self.held.append(ids)
+        return ids, idx.insert_shared(tokens, ids)
+
+    def append(self, i, n):
+        got = self.idx.alloc_blocks(n)
+        if not self.held:
+            self.held.append([])
+        self.held[i].extend(got)
+        return got
+
+    def share(self, i):
+        """A second holder of a slot's first block, as a migration
+        import's or a fleet share's would be."""
+        block = self.held[i][0]
+        self.pool.incref(block)
+        self.held.append([block])
+        return block
+
+    def retire(self, i):
+        ids = self.held.pop(i)
+        for block in ids:
+            self.pool.decref(block)
+        return ids
+
+    def plan(self, tokens):
+        plan = self.idx.plan_insert(tokens)
+        if plan is not None:
+            self.plans.append(plan)
+        return plan and (plan.block_ids, plan.start_block)
+
+    def close_plan(self, i, commit):
+        plan = self.plans.pop(i)
+        (self.idx.commit_insert if commit else self.idx.abort_insert)(plan)
+        self.idx.commit_insert(plan)          # closed: idempotent
+
+    def match(self, tokens, cap):
+        m = self.idx.match(tokens, cap)
+        if m is not None:
+            self.matches.append(m)
+        return m and (m.length, m.block_ids)
+
+    def release(self, i):
+        m = self.matches.pop(i)
+        self.idx.release(m)
+        self.idx.release(m)                   # idempotent
+
+    def probe(self, tokens):
+        return (self.idx.missing_blocks(tokens),
+                self.idx.ngram_continuation(tokens, 3))
+
+    def clear(self):
+        """The engine's restart: trie and pool together, and holders of
+        a match or a plan from before let go of them afterwards."""
+        self.idx.clear()
+        self.pool.reset()
+        self.held = []
+        while self.matches:
+            self.release(0)
+        while self.plans:
+            self.plans[0].block_ids = []      # the reset took them back
+            self.close_plan(0, commit=len(self.plans) % 2 == 0)
+
+
+def random_prompt(rng):
+    """Few distinct blocks, so that prompts share prefixes and branch."""
+    n = int(rng.integers(1, 14))
+    return rng.integers(0, 2, n) if rng.random() < 0.8 \
+        else rng.integers(0, 5, n)
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["private_pool", "shared_pool"])
+@pytest.mark.parametrize("seed", range(8))
+def test_index_agrees_with_the_walks_after_every_operation(seed, shared):
+    """ISSUE 34: the index keeps its eviction order and its count of
+    evictable blocks as it goes; the walks it replaced are the oracles.
+    After every operation of a seeded random walk the real index and one
+    that still walks (same operations, a pool of its own) have handed out
+    the same blocks, evicted as often and hold the same trie — so every
+    victim was the walk's — and on the real index's own trie the next
+    victim and ``evictable_blocks()`` are what the walks find."""
+    rng = np.random.default_rng(1000 * seed + shared)
+    real, walk = Driver(PrefixCacheIndex, shared), Driver(WalkIndex, shared)
+    ops = ["admit", "append", "retire", "plan", "close_plan", "match",
+           "release", "probe", "share", "clear"]
+    weights = np.array([5, 4, 4, 3, 3, 3, 3, 1, 2, 0.15])
+    evictions = 0
+    for step in range(400):
+        op = rng.choice(ops, p=weights / weights.sum())
+        tokens = random_prompt(rng)
+
+        def pick(of):
+            return int(rng.integers(len(of)))
+
+        if op == "admit":
+            args = (tokens, bool(rng.integers(2)))
+        elif op == "append":
+            args = (pick(real.held or [0]), int(rng.integers(1, 4)))
+        elif op == "retire" and real.held:
+            args = (pick(real.held),)
+        elif op in ("plan", "probe"):
+            args = (tokens,)
+        elif op == "close_plan" and real.plans:
+            args = (pick(real.plans), bool(rng.integers(2)))
+        elif op == "match":
+            args = (tokens, None if rng.random() < 0.7
+                    else int(rng.integers(0, 4)))
+        elif op == "release" and real.matches:
+            args = (pick(real.matches),)
+        elif op == "share" and any(real.held):
+            holding = [i for i, held in enumerate(real.held) if held]
+            args = (holding[pick(holding)],)
+        elif op == "clear":
+            args = ()
+        else:
+            continue
+        got, want = (getattr(side, op)(*args) for side in (real, walk))
+        assert got == want, (step, op, args)
+        idx = real.idx
+        assert idx.evictions == walk.idx.evictions, (step, op)
+        assert real.pool.free_blocks == walk.pool.free_blocks, (step, op)
+        assert trie_shape(idx) == trie_shape(walk.idx), (step, op)
+        leaves = walk_evictable(idx)
+        assert len({nd.last_use for nd in leaves}) == len(leaves)
+        # half of the seeds never look at the heap between evictions
+        if seed % 2 == 0:
+            assert idx._coldest() is min(
+                leaves, key=lambda nd: nd.last_use, default=None), (step, op)
+        count = walk_evictable_blocks(idx)
+        assert idx.evictable_blocks() == count, (step, op)
+        assert idx.stats()["evictable_blocks"] == count
+        assert idx._n_nodes == len(trie_shape(idx))
+        assert len(idx._lru) <= idx._n_nodes  # one entry a node at most
+        evictions = idx.evictions
+    assert evictions > 20          # the walk did run the pool dry
+
+
+def count_children_reads(monkeypatch):
+    """Count reads of ``_Node.children``: every way of visiting the trie
+    goes through it."""
+    slot = _Node.__dict__["children"]
+    reads = [0]
+
+    def get(node):
+        reads[0] += 1
+        return slot.__get__(node, _Node)
+
+    monkeypatch.setattr(_Node, "children", property(
+        get, lambda node, value: slot.__set__(node, value)))
+    return reads
+
+
+def test_dry_pool_bookkeeping_does_not_grow_with_the_trie(monkeypatch):
+    """The cost, not a time: with 4,000 cached blocks and a dry pool, a
+    block handed out and a count of the evictable ones each touch a
+    handful of nodes, where the walks touched every node every time; on
+    an empty trie they touch none."""
+    pool = BlockPool(4097, reserve_scratch=True)
+    idx = PrefixCacheIndex(4097, 2, pool=pool)
+    rng = np.random.default_rng(0)
+    for _ in range(250):                      # 250 prompts of 16 blocks
+        tokens = rng.integers(0, 50_000, 32)
+        ids = idx.alloc_blocks(16)
+        idx.insert_shared(tokens, ids)
+        for block in ids:
+            pool.decref(block)                # the donor slot retires
+    assert pool.free_blocks == 96 and idx._n_nodes == 4000
+    idx.alloc_blocks(96)
+    assert pool.free_blocks == 0 and idx.evictable_blocks() == 4000
+    reads = count_children_reads(monkeypatch)
+    walk_evictable(idx)
+    assert reads[0] >= 4000                   # the probe sees a walk
+    reads[0] = 0
+    for _ in range(64):
+        assert len(idx.alloc_blocks(1)) == 1
+        idx.evictable_blocks()
+    assert idx.evictions == 64
+    assert reads[0] <= 64 * 4, reads[0]
+    empty = PrefixCacheIndex(64, 2, pool=BlockPool(64, reserve_scratch=True))
+    reads[0] = 0
+    for _ in range(64):
+        empty.alloc_blocks(1)                 # 63 blocks, then none
+        assert empty.evictable_blocks() == 0
+    assert reads[0] == 0
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_pool_shares_are_the_per_block_sums_to_the_last_bit(seed):
+    """``BlockPool.shares`` splits every block between its holders for
+    all rows in one pass; the floats are those of the per-block Python
+    sum (thirds, fifths and sevenths included), the scratch entries of a
+    table counting nothing."""
+    rng = np.random.default_rng(seed)
+    pool = BlockPool(600, reserve_scratch=True)
+    blocks = [pool.alloc() for _ in range(599)]
+    for block in blocks:
+        for _ in range(int(rng.integers(0, 130 if seed % 2 else 4))):
+            pool.incref(block)
+    tables = np.zeros((128, 64), np.int32)
+    for row in tables:
+        n = int(rng.integers(0, 65))
+        row[:n] = rng.choice(blocks, n, replace=False)
+    want = [sum(1.0 / max(pool.refs(b), 1) for b in row if b) for row in
+            tables.tolist()]
+    assert pool.shares(tables).tolist() == want
 
 
 # --------------------------------------------------------------------- #
