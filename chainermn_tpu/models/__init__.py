@@ -1,5 +1,6 @@
 from chainermn_tpu.models.laguna import LagunaLM
 from chainermn_tpu.models.mlp import MLP
+from chainermn_tpu.models.qwen3_next import Qwen3NextLM
 from chainermn_tpu.models.resnet import (
     AlexNet,
     ResNet,
@@ -12,6 +13,7 @@ from chainermn_tpu.models.resnet import (
 from chainermn_tpu.models.smallthinker import SmallThinkerLM
 from chainermn_tpu.models.transformer import (
     KVCacheKind,
+    SlotStateKind,
     TransformerBlock,
     TransformerLM,
     generate,
@@ -34,6 +36,8 @@ __all__ = [
     "VGG16",
     "KVCacheKind",
     "LagunaLM",
+    "Qwen3NextLM",
+    "SlotStateKind",
     "SmallThinkerLM",
     "TransformerBlock",
     "TransformerLM",

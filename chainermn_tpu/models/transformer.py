@@ -29,6 +29,7 @@ from jax import lax
 from chainermn_tpu.parallel.moe import ExpertParallelMLP
 from chainermn_tpu.parallel.sequence import (
     paged_scale_shape,
+    paged_store_shape,
     sequence_parallel_attention,
 )
 
@@ -47,6 +48,25 @@ class KVCacheKind:
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotStateKind:
+    """A kind of state that is not rows of a block store: what the layers
+    of the kind keep of a sequence has one size however long it is (a
+    linear-attention layer's recurrent state, the last inputs of its short
+    convolution). ``arrays`` names them, ``((key, shape a slot, dtype
+    name), ...)``. The engine keeps for each layer of the kind one array a
+    key, a row a slot and one scratch row behind them for rows that hold no
+    request: no pool, no table, no trie, and one unit a slot at admission.
+    A prefill writes a row's state after its last real token, a decode step
+    advances the active slots' rows in place, and the next prefill into a
+    freed slot starts from zero and never reads what was there. A prompt
+    cannot be continued at an offset without the state at that offset."""
+
+    name: str
+    layers: tuple
+    arrays: tuple
 
 
 class TransformerBlock(nn.Module):
@@ -360,7 +380,9 @@ def init_paged_kv_caches(model, n_blocks, block_size: int, *,
                          local_heads: Optional[int] = None,
                          quant: str = "none"):
     """Zeroed per-layer **paged** KV block stores: a list of ``{'k','v'}``
-    dicts shaped ``[n_blocks, block_size, heads, d_head]`` in the model's
+    dicts shaped ``[n_blocks, block_size, heads, d_head]`` (an int8 store of
+    fewer than 4 heads folds rows and heads into one axis,
+    :func:`~chainermn_tpu.parallel.sequence.paged_store_shape`) in the model's
     layer order, heads and head size as the model's ``kv_cache_spec()``
     gives them for the layer's kind; ``n_blocks`` is one count, or one per
     kind in the spec's order. Within a kind it is one pool of
@@ -386,15 +408,21 @@ def init_paged_kv_caches(model, n_blocks, block_size: int, *,
     dt = jnp.int8 if quant == "int8" else model.compute_dtype
 
     def layer(n, h, dh):
-        d = {"k": jnp.zeros((n, block_size, h, dh), dt),
-             "v": jnp.zeros((n, block_size, h, dh), dt)}
+        shape = paged_store_shape(n, block_size, h, dh, quant)
+        d = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         if quant == "int8":
             shape = paged_scale_shape(n, block_size, h)
             d["k_scale"] = jnp.zeros(shape, jnp.float32)
             d["v_scale"] = jnp.zeros(shape, jnp.float32)
         return d
 
-    layers = {i: layer(n, local_heads or kind.kv_heads, kind.head_dim)
+    def of_kind(kind, n):
+        if isinstance(kind, SlotStateKind):
+            return {key: jnp.zeros((n,) + tuple(shape), jnp.dtype(dtype))
+                    for key, shape, dtype in kind.arrays}
+        return layer(n, local_heads or kind.kv_heads, kind.head_dim)
+
+    layers = {i: of_kind(kind, n)
               for kind, n in zip(spec, n_blocks) for i in kind.layers}
     return [layers[i] for i in range(len(layers))]
 

@@ -463,7 +463,8 @@ class DroplessMoE(nn.Module):
     groups' sizes) visits no tile; their output rows come back undefined and
     are replaced by zeros before the weighted sum. ``shared_d_ff > 0`` adds
     a shared expert (:class:`GatedMLP`, the same activation, every token,
-    no routing weight), whole on every chip.
+    no routing weight), whole on every chip; ``shared_gate`` weighs it by
+    ``sigmoid(x @ shared_gate)``, one scalar a token.
 
     One code path for a prefill of thousands of tokens and a decode step
     of a hundred: assignments are sorted by expert, rows gathered in that
@@ -491,6 +492,7 @@ class DroplessMoE(nn.Module):
     weight_scale: float = 1.0
     held: Optional[tuple] = None        # (first, count) of n_experts
     shared_d_ff: int = 0
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x, router_in=None):
@@ -576,9 +578,17 @@ class DroplessMoE(nn.Module):
                 self.sow("serving_stats", "moe_total",
                          jnp.full_like(local, k))
         if self.shared_d_ff:
-            out = out + GatedMLP(
+            shared = GatedMLP(
                 d_model=d, d_ff=self.shared_d_ff, activation=self.activation,
                 compute_dtype=dt, name="shared")(x)
+            if self.shared_gate:
+                w_sg = self.param("shared_gate", init, (d, 1))
+                with jax.named_scope("shared/gate"):
+                    shared = (jax.nn.sigmoid(jnp.dot(
+                        x.astype(jnp.float32), w_sg.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST))
+                        * shared).astype(dt)
+            out = out + shared
         return out.reshape(lead + (d,))
 
 

@@ -344,6 +344,7 @@ def _sweep_kernel(table_ref, len_ref, *rest,
 def paged_attend(q, store_k, store_v, table, lengths, *,
                  k_scale=None, v_scale=None, scale: Optional[float] = None,
                  max_blocks: Optional[int] = None, first=None,
+                 kv_heads: Optional[int] = None,
                  interpret: Optional[bool] = None):
     """Paged-attention decode over the shared block store, fused.
 
@@ -354,7 +355,10 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
       store, already holding this step's writes (the scatter stays XLA:
       it moves ``S`` rows; the kernel owns the O(length) read side).
       ``Hkv`` divides ``H``: query head ``g`` reads KV head
-      ``g // (H // Hkv)``;
+      ``g // (H // Hkv)``. A store held folded, ``[n_blocks, bs * Hkv, D]``
+      (:func:`~chainermn_tpu.parallel.sequence.paged_store_shape`: an int8
+      store of fewer than 4 heads), is the kernel's own view of a block and
+      goes in as it is; ``kv_heads`` then says ``Hkv``;
     - ``table``: ``[B, max_blocks]`` int32 block table;
     - ``lengths``: ``[B]`` int32 — valid KV rows per row AFTER the
       write (``pos + S``). Only the ``ceil(lengths[b]/bs)`` blocks they
@@ -394,16 +398,20 @@ def paged_attend(q, store_k, store_v, table, lengths, *,
         if n_j != table.shape[1]:
             raise ValueError("a ring goes round the whole table row: "
                              "max_blocks cannot cut it")
+    if store_k.ndim == 3 and not kv_heads:
+        raise ValueError("a folded store needs kv_heads")
     return _attend(q, store_k, store_v, jnp.asarray(table, jnp.int32),
                    jnp.asarray(lengths, jnp.int32), k_scale, v_scale, first,
-                   scale=float(scale), n_j=n_j, interpret=bool(interpret))
+                   scale=float(scale), n_j=n_j, interpret=bool(interpret),
+                   kv_heads=kv_heads if store_k.ndim == 3 else None)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "n_j", "interpret"),
-                   inline=True)
+@functools.partial(jax.jit, static_argnames=("scale", "n_j", "interpret",
+                                             "kv_heads"), inline=True)
 def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
             first=None, *,
-            scale: float, n_j: int, interpret: bool):
+            scale: float, n_j: int, interpret: bool,
+            kv_heads: Optional[int] = None):
     """:func:`paged_attend` with its defaults filled in. A model calls it
     once a layer with the same shapes: ``jit`` traces the kernel for the
     first and hands the others the same equations, which then also lower
@@ -411,7 +419,11 @@ def _attend(q, store_k, store_v, table, lengths, k_scale, v_scale,
     the caller's own names, so no call stands between a block and its
     kernel."""
     b, s_len, h, d = q.shape
-    n_blocks, bs, hk = store_k.shape[:3]
+    if kv_heads is None:
+        n_blocks, bs, hk = store_k.shape[:3]
+    else:                       # held folded: a block is its rows already
+        n_blocks, hk = store_k.shape[0], kv_heads
+        bs = store_k.shape[1] // hk
     if h % hk:
         raise ValueError(f"{h} query heads do not divide over {hk} KV heads")
     group = h // hk

@@ -861,6 +861,37 @@ def paged_scale_shape(n_blocks: int, block_size: int, heads: int) -> tuple:
     return (n_blocks, 1, -(-block_size * heads // 128) * 128)
 
 
+def paged_store_shape(n_blocks: int, block_size: int, heads: int,
+                      head_dim: int, quant: str = "none") -> tuple:
+    """Shape of a block store's K (or V) array: ``[n_blocks, block_size,
+    heads, head_dim]``, but for an int8 store of fewer than 4 heads ``[n_blocks,
+    block_size * heads, head_dim]``, row ``t`` of head ``h`` in row ``t *
+    heads + h``. That is the view the decode kernel takes of any store (a
+    block as one tile of rows); with 4 heads or more the chip lays the
+    four-dimensional array out so that the view is free, with fewer it packs
+    four int8 rows of ONE head into a word (tokens before heads), and every
+    program that wrote a row or ran the kernel then relaid the whole store
+    (ten passes over it a decode step at 2 KV heads of 256, PERF.md section
+    6, PR 35). Held folded, the array is what the kernel reads and the write
+    scatters into, as it lies."""
+    if quant == "int8" and heads < 4:
+        return (n_blocks, block_size * heads, head_dim)
+    return (n_blocks, block_size, heads, head_dim)
+
+
+def _store_block_size(store, heads: int) -> int:
+    """Tokens a block of ``store`` holds (see :func:`paged_store_shape`)."""
+    return store.shape[1] if store.ndim == 4 else store.shape[1] // heads
+
+
+def _unfolded(rows, heads: int):
+    """Blocks gathered from a store, ``[n, bs, heads, D]`` whichever way the
+    store holds them. Only ever what was gathered."""
+    if rows.ndim == 4:
+        return rows
+    return rows.reshape(rows.shape[0], -1, heads, rows.shape[-1])
+
+
 def fold_block_scales(sc):
     """``[n, bs, H]`` per-row-per-head scales as rows of a scale array:
     ``[n, 1, W]``, as :func:`paged_scale_shape` has it."""
@@ -958,7 +989,8 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
     host-managed state threaded in per call."""
     table = kv_cache["table"]
     quant = "k_scale" in kv_cache
-    bs = kv_cache["k"].shape[1]
+    hk = k.shape[2]
+    bs = _store_block_size(kv_cache["k"], hk)
     b, s = q.shape[0], q.shape[1]
     if jnp.ndim(pos_offset) == 0:
         pos_offset = jnp.full((b,), pos_offset, jnp.int32)
@@ -984,21 +1016,20 @@ def paged_update_cache_and_attend(kv_cache, q, k, v, pos_offset, *,
                  {"first": jnp.maximum(lengths - s + 1 - window, 0)})
         out = paged_attend(q, new_k, new_v, table, lengths,
                            k_scale=new_ks, v_scale=new_vs, scale=scale,
-                           **where)
-    elif window is not None or q.shape[2] != new_k.shape[2]:
+                           kv_heads=hk, **where)
+    elif window is not None or q.shape[2] != hk:
         out = _paged_gather_attention(
             q, new_k, new_ks, new_v, new_vs, table, pos_offset,
-            window=window, scale=scale)
+            window=window, scale=scale, kv_heads=hk)
     else:
         flat = table[:, :m_used].reshape(-1)                  # [B*m]
 
         def gather(store, scales):
-            rows = jnp.take(store, flat, axis=0)   # [B*m, bs, H, D]
-            rows = rows.reshape((b, -1) + rows.shape[2:])
+            rows = _unfolded(jnp.take(store, flat, axis=0), hk)
+            rows = rows.reshape((b, -1) + rows.shape[2:])  # [B, m*bs, H, D]
             if not quant:
                 return rows.astype(q.dtype), None
-            sc = unfold_block_scales(jnp.take(scales, flat, axis=0),
-                                     *store.shape[1:3])
+            sc = unfold_block_scales(jnp.take(scales, flat, axis=0), bs, hk)
             return rows, sc.reshape(rows.shape[:3])
 
         kbuf, ksc = gather(new_k, new_ks)
@@ -1032,7 +1063,8 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
     store_k, store_v = kv_cache["k"], kv_cache["v"]
     table = kv_cache["table"]
     quant = "k_scale" in kv_cache
-    bs = store_k.shape[1]
+    h = k.shape[2]
+    bs = _store_block_size(store_k, h)
     b, s = k.shape[0], k.shape[1]
     if jnp.ndim(pos_offset) == 0:
         pos_offset = jnp.full((b,), pos_offset, jnp.int32)
@@ -1053,17 +1085,24 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
         blk = jnp.where(rv, blk, 0)
         off = jnp.where(rv, off, 0)
 
+    if store_k.ndim == 3:
+        # a store held folded (paged_store_shape): head g of row t is row
+        # t * H + g of its block
+        at = (blk[:, None], off[:, None] * h + jnp.arange(h)[None, :])
+    else:
+        at = (blk, off)
+
     def rows_of(store, rows):
         """The store with the call's rows in it, and the rows' scales."""
         rows = rows.reshape((b * s,) + rows.shape[2:])        # [B*S, H, D]
         if not quant:
-            return store.at[blk, off].set(rows.astype(store.dtype)), None
+            return store.at[at].set(rows.astype(store.dtype)), None
         r32 = rows.astype(jnp.float32)
         # symmetric per-row-per-head scale; the epsilon keeps all-zero
         # rows (warmup, padding) from dividing by zero
         sc = jnp.maximum(jnp.max(jnp.abs(r32), axis=-1) / 127.0, 1e-8)
         q8 = jnp.clip(jnp.round(r32 / sc[..., None]), -127, 127)
-        return store.at[blk, off].set(q8.astype(jnp.int8)), sc
+        return store.at[at].set(q8.astype(jnp.int8)), sc
 
     new_k, k_sc = rows_of(store_k, k)
     new_v, v_sc = rows_of(store_v, v)
@@ -1076,7 +1115,6 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
     # the fresh scales, the others keep theirs. Row ``i`` of the call sits
     # in touched block ``(lead + i) // bs`` at row ``(lead + i) % bs``, so
     # what a block takes is one run of its rows, ``[lo, hi)``
-    h = k.shape[2]
     lead = (pos_offset % bs)[:, None]                         # [B, 1]
     n_t = (s + bs - 2) // bs + 1
     n_rows = s if valid is None else jnp.minimum(valid, s)[:, None]
@@ -1119,7 +1157,8 @@ def paged_write_kv(kv_cache, k, v, pos_offset):
 
 def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
                             pos_offset, *, window=None,
-                            scale: Optional[float] = None):
+                            scale: Optional[float] = None,
+                            kv_heads: Optional[int] = None):
     """The XLA read path for what the plain one does not cover: grouped KV
     heads (``q [B, S, H, D]`` over a store of ``Hkv`` heads, query head
     ``g`` reading ``g // (H // Hkv)``) and a window layer's rows, whose
@@ -1130,7 +1169,8 @@ def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
     the kernel serves from: it forms ``[B, Hkv, G, S, entries * bs]``
     scores."""
     b, s, h, d = q.shape
-    bs, hk = store_k.shape[1], store_k.shape[2]
+    hk = kv_heads if store_k.ndim == 3 else store_k.shape[2]
+    bs = _store_block_size(store_k, hk)
     g = h // hk
     if scale is None:
         scale = d ** -0.5
@@ -1138,7 +1178,7 @@ def _paged_gather_attention(q, store_k, k_scale, store_v, v_scale, table,
     flat = table.reshape(-1)
 
     def gather(x):
-        rows = jnp.take(x, flat, axis=0)
+        rows = _unfolded(jnp.take(x, flat, axis=0), hk)
         return rows.reshape((b, n * bs) + rows.shape[2:])
 
     def scales(x):

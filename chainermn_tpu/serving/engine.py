@@ -84,6 +84,27 @@ prefill + decode), compiled once at warmup: table *contents* change
 per call, shapes never do, so the zero-recompile invariant carries over
 unchanged. The legacy dense path is preserved byte-for-byte behind
 ``paged=False`` (the default).
+
+**State that is not rows of K and V.** A model's ``kv_cache_spec()`` may
+name, beside its kinds of KV blocks, a kind whose layers keep one state of a
+fixed size a sequence (:class:`~chainermn_tpu.models.transformer.
+SlotStateKind`: a linear-attention layer's recurrent state and the last
+inputs of its short convolution). For such a layer the store holds one array
+a key indexed by SLOT, ``n_slots`` rows and one scratch row behind them: no
+pool, no table, no trie, nothing to size (``_SlotState``). A prefill program
+is told the store row of each of its rows (``slots``; the scratch row for
+rows that hold no request) and the real tokens of each (``valid``), and
+writes each row's state after its last REAL token there; the decode program's
+rows are the slots in their order, it advances the active ones in place
+(donated) and leaves the others as they are; a freed slot's row is simply
+overwritten by the next prefill into it, which starts from zero and never
+reads it. Admission counts the kind as one unit a slot
+(:meth:`ServingEngine.blocks_needed`), ``kv_stats()['kinds']`` reports
+``slots_live`` and ``bytes``. Such a model cannot continue a prompt at an
+offset, so it is refused what window layers are refused (prefix reuse,
+speculation, ``decode_window``, chunked prefill, KV migration, tensor
+parallelism, ``paged=False``), each with a ``ValueError``;
+preempt-and-replay stays whole, a replay being a prefill from position 0.
 """
 
 from __future__ import annotations
@@ -100,6 +121,7 @@ from jax import lax
 
 from chainermn_tpu.extensions.profiling import Watchdog
 from chainermn_tpu.models.transformer import (
+    SlotStateKind,
     _sampler,
     init_kv_caches,
     init_paged_kv_caches,
@@ -280,6 +302,26 @@ class _KVKind:
         }
 
 
+class _SlotState:
+    """Host-side account of one kind of state that is a row a slot
+    (:class:`~chainermn_tpu.models.transformer.SlotStateKind`): there is
+    nothing to allocate, a slot's row is its state while it holds a request
+    and is overwritten by the next prefill into it. ``rows`` are the slots
+    and one scratch row for program rows that hold no request."""
+
+    def __init__(self, kind, n_slots: int) -> None:
+        self.kind = kind
+        self.name = kind.name
+        self.rows = n_slots + 1
+        self.bytes = len(kind.layers) * self.rows * sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for _, shape, dtype in kind.arrays)
+
+    def stats(self, slots_live: int) -> dict:
+        return {"slots": self.rows - 1, "layers": len(self.kind.layers),
+                "slots_live": slots_live, "bytes": self.bytes}
+
+
 class EngineStateError(RuntimeError):
     """A device-program failure left the engine's donated buffers in an
     unknown state — containment is impossible; the scheduler must fail all
@@ -449,26 +491,38 @@ class ServingEngine:
                 "serving decode supports MoE only via moe_impl='gshard' — "
                 "rebuild the model with moe_impl='gshard' (same params)"
             )
-        # what KV state the model keeps, by layer kind; what follows holds
-        # for one kind that keeps every token, and a model with window
-        # layers is refused the options that are not built for it yet
+        # what state the model keeps, by layer kind; what follows holds for
+        # one kind that keeps every token. Some kinds cannot continue a
+        # prompt at an offset (a window layer's ring, a recurrent state
+        # kept a row a slot), and a model that has one is refused the
+        # options that are not built for it yet; ``_whole_prompts`` then
+        # names the kind
         spec = model.kv_cache_spec()
-        self._windowed = any(k.window is not None for k in spec)
-        if self._windowed:
+        state_spec = [k for k in spec if isinstance(k, SlotStateKind)]
+        spec = [k for k in spec if not isinstance(k, SlotStateKind)]
+        if not spec:
+            raise ValueError("the engine serves a model that keeps K and V "
+                             "rows in at least one layer")
+        self._whole_prompts = (
+            "recurrent state" if state_spec else
+            "window layers" if any(k.window is not None for k in spec)
+            else "")
+        if self._whole_prompts:
             for bad, what in (
                     (prefix_cache_blocks, "prefix reuse across requests "
-                     "(prefix_cache_blocks): the trie indexes one pool"),
-                    (not paged, "paged=False: window layers live in a "
-                     "block store of their own"),
+                     "(prefix_cache_blocks): the trie indexes one pool"
+                     + (", and a hit needs the state at the boundary"
+                        if state_spec else "")),
+                    (not paged, "paged=False: such layers live in a "
+                     "store of their own"),
                     (speculative is not None, "speculative decoding: a "
                      "verify window continues a sequence at an offset"),
                     (decode_window != 1, "decode_window > 1"),
                     (model.tensor_axis is not None, "tensor_axis")):
                 if bad:
-                    raise ValueError(
-                        "not supported for a model with window layers: "
-                        + what)
-        elif kv_window_blocks is not None:
+                    raise ValueError(self._refusal(what))
+        if kv_window_blocks is not None and not any(
+                k.window is not None for k in spec):
             raise ValueError("kv_window_blocks sizes the window layers' "
                              "pool and this model has none")
         if model.tensor_axis is not None and comm is None:
@@ -637,10 +691,15 @@ class ServingEngine:
                         self.n_slots, self.cache_len)
                 for kind in spec]
             self.kv_blocks = self._kv[0].n_blocks
+            # and for a kind that is a row a slot, only the account of it
+            self._state = [_SlotState(kind, self.n_slots)
+                           for kind in state_spec]
+            self._state_layers = sum(len(k.layers) for k in state_spec)
+            self._state_tokens = 0
             # prefix reuse runs on the one pool of a model whose layers
-            # are all of a kind; with window layers nothing is inserted
-            # or matched
-            if len(self._kv) == 1:
+            # are all of a kind; with window layers or a recurrent state
+            # nothing is inserted or matched
+            if not self._whole_prompts:
                 self.prefix_cache = self._kv[0].index
             self._min_insert = max(1, int(prefix_min_insert_blocks))
             self._n_prog_blocks = self._n_max   # match cap for planning
@@ -788,6 +847,10 @@ class ServingEngine:
     def prefix_enabled(self) -> bool:
         return self.prefix_cache is not None
 
+    def _refusal(self, what: str) -> str:
+        return (f"not supported for a model with {self._whole_prompts}: "
+                + what)
+
     def prefill_rows(self, bucket: int) -> int:
         """Rows of ``bucket``'s prefill program, the most requests one
         admission group of that bucket holds: ``prefill_batch`` at the
@@ -809,7 +872,7 @@ class ServingEngine:
         the host-bounce gather/scatter pair is not built (documented
         limitation — export raises, the router decodes in place)."""
         return (self.paged and self.model.tensor_axis is None
-                and not self._windowed)
+                and not self._whole_prompts)
 
     # ------------------------------------------------------------------ #
     # program construction                                                #
@@ -933,10 +996,12 @@ class ServingEngine:
                  keys):
             with annotate("chainermn.prefill"):
                 # a window layer writes a row's real tokens only, the
-                # last ring of them (padding would wrap onto live blocks)
+                # last ring of them (padding would wrap onto live blocks),
+                # and a state layer's state stops at the last of them
                 valid = (jnp.where(active, last_idx + 1, 0)
-                         if self._windowed else None)
-                caches = self._layer_caches(store, table, valid=valid)
+                         if self._whole_prompts else None)
+                caches = self._layer_caches(store, table, valid=valid,
+                                            real=valid)
                 pos = starts[:, None] + jnp.arange(bucket)[None, :]
                 (lg, new_store), stats = model.apply(
                     params, tokens, pos, kv_caches=caches,
@@ -969,7 +1034,8 @@ class ServingEngine:
 
         def body(params, store, table, tokens, pos, active, keys):
             with annotate("chainermn.decode"):
-                caches = self._layer_caches(store, table, **extra)
+                caches = self._layer_caches(
+                    store, table, real=active.astype(jnp.int32), **extra)
                 (lg, new_store), stats = model.apply(
                     params, tokens[:, None], pos[:, None], kv_caches=caches,
                     mutable=[_STATS])
@@ -1089,37 +1155,51 @@ class ServingEngine:
         return body
 
     def _init_paged_store(self, local_heads: Optional[int] = None):
+        counts = {k.name: k.n_blocks for k in self._kv}
+        counts.update((st.name, st.rows) for st in self._state)
         return init_paged_kv_caches(
-            self.model, tuple(kv.n_blocks for kv in self._kv),
+            self.model,
+            tuple(counts[k.name] for k in self.model.kv_cache_spec()),
             self.kv_block_size, local_heads=local_heads,
             quant=self.kv_quant)
 
-    def _layer_caches(self, store, tables, valid=None, **extra):
+    def _layer_caches(self, store, tables, valid=None, real=None, **extra):
         """Inside a program: the cache dict of every layer, its store
         beside its kind's table. ``tables`` is what :meth:`_table_args`
         builds, one dict a kind; ``valid`` goes to every layer where the program caps a
         row's writes for all of them, and otherwise (a prefill) to window
-        layers alone; ``extra`` are static entries for all layers."""
+        layers alone; ``extra`` are static entries for all layers that keep
+        K and V. A layer whose state is a row a slot gets ``real`` as its
+        ``valid``: the tokens of each row that advance its state."""
         caches = [None] * len(store)
         for kv, ops in zip(self._kv, tables):
             static = dict(extra)
             if kv.window is not None:
                 static["window"] = kv.window
             if valid is not None and (kv.window is not None
-                                      or not self._windowed):
+                                      or not self._whole_prompts):
                 static["valid"] = valid
             for i in kv.kind.layers:
                 caches[i] = dict(store[i], **ops, **static)
+        for st, ops in zip(self._state, tables[len(self._kv):]):
+            for i in st.kind.layers:
+                caches[i] = dict(store[i], **ops, valid=real)
         return caches
 
     def _table_args(self, rows: Optional[int] = None) -> tuple:
         """The per-kind table operand of a program: the decode step's
         (every slot's row), or all-scratch tables of ``rows`` rows for a
-        prefill to fill in."""
+        prefill to fill in. A kind that is a row a slot has no table: a
+        decode step's rows are the slots in their order, and a prefill
+        takes ``slots``, the store row of each of its rows (the scratch
+        row until filled in)."""
         out = []
         for kv in self._kv:
             out.append({"table": jnp.asarray(kv.tables) if rows is None
                         else np.zeros((rows, kv.width), np.int32)})
+        for st in self._state:
+            out.append({} if rows is None else
+                       {"slots": np.full((rows,), st.rows - 1, np.int32)})
         return tuple(out)
 
     def _insert_body(self):
@@ -1768,6 +1848,8 @@ class ServingEngine:
                         alloc_records.append((slot, ids))
                         for kv, ops in zip(self._kv, table):
                             ops["table"][i] = kv.tables[slot]
+                        for ops in table[len(self._kv):]:
+                            ops["slots"][i] = slot
                         suffix = plan.prompt[plan.start:]
                         tokens[i, : len(suffix)] = suffix
                         starts[i] = plan.start
@@ -1809,6 +1891,7 @@ class ServingEngine:
                               cached=plan.start, batch=len(plans),
                               blocks=sum(len(i) for i in ids))
             out.append((slot, first))
+            self._state_tokens += len(plan.prompt) * self._state_layers
             if self._drafter is not None:
                 self._drafter.on_admit(slot, plan.prompt, first)
             # zero-copy trie insert: the slot's blocks already hold the
@@ -1836,8 +1919,8 @@ class ServingEngine:
         ``cache_len`` (``bucket_for``'s ``start + b <= cache_len``
         constraint; an out-of-range bucket would clamp table lookups onto
         live blocks). ``None`` means: admit unchunked."""
-        if not self.paged or self._windowed:
-            return None     # a window layer's prefill takes whole prompts
+        if not self.paged or self._whole_prompts:
+            return None     # such a layer's prefill takes whole prompts
         chunk_tokens = int(chunk_tokens)
         if chunk_tokens < 1:
             return None
@@ -1868,6 +1951,9 @@ class ServingEngine:
         converts into refcounts). Returns the claimed slot."""
         if not self.paged:
             raise RuntimeError("chunked prefill needs paged=True")
+        if self._whole_prompts:
+            raise ValueError(self._refusal(
+                "chunked prefill (a chunk continues a prompt at an offset)"))
         if not self.free_slots:
             raise RuntimeError("no free slot for chunked prefill")
         slot = min(self.free_slots)
@@ -2058,10 +2144,9 @@ class ServingEngine:
                     self._store, jnp.asarray(one), rows, jnp.int32(1))
 
     def _refuse_migration(self) -> None:
-        if self._windowed:
-            raise ValueError(
-                "not supported for a model with window layers: KV "
-                "migration (a payload carries one store's blocks)")
+        if self._whole_prompts:
+            raise ValueError(self._refusal(
+                "KV migration (a payload carries one store's blocks)"))
 
     def export_slot_kv(self, slot: int,
                        ctx: Optional[dict] = None, *,
@@ -2341,7 +2426,8 @@ class ServingEngine:
         """Worst-case NEW blocks a request admits with, one count per kind
         of KV state: blocks covering ``[start, prompt_len + max_new)``
         (``start`` = cached-prefix tokens, whose blocks are shared, not
-        allocated), a window kind's capped at its ring. The scheduler's
+        allocated), a window kind's capped at its ring, and one unit (its
+        slot's row) of a kind that is a row a slot. The scheduler's
         block-budget admission compares this against
         :meth:`kv_blocks_admittable`, kind by kind. Multi-token rounds add
         ``ceil(write_horizon / block_size)`` headroom: a verify window
@@ -2350,13 +2436,17 @@ class ServingEngine:
         bs = self.kv_block_size
         return np.array(
             [kv.blocks_for(prompt_len + max_new) - start // bs
-             + self._spec_headroom for kv in self._kv], np.int64)
+             + self._spec_headroom for kv in self._kv]
+            + [1] * len(self._state), np.int64)
 
     def kv_blocks_admittable(self) -> np.ndarray:
         """Blocks an admission may claim without ever starving a decode,
         per kind: free pool blocks, plus trie blocks eviction could
-        reclaim, minus the growth already reserved by active slots."""
-        return np.array([kv.admittable() for kv in self._kv], np.int64)
+        reclaim, minus the growth already reserved by active slots; of a
+        kind that is a row a slot, the free slots."""
+        return np.array([kv.admittable() for kv in self._kv]
+                        + [len(self.free_slots)] * len(self._state),
+                        np.int64)
 
     def _horizon_block_range(self, slot: int) -> range:
         """Blocks the slot's next round may write: those covering
@@ -2479,9 +2569,27 @@ class ServingEngine:
             "blocks_free": first["blocks_free"],
             "blocks_reserved": first["blocks_reserved"],
             "peak_active": self.peak_active,
-            # and the same of every kind's, by the spec's names
-            "kinds": {kv.name: kv.stats() for kv in self._kv},
+            # and the same of every kind's, by the spec's names; of a
+            # kind that is a row a slot, the slots holding a request and
+            # the bytes of its arrays
+            "kinds": dict(
+                {kv.name: kv.stats() for kv in self._kv},
+                **{st.name: st.stats(self.active_slots)
+                   for st in self._state}),
         }
+
+    def pop_state_stats(self) -> Optional[tuple]:
+        """``(slots live, bytes, tokens)`` of the state kept a row a slot:
+        rows holding a request now, the bytes of all such arrays, and the
+        tokens x layers whose state the programs advanced since the last
+        call (cleared on read); ``None`` for a model that keeps none. The
+        scheduler drains it into
+        :class:`~chainermn_tpu.serving.metrics.ServingMetrics`."""
+        if not (self.paged and self._state):
+            return None
+        tokens, self._state_tokens = self._state_tokens, 0
+        return (self.active_slots, sum(st.bytes for st in self._state),
+                tokens)
 
     def flush_inserts(self) -> None:
         """Run the deferred trie inserts (one compiled copy per prompt
@@ -2606,6 +2714,9 @@ class ServingEngine:
             self._c_decode_steps.inc()
             self._events.emit("decode_step", active=int(self._active.sum()))
             self._guard.check()
+            if self.paged:
+                self._state_tokens += (int(self._active.sum())
+                                       * self._state_layers)
             out = {}
             for slot in np.flatnonzero(self._active):
                 slot = int(slot)

@@ -97,6 +97,13 @@ class ServingMetrics:
         self._h_cached = reg.histogram("cached_prefix_frac", labels)
         self._c_moe_local = reg.counter("moe_assignments_local_total", labels)
         self._c_moe_total = reg.counter("moe_assignments_total", labels)
+        # state kept a row a slot beside the KV blocks (a linear-attention
+        # layer's): rows holding a request, the bytes of the arrays, and
+        # tokens x layers whose state a program advanced
+        self._g_state_slots = reg.gauge("serving_state_slots_live", labels)
+        self._g_state_bytes = reg.gauge("serving_state_bytes", labels)
+        self._c_state_tokens = reg.counter("linear_state_tokens_total",
+                                           labels)
         self._g_queue = reg.gauge("serving_queue_depth_now", labels)
         self._g_active = reg.gauge("serving_active_slots", labels)
         # paged-KV series (PR 7): store occupancy gauges sampled per step,
@@ -202,6 +209,15 @@ class ServingMetrics:
         deployment, ``DroplessMoE.held``)."""
         self._c_moe_local.inc(local)
         self._c_moe_total.inc(total)
+
+    def record_slot_state(self, slots_live: int, n_bytes: int,
+                          tokens: int) -> None:
+        """The engine's state kept a row a slot: rows holding a request
+        now, the bytes of its arrays, and the tokens x layers whose state
+        the programs advanced since the last call."""
+        self._g_state_slots.set(slots_live)
+        self._g_state_bytes.set(n_bytes)
+        self._c_state_tokens.inc(tokens)
 
     def record_token(self, t_prev_token: float, t_token: float) -> None:
         self._h_tpot.observe(t_token - t_prev_token)
@@ -439,6 +455,10 @@ class ServingMetrics:
         if self._c_moe_total.value:
             out["moe_local_share"] = round(
                 self._c_moe_local.value / self._c_moe_total.value, 4)
+        if self._g_state_bytes.value:
+            out["state_slots_live"] = int(self._g_state_slots.value)
+            out["state_bytes"] = int(self._g_state_bytes.value)
+            out["linear_state_tokens"] = int(self._c_state_tokens.value)
         for hist, prefix in ((self._h_queue, "queue_depth"),
                              (self._h_occ, "slot_occupancy")):
             samples = hist.samples

@@ -833,6 +833,10 @@ class FCFSScheduler:
             counts = pop() if pop is not None else None
             if counts is not None:
                 self.metrics.record_moe_assignments(*counts)
+            pop = getattr(self.engine, "pop_state_stats", None)
+            state = pop() if pop is not None else None
+            if state is not None:
+                self.metrics.record_slot_state(*state)
             with self._lock:
                 depth = len(self._queue)
                 batch_depth = sum(1 for r in self._queue

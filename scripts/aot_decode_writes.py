@@ -38,11 +38,15 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(_HERE, "aot_decode_writes.jsonl")
 
 BS, D = 16, 128
-# (name, blocks, table entries, query heads, KV heads, window, prefill rows)
+# (name, blocks, table entries, query heads, KV heads, window, prefill rows
+# [, head size where it is not 128])
 STORES = [
     ("cell1", 5121, 64, 16, 16, None, 512),
     ("cell3_full", 27701, 416, 28, 4, None, 1024),
     ("cell3_window", 21201, 257, 28, 4, 4096, 1024),
+    # 2 KV heads of 256 under 16 query heads: an int8 store of fewer than 4
+    # heads is held folded (paged_store_shape), or every program relays it
+    ("cell5_full", 55401, 416, 16, 2, None, 1024, 256),
 ]
 _SHAPE = re.compile(r"\b(f32|s8)\[([\d,]+)\]")
 _INSTR = re.compile(r"^(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$")
@@ -118,11 +122,12 @@ def records(topo, stores=STORES, programs=("decode", "prefill_write")):
     class OneKind:
         compute_dtype = jnp.bfloat16
 
-        def __init__(self, kv_heads):
-            self.kv_heads = kv_heads
+        def __init__(self, kv_heads, head_dim):
+            self.kv_heads, self.head_dim = kv_heads, head_dim
 
         def kv_cache_spec(self):
-            return (KVCacheKind("kind", (0,), self.kv_heads, D),)
+            return (KVCacheKind("kind", (0,), self.kv_heads,
+                                self.head_dim),)
 
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
@@ -134,9 +139,10 @@ def records(topo, stores=STORES, programs=("decode", "prefill_write")):
         return jax.jit(fn, donate_argnums=(0,)).lower(
             store, *rest).compile().as_text()
 
-    for name, n_blocks, entries, h, hk, window, s_fill in stores:
+    for name, n_blocks, entries, h, hk, window, s_fill, *wide in stores:
+        d = wide[0] if wide else D
         store = jax.eval_shape(lambda: init_paged_kv_caches(
-            OneKind(hk), n_blocks, BS, quant="int8")[0])
+            OneKind(hk, d), n_blocks, BS, quant="int8")[0])
         store = {k: aval(v.shape, v.dtype) for k, v in store.items()}
         static = {} if window is None else {"window": window}
 
@@ -153,7 +159,7 @@ def records(topo, stores=STORES, programs=("decode", "prefill_write")):
             return paged_write_kv(cache, k, v, pos)
 
         def rows(b, s, heads):
-            return aval((b, s, heads, D), jnp.bfloat16)
+            return aval((b, s, heads, d), jnp.bfloat16)
 
         built = {
             "decode": (decode, aval((128, entries), jnp.int32),

@@ -47,11 +47,14 @@ def count(topo):
         ops.set_kernels_interpreted(None)
 
 
-@pytest.mark.parametrize("store", ["cell1", "cell3_window"])
+@pytest.mark.parametrize("store", ["cell1", "cell3_window", "cell5_full"])
 def test_decode_layer_passes_over_no_scale_array(count, store):
     """Write and kernel at a cell's shapes, the store donated: two Mosaic
     calls (the scale write, the sweep) and not one operation, relayout or
-    staging copy, over a whole scale array or int8 store."""
+    staging copy, over a whole scale array or int8 store. Cell 5's store has
+    2 KV heads of 256: held as ``[n_blocks, bs, 2, 256]`` the chip lays it
+    out tokens before heads and the scatter and the kernel's view relaid
+    the whole store ten times a layer (PR 35); it is held folded."""
     rec = count(store, "decode")
     assert rec["mosaic_calls"] == 2
     assert rec["whole_scale_array_ops"] == 0

@@ -1,0 +1,585 @@
+"""A model of Gated DeltaNet (linear-attention) layers and gated
+full-attention layers with a share of the experts held, against the plain
+reference that sits beside the benchmark's configuration
+(``benchmarks/configs/qwen3-next-80b-a3b-l4-ep2.py``: ``jax.numpy``,
+float32, the recurrence a token at a time, nothing of the program): the
+whole model's logits, the chunked form against the recurrence at fast and at
+slow decay, prefill and then decode through the block store and the state
+store, slots reused, a preempted request replayed, the two shares of the
+experts, what an engine refuses for such a model, its instruments, the
+kernels at heads of 256, and the scopes its device operations are found by.
+Small sizes that keep every ratio of the published model (2 value heads a
+key head, 8 query heads a KV head, rotary on a quarter of the head, 8
+experts top-2 with 4 held), seeded weights, CPU.
+"""
+
+import importlib.util
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chainermn_tpu.models import KVCacheKind, Qwen3NextLM, SlotStateKind
+from chainermn_tpu.models.qwen3_next import (
+    CHUNK,
+    GatedDeltaNet,
+    chunk_gated_delta_rule,
+    recurrent_gated_delta_rule,
+)
+from chainermn_tpu.ops import flash_attention
+from chainermn_tpu.parallel.moe import DroplessMoE
+from chainermn_tpu.parallel.sequence import (
+    paged_scale_shape,
+    paged_store_shape,
+    paged_update_cache_and_attend,
+    paged_write_kv,
+)
+from chainermn_tpu.resilience.faults import FaultInjector
+from chainermn_tpu.serving import FCFSScheduler, ServingEngine
+from chainermn_tpu.serving.speculative import SpeculativeConfig
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def _reference():
+    path = ROOT / "benchmarks" / "configs" / "qwen3-next-80b-a3b-l4-ep2.py"
+    spec = importlib.util.spec_from_file_location("qwen3_next_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+# the published key names, at a size the CPU holds
+CFG = {
+    "vocab_size": 97, "hidden_size": 32, "num_hidden_layers": 4,
+    "full_attention_interval": 4,
+    "num_attention_heads": 8, "num_key_value_heads": 1, "head_dim": 16,
+    "partial_rotary_factor": 0.25, "rope_theta": 1e7,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 8, "linear_value_head_dim": 8,
+    "linear_conv_kernel_dim": 4,
+    "num_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+    "shared_expert_intermediate_size": 16,
+    "held_experts": {"first": 4, "count": 4, "published": 8},
+    "rms_norm_eps": 1e-6,
+}
+
+
+def build(cfg, **kw):
+    held = cfg["held_experts"]
+    return Qwen3NextLM(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        linear_k_heads=cfg["linear_num_key_heads"],
+        linear_v_heads=cfg["linear_num_value_heads"],
+        linear_k_dim=cfg["linear_key_head_dim"],
+        linear_v_dim=cfg["linear_value_head_dim"],
+        conv_kernel=cfg["linear_conv_kernel_dim"],
+        full_attention_interval=cfg["full_attention_interval"],
+        partial_rotary_factor=cfg["partial_rotary_factor"],
+        rope_theta=cfg["rope_theta"], d_ff=cfg["moe_intermediate_size"],
+        n_experts=held["published"], top_k=cfg["num_experts_per_tok"],
+        held_experts=(held["first"], held["count"]),
+        shared_d_ff=cfg["shared_expert_intermediate_size"],
+        rms_norm_eps=cfg["rms_norm_eps"], max_len=128,
+        compute_dtype=jnp.float32, **kw)
+
+
+def seeded(model, seed=0):
+    """Weights from a seed, the norm scales moved off 1 so that a path which
+    dropped them would show, and heads that forget at a few tenths a token
+    as the benchmark's draws do (the published start, A up to 16, forgets
+    everything at once)."""
+    params = {"params": model.init(
+        jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))["params"]}
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    out = []
+    for (path, leaf), key in zip(leaves, keys):
+        name = str(getattr(path[-1], "key", ""))
+        if name == "scale":
+            leaf = 1.0 + 0.1 * jax.random.normal(key, leaf.shape)
+        elif name == "A_log":
+            leaf = 0.5 * jax.random.normal(key, leaf.shape)
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@pytest.fixture(scope="module")
+def lm():
+    model = build(CFG)
+    return model, seeded(model)
+
+
+def tokens_of(seed, b, t):
+    return jnp.asarray(np.random.default_rng(seed).integers(
+        0, CFG["vocab_size"], (b, t)), jnp.int32)
+
+
+# -- the model against the reference ---------------------------------------- #
+
+def test_whole_model_logits_match_reference(lm):
+    """Float32 on both sides, 150 tokens (two chunks and a ragged third).
+    What is left is the order of summation, which the gated norm after the
+    recurrence amplifies where a head's output is small (it divides by the
+    output's own size): 2e-3 of logits of size 4, where a dropped gate, a
+    wrong pairing of the rotary entries or a state carried wrongly moves
+    them by tenths."""
+    model, params = lm
+    toks = tokens_of(0, 2, 150)
+    got, want = model.apply(params, toks), REF.logits(params, toks, CFG)
+    assert float(jnp.max(jnp.abs(want))) > 1.0
+    np.testing.assert_allclose(got, want, atol=2e-3, rtol=2e-3)
+
+
+def test_reference_control_is_another_model(lm):
+    _, params = lm
+    toks = tokens_of(2, 1, 64)
+    exact = REF.logits(params, toks, CFG)
+    low = REF.logits(params, toks, CFG, lowp=True)
+    assert float(jnp.max(jnp.abs(exact - low))) > 1e-2
+
+
+def test_the_spec_names_both_kinds_of_state(lm):
+    model, _ = lm
+    full, linear = model.kv_cache_spec()
+    assert isinstance(full, KVCacheKind) and full.layers == (3,)
+    assert (full.kv_heads, full.head_dim, full.window) == (1, 16, None)
+    assert isinstance(linear, SlotStateKind) and linear.layers == (0, 1, 2)
+    assert linear.arrays == (("S", (4, 8, 8), "float32"),
+                             ("conv", (3, 2 * 16 + 32), "float32"))
+
+
+# -- the two forms of the gated delta rule ---------------------------------- #
+
+def _rule_inputs(seed, b, t, h, dk, dv, keep):
+    """Normed q and k, v, beta, and g such that a head keeps ``keep`` of
+    its state a token (a pair: the range)."""
+    rng = np.random.default_rng(seed)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(rng.standard_normal((b, t, h, dk))) / np.sqrt(dk)
+    k = unit(rng.standard_normal((b, t, h, dk)))
+    v = rng.standard_normal((b, t, h, dv))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((b, t, h))))
+    g = np.log(rng.uniform(*keep, (b, t, h)))
+    return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+
+
+@pytest.mark.parametrize("t", [1, CHUNK - 1, CHUNK, CHUNK + 1, 150, 300])
+@pytest.mark.parametrize("keep", [(0.25, 0.75), (0.99, 0.999)])
+def test_chunked_form_is_the_recurrence(t, keep):
+    """Lengths that are no multiple of the chunk, heads that forget in a
+    few tokens and heads that keep 0.99-0.999 a token (a state 300 tokens
+    deep): outputs and final state to float32's rounding."""
+    args = _rule_inputs(t, 2, t, 3, 16, 8, keep)
+    o_rec, s_rec = recurrent_gated_delta_rule(*args)
+    o_chunk, s_chunk = chunk_gated_delta_rule(*args)
+    np.testing.assert_allclose(o_chunk, o_rec, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(s_chunk, s_rec, atol=2e-5, rtol=2e-5)
+
+
+def test_padding_of_a_bucket_row_leaves_the_state_alone():
+    """``g = 0`` and ``beta = 0`` past a row's length: the final state is
+    the state after its last real token, whatever the padding holds."""
+    q, k, v, g, beta = _rule_inputs(5, 2, 100, 3, 16, 8, (0.9, 0.99))
+    real = jnp.arange(100)[None, :, None] < jnp.asarray([37, 100])[:, None,
+                                                                   None]
+    _, state = chunk_gated_delta_rule(q, k, v, jnp.where(real, g, 0.0),
+                                      jnp.where(real, beta, 0.0))
+    _, short = recurrent_gated_delta_rule(
+        *(x[:1, :37] for x in (q, k, v, g, beta)))
+    np.testing.assert_allclose(state[0], short[0], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("keep", ["fast", "slow"])
+def test_layer_prefill_then_recurrence_is_the_whole_sequence(keep):
+    """The layer itself: a prompt through the chunked form into a slot's
+    row (rows of unlike lengths, one of them padding only), then a token at
+    a time through the recurrence on the store, against the whole sequence
+    at once."""
+    layer = GatedDeltaNet(d_model=32, n_k_heads=2, n_v_heads=4, d_k=8, d_v=8,
+                          conv_kernel=4, rms_norm_eps=1e-6,
+                          compute_dtype=jnp.float32)
+    a = jax.random.normal(jax.random.PRNGKey(0), (3, 90, 32))
+    params = layer.init(jax.random.PRNGKey(1), a)
+    if keep == "slow":      # exp(g) of 0.99-0.999: A = exp(-5.5)
+        params = jax.tree_util.tree_map_with_path(
+            lambda p, x: jnp.full_like(x, -5.5)
+            if "A_log" in jax.tree_util.keystr(p) else x, params)
+    whole, _ = layer.apply(params, a)
+    lengths = jnp.asarray([70, 5, 0])
+    store = {"S": jnp.full((5, 4, 8, 8), 7.0),        # a former tenant's
+             "conv": jnp.full((5, 3, 64), 7.0)}
+    out, store = layer.apply(params, a[:, :80], dict(
+        store, valid=lengths, slots=jnp.asarray([2, 0, 4])))
+    np.testing.assert_allclose(out[0, :70], whole[0, :70], atol=1e-4,
+                               rtol=1e-4)
+    np.testing.assert_allclose(out[1, :5], whole[1, :5], atol=1e-4,
+                               rtol=1e-4)
+    assert float(jnp.max(jnp.abs(store["S"][1] - 7.0))) == 0.0   # untouched
+    # decode: the batch rows are the store's rows 0..3 in their order
+    feed = jnp.zeros((4, 1, 32)).at[2].set(a[0, 70:71]).at[0].set(a[1, 5:6])
+    valid = jnp.asarray([1, 0, 1, 0])
+    for step in range(10):
+        feed = jnp.zeros((4, 1, 32)).at[2, 0].set(a[0, 70 + step]).at[
+            0, 0].set(a[1, 5 + step])
+        out, store = layer.apply(params, feed, dict(store, valid=valid))
+        np.testing.assert_allclose(out[2, 0], whole[0, 70 + step],
+                                   atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(out[0, 0], whole[1, 5 + step],
+                                   atol=1e-4, rtol=1e-4)
+    assert float(jnp.max(jnp.abs(store["S"][1] - 7.0))) == 0.0
+
+
+# -- prefill, then decode, through both stores ------------------------------ #
+
+def served_gap(params, prompt, served):
+    """The widest gap by which a served token's logit lies below the
+    reference's best at its position (the benchmark's number): a comparison
+    of logits, since with random weights the first place changes hands on
+    rounding."""
+    seq = jnp.asarray(np.concatenate([prompt, served])[None], jnp.int32)
+    lg = REF.logits(params, seq, CFG)[0]
+    p = len(prompt)
+    rows = lg[p - 1:p - 1 + len(served)]
+    picked = rows[jnp.arange(len(served)), jnp.asarray(served)]
+    return float(jnp.max(jnp.max(rows, axis=-1) - picked))
+
+
+def engine_for(model, params, **kw):
+    args = dict(n_slots=3, prefill_buckets=(8, 32, 64, 72), prefill_batch=2,
+                paged=True, kv_block_size=8, cache_len=104)
+    args.update(kw)
+    return ServingEngine(model, params, **args)
+
+
+# more requests than slots, two of them in one prefill program (5 and 7 of a
+# bucket of 8, rows of unlike lengths), prompts of one chunk and of two
+WORK = [(5, 20), (40, 60), (20, 30), (7, 12), (33, 9), (64, 36), (70, 30)]
+
+
+def serve(engine, seed=5, work=WORK):
+    rng = np.random.default_rng(seed)
+    sched = FCFSScheduler(engine)
+    reqs = [sched.submit(rng.integers(0, CFG["vocab_size"], p), a)
+            for p, a in work]
+    sched.run_until_idle()
+    return sched, reqs
+
+
+@pytest.mark.parametrize("kv_quant,kernel,limit", [
+    ("none", True, 2e-3), ("int8", False, 0.15), ("int8", True, 0.15)])
+def test_prefill_then_decode_matches_the_full_forward(lm, kv_quant, kernel,
+                                                      limit):
+    """Seven requests on three slots (every slot freed and taken again),
+    contexts to 100: what the engine serves through the block store of the
+    full layer and the state store of the linear layers is, position by
+    position, within ``limit`` of the reference's best logit. Float32
+    without a quantised store: the order of summation alone, as in the
+    whole-model test; an int8 store adds its rounding of K and V."""
+    model, params = lm
+    engine = engine_for(model, params, kv_quant=kv_quant,
+                        paged_kernel=kernel)
+    sched, reqs = serve(engine)
+    assert all(len(r.tokens) == a for r, (_, a) in zip(reqs, WORK))
+    for r in reqs:
+        assert served_gap(params, r.prompt, list(r.tokens)) <= limit
+    assert engine.compile_counts() == {"prefill": 4, "decode": 1}
+    assert engine.recompiles == {}
+    assert sched.metrics.report()["prefill_batch_size_max"] == 2
+
+
+def test_a_request_is_served_the_same_alone_and_in_company(lm):
+    """Rows of unlike lengths in one program, other slots' states beside it
+    in the store, a slot a longer request has just left: a request's tokens
+    are what it gets on an engine of its own."""
+    model, params = lm
+    _, together = serve(engine_for(model, params))
+    for i in (0, 3, 5):
+        _, alone = serve(engine_for(model, params), work=WORK[i:i + 1],
+                         seed=100 + i)
+        again = FCFSScheduler(engine_for(model, params))
+        req = again.submit(together[i].prompt, WORK[i][1])
+        again.run_until_idle()
+        assert list(req.tokens) == list(together[i].tokens)
+        assert len(alone[0].tokens) == WORK[i][1]
+
+
+def test_a_preempted_request_replays_to_the_same_tokens(lm):
+    """A replay is a prefill from position 0: the state it left behind is
+    overwritten, not continued."""
+    model, params = lm
+    _, want = serve(engine_for(model, params), work=WORK[:2])
+    engine = engine_for(model, params)
+    engine.warmup()
+    sched = FCFSScheduler(engine)
+    rng = np.random.default_rng(5)
+    reqs = [sched.submit(rng.integers(0, CFG["vocab_size"], p), a)
+            for p, a in WORK[:2]]
+    inj = FaultInjector(seed=0)
+    inj.arm("serving.kv_append", kind="raise", times=1)
+    with inj:
+        sched.run_until_idle()
+    assert inj.fired_log == [("serving.kv_append", "raise")]
+    assert sched.metrics.report()["kv_preemptions"] == 1
+    assert sched.engine_restarts == 0
+    for got, ref in zip(reqs, want):
+        assert list(got.tokens) == list(ref.tokens)
+
+
+# -- the share of the experts ----------------------------------------------- #
+
+def test_two_shares_and_the_gated_shared_expert_once_add_up():
+    """Experts 0-3 on one chip and 4-7 on the other, each with the whole
+    shared expert and its gate: the two parts less one shared term are the
+    uncut layer."""
+    kw = dict(n_experts=8, d_model=32, d_ff=16, top_k=2,
+              compute_dtype=jnp.float32, activation="silu", shared_d_ff=16,
+              shared_gate=True)
+    whole = DroplessMoE(**kw)
+    x = jax.random.normal(jax.random.PRNGKey(0), (40, 32))
+    params = whole.init(jax.random.PRNGKey(1), x)
+    inner = params["params"]
+    gate = jax.nn.sigmoid(x @ inner["shared_gate"])
+    sh = inner["shared"]
+    shared = gate * ((jax.nn.silu(x @ sh["gate_proj"]["kernel"])
+                      * (x @ sh["up_proj"]["kernel"]))
+                     @ sh["down_proj"]["kernel"])
+    assert float(jnp.max(jnp.abs(shared))) > 1e-3
+    parts = []
+    for first in (0, 4):
+        cut = {"params": dict(inner, **{
+            name: inner[name][first:first + 4]
+            for name in ("w_gate", "w_up", "w_down")})}
+        parts.append(DroplessMoE(held=(first, 4), **kw).apply(cut, x))
+    np.testing.assert_allclose(parts[0] + parts[1] - shared,
+                               whole.apply(params, x), atol=1e-5, rtol=1e-5)
+
+
+# -- what an engine refuses for a recurrent state --------------------------- #
+
+def _constructed(option):
+    return lambda model, params: engine_for(model, params, **option)
+
+
+def _tensor_axis(model, params):
+    return engine_for(build(CFG, tensor_axis="mp"), params, comm=object())
+
+
+def _migration(model, params):
+    return engine_for(model, params).export_slot_kv(0)
+
+
+def _chunked(model, params):
+    engine = engine_for(model, params)
+    plan = engine.plan_admission(np.arange(40), max_new=4)
+    assert engine.plan_chunks(plan, 16) is None
+    return engine.begin_chunked(plan, [(0, 16, 32), (16, 24, 32)])
+
+
+@pytest.mark.parametrize("attempt,match", [
+    (_constructed(dict(paged=False, prefix_cache_blocks=8)), "prefix reuse"),
+    (_constructed(dict(speculative=SpeculativeConfig(k=2))), "speculative"),
+    (_constructed(dict(decode_window=2)), "decode_window"),
+    (_chunked, "chunked prefill"),
+    (_migration, "migration"),
+    (_tensor_axis, "tensor_axis"),
+    (_constructed(dict(paged=False)), "paged=False"),
+], ids=["prefix-reuse", "speculation", "decode-window", "chunked-prefill",
+        "kv-migration", "tensor-axis", "dense"])
+def test_engine_refuses_what_continues_a_prompt_at_an_offset(lm, attempt,
+                                                             match):
+    """A hit, a verify window, a chunk and a migrated request all need the
+    state at an offset, which is not kept (snapshots are not built)."""
+    model, params = lm
+    with pytest.raises(ValueError, match="recurrent state.*" + match):
+        attempt(model, params)
+
+
+def test_prompts_repeat_without_prefix_reuse(lm):
+    model, params = lm
+    engine = engine_for(model, params)
+    assert not engine.prefix_enabled and not engine.migration_supported
+    sched = FCFSScheduler(engine)
+    first = sched.submit(np.arange(24), 5)
+    sched.run_until_idle()
+    again = sched.submit(np.arange(24), 5)
+    sched.run_until_idle()
+    assert list(first.tokens) == list(again.tokens)
+    assert engine.prefix_stats() == {}
+
+
+# -- the state kind's account and instruments ------------------------------- #
+
+def test_kv_stats_admission_and_the_three_instruments(lm):
+    from chainermn_tpu.monitor import catalog
+    from chainermn_tpu.monitor._state import get_registry
+
+    model, params = lm
+    engine = engine_for(model, params)
+    kinds = engine.kv_stats()["kinds"]
+    assert set(kinds) == {"full", "linear"}
+    # three slots and the scratch row, three layers: S in float32 and the
+    # convolution's three last inputs
+    per_row = 4 * 8 * 8 * 4 + 3 * 64 * 4
+    assert kinds["linear"] == {"slots": 3, "layers": 3, "slots_live": 0,
+                               "bytes": 3 * 4 * per_row}
+    assert [x.shape for x in engine._store[0].values()] == [
+        (4, 4, 8, 8), (4, 3, 64)]
+    # admission counts the kind as one unit a slot
+    assert list(engine.blocks_needed(40, 60)) == [13, 1]
+    assert engine.kv_blocks_admittable()[1] == 3
+    sched = FCFSScheduler(engine)
+    reqs = [sched.submit(np.arange(p), a) for p, a in ((5, 6), (20, 3))]
+    sched.step()
+    sched.step()
+    assert engine.kv_stats()["kinds"]["linear"]["slots_live"] == 2
+    assert engine.kv_blocks_admittable()[1] == 1
+    sched.run_until_idle()
+    report = sched.metrics.report()
+    assert report["state_bytes"] == kinds["linear"]["bytes"]
+    assert report["state_slots_live"] == 0
+    # tokens x linear layers whose state a program advanced: both prompts,
+    # and every token decoded after a request's first
+    assert report["linear_state_tokens"] == 3 * (25 + (6 - 1) + (3 - 1))
+    assert all(len(r.tokens) == n for r, n in zip(reqs, (6, 3)))
+    names = {"serving_state_slots_live", "serving_state_bytes",
+             "linear_state_tokens_total"}
+    assert names <= set(catalog.METRIC_NAMES)
+    snap = get_registry().snapshot()
+    assert names <= {key.split("{")[0] for kind in ("counters", "gauges")
+                     for key in snap[kind]}
+
+
+# -- the kernels at heads of 256 -------------------------------------------- #
+
+def _quantized(x):
+    sc = np.maximum(np.abs(x).max(-1) / 127.0, 1e-8)[..., None]
+    return np.clip(np.round(x / sc), -127, 127) * sc
+
+
+@pytest.mark.parametrize("store", ["int8", "bf16"])
+def test_paged_kernel_at_heads_of_256_on_two_kv_heads(store):
+    """16 query heads on 2 KV heads of 256 (8 a group, a head spans two
+    tiles of lanes), blocks of 16; the int8 store of 2 heads is held folded
+    (``paged_store_shape``). The kernel against the XLA read path, and both
+    against attention written out."""
+    b, h, hk, d, bs = 2, 16, 2, 256, 16
+    rng = np.random.default_rng(0)
+    totals = [70, 37]
+    width = 5
+    table = np.zeros((b, width), np.int32)
+    table[0, :5] = 1 + np.arange(5)
+    table[1, :3] = 6 + np.arange(3)
+    n_blocks = 9
+    dt = jnp.bfloat16 if store == "bf16" else jnp.float32
+    shape = paged_store_shape(n_blocks, bs, hk, d,
+                              "int8" if store == "int8" else "none")
+    assert shape == ((n_blocks, bs * hk, d) if store == "int8"
+                     else (n_blocks, bs, hk, d))
+    cache = {"k": jnp.zeros(shape, jnp.int8 if store == "int8" else dt)}
+    cache["v"] = cache["k"]
+    if store == "int8":
+        cache["k_scale"] = jnp.zeros(
+            paged_scale_shape(n_blocks, bs, hk), jnp.float32)
+        cache["v_scale"] = cache["k_scale"]
+    ks, vs = (rng.standard_normal((b, 70, hk, d)).astype(np.float32)
+              for _ in range(2))
+    qs = rng.standard_normal((b, 70, h, d)).astype(np.float32)
+    prompt = np.array([45, 20])
+    cache = paged_write_kv(
+        dict(cache, table=jnp.asarray(table), valid=jnp.asarray(prompt)),
+        jnp.asarray(ks[:, :48], dt), jnp.asarray(vs[:, :48], dt),
+        jnp.zeros((b,), jnp.int32))
+    pos = prompt.copy()
+    tol = 2e-2 if store == "bf16" else 2e-6
+    for _ in range(26):
+        live = pos < np.array(totals)
+        at = np.minimum(pos, 69)
+        row = lambda x: jnp.asarray(x[np.arange(b), at][:, None], dt)
+        tab = jnp.asarray(np.where(live[:, None], table, 0))
+        outs = []
+        for use_kernel in (False, True):
+            c = dict(cache, table=tab)
+            if use_kernel:
+                c["use_kernel"] = True
+            o, new = paged_update_cache_and_attend(
+                c, row(qs), row(ks), row(vs), jnp.asarray(pos, jnp.int32))
+            outs.append(np.asarray(o, np.float32))
+        cache = new
+        for i in np.flatnonzero(live):
+            t = pos[i]
+            kd, vd = ks[i, :t + 1], vs[i, :t + 1]
+            if store == "int8":
+                kd, vd = _quantized(kd), _quantized(vd)
+            want = np.zeros((h, d), np.float32)
+            for head in range(h):
+                g = head // (h // hk)
+                sc = kd[:, g] @ qs[i, t, head] / np.sqrt(d)
+                p = np.exp(sc - sc.max())
+                want[head] = (p / p.sum()) @ vd[:, g]
+            for o in outs:
+                np.testing.assert_allclose(o[i, 0], want, atol=tol * 5,
+                                           rtol=tol)
+        pos = pos + live
+
+
+def test_a_store_of_four_heads_or_more_keeps_its_shape():
+    """Only an int8 store of fewer than 4 heads is held folded: the served
+    cells' stores (4, 8 and 16 KV heads) are the arrays they were."""
+    for heads in (4, 8, 16):
+        assert paged_store_shape(9, 16, heads, 128, "int8") == (
+            9, 16, heads, 128)
+    assert paged_store_shape(9, 16, 2, 256, "none") == (9, 16, 2, 256)
+    assert paged_store_shape(9, 16, 2, 256, "int8") == (9, 32, 256)
+
+
+@pytest.mark.parametrize("block", [32, 64])
+def test_flash_forward_at_heads_of_256_matches_plain_attention(block):
+    b, t, h, hk, d = 1, 128, 16, 2, 256
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b, t, hk, d)), jnp.float32)
+            for _ in range(2))
+    got = flash_attention(q, k, v, causal=True, block_q=block, block_k=block)
+    rep = lambda x: jnp.repeat(x, h // hk, axis=2)
+    i = np.arange(t)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, rep(k)) / np.sqrt(d)
+    p = jax.nn.softmax(jnp.where((i[:, None] >= i[None, :])[None, None], s,
+                                 -jnp.inf), -1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", p, rep(v))
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+
+
+# -- the scopes the benchmark's readers find operations by ------------------ #
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_scopes_are_in_the_lowered_programs(lm, program):
+    model, params = lm
+    engine = engine_for(model, params)
+    if program == "decode":
+        text = engine._decode_fn.lower(*engine._decode_args()).as_text(
+            debug_info=True)
+    else:
+        k = engine.prefill_rows(32)
+        zeros = jnp.zeros((k,), jnp.int32)
+        text = engine._prefill_fns[32].lower(
+            engine.params, engine._store, engine._table_args(rows=k),
+            jnp.zeros((k, 32), jnp.int32), zeros, zeros,
+            jnp.zeros((k,), bool), jnp.zeros((k, 2), jnp.uint32)).as_text(
+                debug_info=True)
+    for scope in ("in_proj", "conv", "recurrence", "norm_gate", "out_proj"):
+        for block in (0, 1, 2):
+            assert f"block_{block}/gdn/{scope}" in text, scope
+    assert "block_3/gdn" not in text
+    assert "block_3/attn/q_proj" in text and "block_3/attn/q_norm" in text
+    for block in range(4):
+        for scope in ("moe/shared/gate_proj", "moe/shared/gate",
+                      "moe/route", "moe/experts", "moe/combine"):
+            assert f"block_{block}/{scope}" in text, scope
