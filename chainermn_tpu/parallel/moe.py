@@ -469,7 +469,17 @@ class DroplessMoE(nn.Module):
     One code path for a prefill of thousands of tokens and a decode step
     of a hundred: assignments are sorted by expert, rows gathered in that
     order, three grouped products (:func:`grouped_matmul`) over the
-    experts, and the rows gathered back and summed with their weights.
+    experts, and the rows gathered back and summed with their weights. The
+    rows come back as ``top_k`` gathers of ``[t, d]``, a token's j-th
+    assignment each, and each is cast to float32, zeroed where its expert
+    is not held, weighed and added into one float32 ``[t, d]`` accumulator
+    (in the order 0 .. ``top_k - 1``), cast once at the end: every expert
+    row is read once, in ``compute_dtype``. Never as ``[t, top_k, d]``:
+    with the top-k in the second-minor dimension (6 or 10 of the chip's 8
+    sublanes a tile) that array is no view of ``[t * top_k, d]``, and the
+    chip relays every row into float32 padded to 8 or 16 sublanes before
+    it sums them, 18.8 bytes moved an element where 2 are read
+    (``scripts/aot_moe_combine.py``).
     Under ``jax.named_scope`` the device operations read ``moe/route``
     (router, top-k, sort, gather), ``moe/experts`` (the products),
     ``moe/combine`` and ``moe/shared``.
@@ -548,17 +558,22 @@ class DroplessMoE(nn.Module):
                 y = grouped_matmul(h, w_down.astype(dt), sizes, dt)
             with jax.named_scope("combine"):
                 back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(t * k, dtype=order.dtype))
-                y = y.at[back].get(mode="promise_in_bounds",
-                                   unique_indices=True).reshape(t, k, d)
-                y = y.astype(jnp.float32)
-                if self.held is not None:
-                    # rows no product wrote hold whatever was there
-                    y = jnp.where(here[..., None], y, 0.0)
-                out = jnp.einsum("tk,tkd->td", w, y)
+                    jnp.arange(t * k, dtype=order.dtype)).reshape(t, k)
+                out = jnp.zeros((t, d), jnp.float32)
+                for j in range(k):
+                    # every token's j-th row, [t, d]: [t, k, d] is the
+                    # array the chip pads and relays (the docstring)
+                    yj = y.at[back[:, j]].get(
+                        mode="promise_in_bounds",
+                        unique_indices=True).astype(jnp.float32)
+                    if self.held is not None:
+                        # rows no product wrote hold whatever was there
+                        yj = jnp.where(here[:, j:j + 1], yj, 0.0)
+                    out = out + w[:, j:j + 1] * yj
+                out = out.astype(dt)
             if self.held is None:
-                return out.astype(dt)
-            return out.astype(dt), jnp.sum(here, axis=-1, dtype=jnp.int32)
+                return out
+            return out, jnp.sum(here, axis=-1, dtype=jnp.int32)
 
         t = x.shape[0]
         if t > _TOKEN_PASS and t % _TOKEN_PASS == 0:

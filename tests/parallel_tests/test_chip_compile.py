@@ -3,10 +3,14 @@
 lowered the INTERPRETED paged kernel and so passed a copy that Mosaic
 refuses; these hold the decode layer and the scale write to the real
 lowering at the served widths, and count the passes over a scale array.
+ISSUE 36's relayout of every expert row into padded float32 was the chip's
+tiling at work on a ``[t, k, d]`` array: the mixture layer's combine is held
+to the compiled program here too.
 
 One file, the topology in a fixture: only the worker that runs these tests
 loads the TPU's library (the on-chip-measurement guide, section 2)."""
 
+import contextlib
 import importlib.util
 import pathlib
 
@@ -15,8 +19,27 @@ import pytest
 
 from chainermn_tpu import ops
 
-SCRIPT = (pathlib.Path(__file__).resolve().parents[2]
-          / "scripts" / "aot_decode_writes.py")
+SCRIPTS = pathlib.Path(__file__).resolve().parents[2] / "scripts"
+
+
+def _script(name):
+    spec = importlib.util.spec_from_file_location(name,
+                                                  SCRIPTS / f"{name}.py")
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    return script
+
+
+@contextlib.contextmanager
+def _traced_as_on_the_chip():
+    """The kernels traced as the chip traces them, and at the chip's
+    default matmul precision, not the suite's ``highest``."""
+    ops.set_kernels_interpreted(False)
+    try:
+        with jax.default_matmul_precision("default"):
+            yield
+    finally:
+        ops.set_kernels_interpreted(None)
 
 
 @pytest.fixture(scope="module")
@@ -31,20 +54,13 @@ def topo():
 
 @pytest.fixture
 def count(topo):
-    """``scripts/aot_decode_writes.py``'s count for one store and program,
-    the kernels traced as the chip traces them (and at the chip's default
-    matmul precision, not the suite's ``highest``)."""
-    spec = importlib.util.spec_from_file_location("aot_decode_writes", SCRIPT)
-    script = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(script)
-    ops.set_kernels_interpreted(False)
-    try:
-        with jax.default_matmul_precision("default"):
-            yield lambda store, program: next(script.records(
-                topo, [s for s in script.STORES if s[0] == store],
-                (program,)))[0]
-    finally:
-        ops.set_kernels_interpreted(None)
+    """``scripts/aot_decode_writes.py``'s count for one store and
+    program."""
+    script = _script("aot_decode_writes")
+    with _traced_as_on_the_chip():
+        yield lambda store, program: next(script.records(
+            topo, [s for s in script.STORES if s[0] == store],
+            (program,)))[0]
 
 
 @pytest.mark.parametrize("store", ["cell1", "cell3_window", "cell5_full"])
@@ -69,3 +85,24 @@ def test_prefill_write_relays_no_scale_array(count):
     assert rec["mosaic_calls"] == 1
     assert rec["whole_scale_array_ops"] == rec["async_staging"]
     assert rec["whole_int8_store_ops"] == 0
+
+
+@pytest.mark.parametrize("layer", ["cell3", "cell4", "cell5"])
+def test_combine_forms_no_float32_array_of_the_expert_rows(topo, layer):
+    """``scripts/aot_moe_combine.py``'s listing of one mixture layer at a
+    cell's widths and a decode step's rows (top-6 and top-10: neither a
+    multiple of the 8 sublanes): the expert rows come back as ``top_k``
+    gathers of ``[t, d]`` in bfloat16, and no operation, under
+    ``moe/combine`` or under no scope at all, has a float32 result of
+    ``t * k * d`` elements (the parent's ``f32[128,10,3072]`` reshape,
+    25 MB written for 7.9 MB read)."""
+    script = _script("aot_moe_combine")
+    with _traced_as_on_the_chip():
+        rec, listed = next(script.records(
+            topo, [spec for spec in script.LAYERS if spec[0] == layer], ()))
+    assert rec["f32_expert_row_arrays"] == 0
+    assert rec["unscoped_expert_row_ops"] == 0
+    rows = f"bf16[{rec['t']},{rec['d']}]"
+    assert sum(o["op"] == "fusion kCustom" and o["result"].startswith(rows)
+               and o["scope"].endswith("combine/gather")
+               for o in listed) == rec["k"]
