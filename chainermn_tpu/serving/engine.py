@@ -1699,35 +1699,39 @@ class ServingEngine:
                     # detection exists for
                     inject(point, batch=len(plans), bucket=bucket,
                            slots=slots)
-                    tokens = np.zeros((k, bucket), np.int32)
-                    starts = np.zeros((k,), np.int32)
-                    last = np.zeros((k,), np.int32)
-                    active = np.zeros((k,), bool)
-                    slot_ids = np.zeros((k,), np.int32)
-                    keys = [jnp.zeros((2,), jnp.uint32)] * k
-                    extra = ()
-                    if self.prefix_cache is not None:
-                        fetch_ids = np.zeros((k, self._n_prog_blocks),
-                                             np.int32)
-                    for i, (plan, slot) in enumerate(zip(plans, slots)):
-                        suffix = plan.prompt[plan.start:]
-                        tokens[i, : len(suffix)] = suffix
-                        starts[i] = plan.start
-                        last[i] = len(suffix) - 1
-                        active[i] = True
-                        slot_ids[i] = slot
-                        keys[i] = plan.rng
-                        if plan.match is not None:
-                            fetch_ids[i, : len(plan.match.block_ids)] = \
-                                plan.match.block_ids
-                    if self.prefix_cache is not None:
-                        extra = (self._store, jnp.asarray(fetch_ids))
-                    self.caches, firsts, keys_out = self._prefill_fns[bucket](
-                        self.params, self.caches, jnp.asarray(tokens),
-                        jnp.asarray(slot_ids), jnp.asarray(starts),
-                        jnp.asarray(last), jnp.asarray(active),
-                        jnp.stack(keys), *extra)
-                    firsts = device_fetch(firsts)
+                    with annotate("chainermn.serving_prefill_args"):
+                        tokens = np.zeros((k, bucket), np.int32)
+                        starts = np.zeros((k,), np.int32)
+                        last = np.zeros((k,), np.int32)
+                        active = np.zeros((k,), bool)
+                        slot_ids = np.zeros((k,), np.int32)
+                        keys = [jnp.zeros((2,), jnp.uint32)] * k
+                        extra = ()
+                        if self.prefix_cache is not None:
+                            fetch_ids = np.zeros((k, self._n_prog_blocks),
+                                                 np.int32)
+                        for i, (plan, slot) in enumerate(zip(plans, slots)):
+                            suffix = plan.prompt[plan.start:]
+                            tokens[i, : len(suffix)] = suffix
+                            starts[i] = plan.start
+                            last[i] = len(suffix) - 1
+                            active[i] = True
+                            slot_ids[i] = slot
+                            keys[i] = plan.rng
+                            if plan.match is not None:
+                                fetch_ids[i, : len(plan.match.block_ids)] = \
+                                    plan.match.block_ids
+                        if self.prefix_cache is not None:
+                            extra = (self._store, jnp.asarray(fetch_ids))
+                        args = (jnp.asarray(tokens), jnp.asarray(slot_ids),
+                                jnp.asarray(starts), jnp.asarray(last),
+                                jnp.asarray(active), jnp.stack(keys), *extra)
+                    with annotate("chainermn.serving_prefill_dispatch"):
+                        self.caches, firsts, keys_out = \
+                            self._prefill_fns[bucket](
+                                self.params, self.caches, *args)
+                    with annotate("chainermn.serving_prefill_fetch"):
+                        firsts = device_fetch(firsts)
             except Exception as e:
                 if not self._state_ok():
                     raise EngineStateError(
@@ -1837,31 +1841,36 @@ class ServingEngine:
                                hits=n_cached, batch=len(plans))
                     inject(point, batch=len(plans), bucket=bucket,
                            slots=slots)
-                    tokens = np.zeros((k, bucket), np.int32)
-                    starts = np.zeros((k,), np.int32)
-                    last = np.zeros((k,), np.int32)
-                    active = np.zeros((k,), bool)
-                    table = self._table_args(rows=k)
-                    keys = [jnp.zeros((2,), jnp.uint32)] * k
-                    for i, (plan, slot) in enumerate(zip(plans, slots)):
-                        ids = self._paged_alloc_slot(plan, slot)
-                        alloc_records.append((slot, ids))
-                        for kv, ops in zip(self._kv, table):
-                            ops["table"][i] = kv.tables[slot]
-                        for ops in table[len(self._kv):]:
-                            ops["slots"][i] = slot
-                        suffix = plan.prompt[plan.start:]
-                        tokens[i, : len(suffix)] = suffix
-                        starts[i] = plan.start
-                        last[i] = len(suffix) - 1
-                        active[i] = True
-                        keys[i] = plan.rng
-                    self._store, firsts, keys_out = self._prefill_fns[bucket](
-                        self.params, self._store, table,
-                        jnp.asarray(tokens), jnp.asarray(starts),
-                        jnp.asarray(last), jnp.asarray(active),
-                        jnp.stack(keys))
-                    firsts = self._take_moe_counts(device_fetch(firsts), k)
+                    with annotate("chainermn.serving_prefill_args"):
+                        tokens = np.zeros((k, bucket), np.int32)
+                        starts = np.zeros((k,), np.int32)
+                        last = np.zeros((k,), np.int32)
+                        active = np.zeros((k,), bool)
+                        table = self._table_args(rows=k)
+                        keys = [jnp.zeros((2,), jnp.uint32)] * k
+                        for i, (plan, slot) in enumerate(zip(plans, slots)):
+                            ids = self._paged_alloc_slot(plan, slot)
+                            alloc_records.append((slot, ids))
+                            for kv, ops in zip(self._kv, table):
+                                ops["table"][i] = kv.tables[slot]
+                            for ops in table[len(self._kv):]:
+                                ops["slots"][i] = slot
+                            suffix = plan.prompt[plan.start:]
+                            tokens[i, : len(suffix)] = suffix
+                            starts[i] = plan.start
+                            last[i] = len(suffix) - 1
+                            active[i] = True
+                            keys[i] = plan.rng
+                        args = (jnp.asarray(tokens), jnp.asarray(starts),
+                                jnp.asarray(last), jnp.asarray(active),
+                                jnp.stack(keys))
+                    with annotate("chainermn.serving_prefill_dispatch"):
+                        self._store, firsts, keys_out = \
+                            self._prefill_fns[bucket](
+                                self.params, self._store, table, *args)
+                    with annotate("chainermn.serving_prefill_fetch"):
+                        firsts = self._take_moe_counts(device_fetch(firsts),
+                                                       k)
             except Exception as e:
                 for slot, ids in alloc_records:   # undo: nothing admitted
                     self._paged_unalloc_slot(slot, ids)
@@ -2015,25 +2024,28 @@ class ServingEngine:
                 inject(SERVING_CHUNK_PREFILL, slot=slot,
                        chunk=st.next_idx, of=len(st.chunks),
                        bucket=bucket, frontier=frontier)
-                tokens = np.zeros((k, bucket), np.int32)
-                starts = np.zeros((k,), np.int32)
-                last = np.zeros((k,), np.int32)
-                active = np.zeros((k,), bool)
-                table = np.zeros((k, self._n_max), np.int32)
-                keys = [jnp.zeros((2,), jnp.uint32)] * k
-                tokens[0, :clen] = st.prompt[frontier:frontier + clen]
-                starts[0] = frontier
-                last[0] = clen - 1
-                active[0] = True
-                table[0, : len(st.ids)] = st.ids
-                if final:
-                    keys[0] = st.rng
-                self._store, nxt, keys_out = self._prefill_fns[bucket](
-                    self.params, self._store, ({"table": table},),
-                    jnp.asarray(tokens), jnp.asarray(starts),
-                    jnp.asarray(last), jnp.asarray(active),
-                    jnp.stack(keys))
-                first = int(device_fetch(nxt)[0]) if final else None
+                with annotate("chainermn.serving_prefill_args"):
+                    tokens = np.zeros((k, bucket), np.int32)
+                    starts = np.zeros((k,), np.int32)
+                    last = np.zeros((k,), np.int32)
+                    active = np.zeros((k,), bool)
+                    table = np.zeros((k, self._n_max), np.int32)
+                    keys = [jnp.zeros((2,), jnp.uint32)] * k
+                    tokens[0, :clen] = st.prompt[frontier:frontier + clen]
+                    starts[0] = frontier
+                    last[0] = clen - 1
+                    active[0] = True
+                    table[0, : len(st.ids)] = st.ids
+                    if final:
+                        keys[0] = st.rng
+                    args = (jnp.asarray(tokens), jnp.asarray(starts),
+                            jnp.asarray(last), jnp.asarray(active),
+                            jnp.stack(keys))
+                with annotate("chainermn.serving_prefill_dispatch"):
+                    self._store, nxt, keys_out = self._prefill_fns[bucket](
+                        self.params, self._store, ({"table": table},), *args)
+                with annotate("chainermn.serving_prefill_fetch"):
+                    first = int(device_fetch(nxt)[0]) if final else None
         except Exception as e:
             if not self._state_ok():
                 raise EngineStateError(
@@ -2706,8 +2718,9 @@ class ServingEngine:
             inject(SERVING_DECODE, active=int(self._active.sum()))
             with annotate("chainermn.serving_decode_args"):
                 args = self._decode_args()
-            state, nxt, self._keys = self._decode_fn(*args)
-            self._set_kv_state(state)
+            with annotate("chainermn.serving_decode_dispatch"):
+                state, nxt, self._keys = self._decode_fn(*args)
+                self._set_kv_state(state)
             with annotate("chainermn.serving_decode_fetch"):
                 nxt = self._take_moe_counts(device_fetch(nxt), self.n_slots)
         with annotate("chainermn.serving_decode_post"):
@@ -2745,8 +2758,9 @@ class ServingEngine:
             inject(SERVING_DECODE, active=int(self._active.sum()), window=n)
             with annotate("chainermn.serving_decode_args"):
                 args = self._decode_args()
-            state, out, self._keys = self._window_fn(*args)
-            self._set_kv_state(state)
+            with annotate("chainermn.serving_decode_dispatch"):
+                state, out, self._keys = self._window_fn(*args)
+                self._set_kv_state(state)
             with annotate("chainermn.serving_decode_fetch"):
                 out = device_fetch(out)
         with annotate("chainermn.serving_decode_post"):
@@ -2793,7 +2807,9 @@ class ServingEngine:
                 args = (self._table_args(), jnp.asarray(tokens),
                         jnp.asarray(self._pos), jnp.asarray(valid),
                         jnp.asarray(self._active))
-            self._store, g = self._spec_fn(self.params, self._store, *args)
+            with annotate("chainermn.serving_decode_dispatch"):
+                self._store, g = self._spec_fn(self.params, self._store,
+                                               *args)
             with annotate("chainermn.serving_decode_fetch"):
                 g = device_fetch(g)
         with annotate("chainermn.serving_decode_post"):

@@ -346,12 +346,21 @@ STEP_PHASES = (
     "chainermn.serving_flush",        # deferred prefix inserts
     "chainermn.serving_account",      # step gauges, cost-ledger flush
 )
-#: The only spans that may open inside a phase, by parent.
+#: The only spans that may open inside a phase or inside one of these
+#: children, by parent, in the order they open. A program's host side is
+#: split where its time goes: operands (``_args``), the jit call
+#: (``_dispatch``), the wait for its result (``_fetch``).
+_PREFILL_CHILDREN = ("chainermn.serving_prefill_args",
+                     "chainermn.serving_prefill_dispatch",
+                     "chainermn.serving_prefill_fetch")
 STEP_PHASE_CHILDREN = {
     "chainermn.serving_admit": ("chainermn.serving_prefill",),
     "chainermn.serving_blocks": ("chainermn.serving_chunk_prefill",),
     "chainermn.serving_decode": ("chainermn.serving_decode_args",
+                                 "chainermn.serving_decode_dispatch",
                                  "chainermn.serving_decode_fetch"),
+    "chainermn.serving_prefill": _PREFILL_CHILDREN,
+    "chainermn.serving_chunk_prefill": _PREFILL_CHILDREN,
 }
 
 

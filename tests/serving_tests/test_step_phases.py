@@ -1,9 +1,11 @@
 """The profiler's view of ``FCFSScheduler.step()``: sibling phase spans in
 the order ``STEP_PHASES`` gives, children only inside their stated parent,
-``serving_decode`` at its old extent, the counts three spans carry, an idle
-client under ``serving_idle`` — read through a recording stand-in put in
-place of ``jax.profiler.TraceAnnotation`` (no timing is asserted). And the
-``blocks_live`` gauge the same bookkeeping feeds."""
+``serving_decode`` at its old extent, the host side of a decode and of a
+prefill program split into operands, dispatch and fetch, the counts three
+spans carry, an idle client under ``serving_idle`` — read through a
+recording stand-in put in place of ``jax.profiler.TraceAnnotation`` (no
+timing is asserted). And the ``blocks_live`` gauge the same bookkeeping
+feeds."""
 
 import threading
 import time
@@ -26,6 +28,7 @@ from chainermn_tpu.serving import engine as engine_mod
 from chainermn_tpu.serving.scheduler import STEP_PHASE_CHILDREN, STEP_PHASES
 
 DECODE, SPEC = "chainermn.serving_decode", "chainermn.serving_spec_verify"
+PREFILL = "chainermn.serving_prefill"
 IDLE = "chainermn.serving_idle"
 ENGINES = {
     "dense": dict(n_slots=2, prefill_len=6, cache_len=24),
@@ -145,45 +148,98 @@ def test_step_is_tiled_by_its_phases_in_order(engines, recorder, kind):
                  for n in STEP_PHASES)
     # siblings only, in the program's own order: no span holds a whole step
     assert tuple(n for n, _, _ in top) == want
-    for name, _, children in top:
+
+    def check(name, children):
         allowed = STEP_PHASE_CHILDREN.get(DECODE if name == SPEC else name,
                                           ())
         for child, _, grand in children:
             assert child in allowed, (name, child)
-            assert not grand, (child, grand)
+            check(child, grand)
+
+    for name, _, children in top:
+        check(name, children)
     by_name = {n: c for n, _, c in top}
-    assert [c[0] for c in by_name["chainermn.serving_admit"]] == [
-        "chainermn.serving_prefill"]
+    admit = by_name["chainermn.serving_admit"]
+    assert [c[0] for c in admit] == [PREFILL]
+    assert [c[0] for c in admit[0][2]] == list(STEP_PHASE_CHILDREN[PREFILL])
     assert [c[0] for c in by_name[want[3]]] == list(
         STEP_PHASE_CHILDREN[DECODE])
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged", "window"])
+def noted(recorder, what, fn):
+    """``fn``, noting each call in the recording under ``what``."""
+    def call(*args, **kw):
+        recorder.note("call", what)
+        return fn(*args, **kw)
+    return call
+
+
+def note_calls(engine, recorder, monkeypatch):
+    """Note the operand builder, every program and every fetch."""
+    monkeypatch.setattr(engine, "_decode_args", noted(
+        recorder, "_decode_args", engine._decode_args))
+    for attr in ("_decode_fn", "_window_fn", "_spec_fn"):
+        if getattr(engine, attr, None) is not None:
+            monkeypatch.setattr(engine, attr, noted(
+                recorder, "program", getattr(engine, attr)))
+    monkeypatch.setattr(engine, "_prefill_fns", {
+        b: noted(recorder, "program", f)
+        for b, f in engine._prefill_fns.items()})
+    monkeypatch.setattr(engine_mod, "device_fetch", noted(
+        recorder, "device_fetch", engine_mod.device_fetch))
+
+
+def inside(seq, name):
+    """What opens, closes and is called inside the first span of ``name``,
+    and what follows it."""
+    lo, hi = seq.index(("open", name)), seq.index(("close", name))
+    return seq[lo + 1:hi], seq[hi + 1]
+
+
+@pytest.mark.parametrize("kind", list(ENGINES))
 def test_decode_span_keeps_its_extent(engines, recorder, monkeypatch, kind):
     """``serving_decode`` still opens before the operands are built and
-    closes after the fetch; the two children hold just those."""
+    closes after the fetch; its three children hold the operands, the
+    program's call and the fetch, in that order. A speculative round
+    builds its operands inline."""
     engine = engines(kind)
-    args, fetch = engine._decode_args, engine_mod.device_fetch
-
-    def noted_args():
-        recorder.note("call", "_decode_args")
-        return args()
-
-    def noted_fetch(values):
-        recorder.note("call", "device_fetch")
-        return fetch(values)
-
-    monkeypatch.setattr(engine, "_decode_args", noted_args)
-    monkeypatch.setattr(engine_mod, "device_fetch", noted_fetch)
+    note_calls(engine, recorder, monkeypatch)
     log = one_recorded_step(engine, recorder)
-    seq = [(what, name) for what, name, _, _ in log]
-    lo, hi = seq.index(("open", DECODE)), seq.index(("close", DECODE))
-    assert seq[lo + 1:hi] == [
-        ("open", "chainermn.serving_decode_args"), ("call", "_decode_args"),
+    held, after = inside([(w, n) for w, n, _, _ in log],
+                         SPEC if kind == "spec" else DECODE)
+    args = [] if kind == "spec" else [("call", "_decode_args")]
+    assert held == [
+        ("open", "chainermn.serving_decode_args"), *args,
         ("close", "chainermn.serving_decode_args"),
+        ("open", "chainermn.serving_decode_dispatch"), ("call", "program"),
+        ("close", "chainermn.serving_decode_dispatch"),
         ("open", "chainermn.serving_decode_fetch"), ("call", "device_fetch"),
         ("close", "chainermn.serving_decode_fetch")]
-    assert seq[hi + 1] == ("open", "chainermn.serving_decode_post")
+    assert after == ("open", "chainermn.serving_decode_post")
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_prefill_span_is_split_where_its_time_goes(engines, recorder,
+                                                   monkeypatch, kind):
+    """An admission's ``serving_prefill`` holds its operands (the block
+    allocation of a paged engine among them), the program's call and the
+    fetch of the first tokens, each in its own child, in that order."""
+    engine = engines(kind)
+    note_calls(engine, recorder, monkeypatch)
+    if kind == "paged":
+        monkeypatch.setattr(engine, "_paged_alloc_slot", noted(
+            recorder, "_paged_alloc_slot", engine._paged_alloc_slot))
+    log = one_recorded_step(engine, recorder)
+    held, after = inside([(w, n) for w, n, _, _ in log], PREFILL)
+    alloc = [("call", "_paged_alloc_slot")] if kind == "paged" else []
+    assert held == [
+        ("open", "chainermn.serving_prefill_args"), *alloc,
+        ("close", "chainermn.serving_prefill_args"),
+        ("open", "chainermn.serving_prefill_dispatch"), ("call", "program"),
+        ("close", "chainermn.serving_prefill_dispatch"),
+        ("open", "chainermn.serving_prefill_fetch"), ("call", "device_fetch"),
+        ("close", "chainermn.serving_prefill_fetch")]
+    assert after == ("close", "chainermn.serving_admit")
 
 
 def test_three_spans_carry_counts(engines, recorder):
