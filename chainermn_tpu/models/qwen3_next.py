@@ -44,8 +44,13 @@ columns, a permutation that a checkpoint loader would apply.
 :class:`GatedDeltaNet` has two forms that agree (tests hold them equal): a
 whole sequence from a zero state runs the chunked form (chunks of
 ``CHUNK``: inside a chunk one triangular system, solved by doubling;
-between chunks a scan over the state), one token a row runs the recurrence
-on the state it is given. Serving: ``kv_cache_spec()`` tells the engine two
+between chunks the state carries over), one token a row runs the recurrence
+on the state it is given. The chunked form is one Pallas kernel
+(:func:`~chainermn_tpu.ops.gated_delta.chunk_gated_delta`: the state in
+VMEM across a prompt's chunks, only the live chunks walked) at heads of
+whole lanes (:func:`~chainermn_tpu.ops.gated_delta.kernel_takes`: the
+published 128), and :func:`chunk_gated_delta_rule` in XLA at narrower ones
+(the tests' small model). Serving: ``kv_cache_spec()`` tells the engine two
 kinds of state, the full layers' K/V rows in a block store and the linear
 layers' ``S`` and the convolution's last inputs, a row a slot
 (:class:`~chainermn_tpu.models.transformer.SlotStateKind`). With
@@ -70,10 +75,13 @@ from chainermn_tpu.models.smallthinker import (
     token_positions,
 )
 from chainermn_tpu.models.transformer import KVCacheKind, SlotStateKind
+from chainermn_tpu.ops.gated_delta import (
+    CHUNK,
+    chunk_gated_delta,
+    kernel_takes,
+)
 from chainermn_tpu.parallel.moe import DroplessMoE
 
-# tokens a chunk of the chunked form holds
-CHUNK = 64
 _HIGHEST = lax.Precision.HIGHEST
 
 
@@ -119,8 +127,10 @@ def recurrent_gated_delta_rule(q, k, v, g, beta, state=None):
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     """The gated delta rule over whole sequences from a zero state, in
-    chunks (arXiv:2412.06464, section 3.3): shapes as
-    :func:`recurrent_gated_delta_rule`. Inside a chunk the tokens' updates
+    chunks (arXiv:2412.06464, section 3.3), in XLA: shapes as
+    :func:`recurrent_gated_delta_rule`. The layer's form for heads the
+    kernel does not take (:func:`~chainermn_tpu.ops.gated_delta.
+    kernel_takes`). Inside a chunk the tokens' updates
     ``u_i = beta_i (v_i - sum_{j<i} decay_ij (k_i . k_j) u_j)`` are one
     unit-triangular system ``(I + L) U = beta V``; ``L`` is strictly lower
     and so ``-L`` is nilpotent, and ``(I + L)^-1`` is the product of ``I +
@@ -251,9 +261,6 @@ class GatedDeltaNet(nn.Module):
                 new_conv = jnp.where(valid[:, None, None] > 0, full[:, 1:],
                                      past.astype(u.dtype))
         with jax.named_scope("recurrence"):
-            q = mixed[..., :key_dim].reshape(b, s, hk, dk)
-            k = mixed[..., key_dim:2 * key_dim].reshape(b, s, hk, dk)
-            v = mixed[..., 2 * key_dim:].reshape(b, s, hv, dv)
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 ba[..., hv:] + dt_bias.astype(jnp.float32))
@@ -261,12 +268,21 @@ class GatedDeltaNet(nn.Module):
                 real = jnp.arange(s)[None, :] < valid[:, None]
                 g = jnp.where(real[..., None], g, 0.0)
                 beta = jnp.where(real[..., None], beta, 0.0)
-            q = _l2norm(q) * dk ** -0.5
-            k = _l2norm(k)
-            if hv != hk:
-                q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+            kernel = not decode and kernel_takes(hk, hv, dk, dv)
+            if not kernel:
+                q = _l2norm(mixed[..., :key_dim].reshape(b, s, hk, dk))
+                k = _l2norm(mixed[..., key_dim:2 * key_dim].reshape(
+                    b, s, hk, dk))
+                q, k = (jnp.repeat(x, hv // hk, axis=2)
+                        for x in (q * dk ** -0.5, k))
+                v = mixed[..., 2 * key_dim:].reshape(b, s, hv, dv)
             new_state = None
-            if decode:
+            if kernel:
+                # q, k and v read out of the convolution's output, q and k
+                # normed, inside the kernel
+                o, last = chunk_gated_delta(mixed, g, beta, valid,
+                                            k_heads=hk, dk=dk)
+            elif decode:
                 # the store's rows past the batch (the scratch row) ride
                 # along with g = 0 and beta = 0, so the whole array is
                 # read and written where it lies
@@ -283,11 +299,11 @@ class GatedDeltaNet(nn.Module):
                         0, axis=0)}
             else:
                 o, last = chunk_gated_delta_rule(q, k, v, g, beta)
-                if prefill:
-                    new_state = {
-                        "S": state["S"].at[state["slots"]].set(last),
-                        "conv": state["conv"].at[state["slots"]].set(
-                            new_conv.astype(state["conv"].dtype))}
+            if prefill:
+                new_state = {
+                    "S": state["S"].at[state["slots"]].set(last),
+                    "conv": state["conv"].at[state["slots"]].set(
+                        new_conv.astype(state["conv"].dtype))}
         with jax.named_scope("norm_gate"):
             # a plain scale here, not 1 + w
             o = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=jnp.float32,
@@ -439,7 +455,7 @@ class Qwen3NextLM(nn.Module):
                 ("S", (self.linear_v_heads, self.linear_k_dim,
                        self.linear_v_dim), "float32"),
                 ("conv", (self.conv_kernel - 1, conv_width),
-                 jnp.dtype(self.compute_dtype).name))))
+                 jnp.dtype(self.compute_dtype).name)), CHUNK))
         return tuple(kinds)
 
     @nn.compact

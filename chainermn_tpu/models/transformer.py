@@ -62,11 +62,15 @@ class SlotStateKind:
     A prefill writes a row's state after its last real token, a decode step
     advances the active slots' rows in place, and the next prefill into a
     freed slot starts from zero and never reads what was there. A prompt
-    cannot be continued at an offset without the state at that offset."""
+    cannot be continued at an offset without the state at that offset.
+    ``chunk``: the tokens a chunk of the kind's whole-prompt form holds,
+    where a prefill walks a row's prompt chunk by chunk (``None``: it does
+    not); the engine counts the chunks a program walks and skips."""
 
     name: str
     layers: tuple
     arrays: tuple
+    chunk: Optional[int] = None
 
 
 class TransformerBlock(nn.Module):

@@ -696,6 +696,12 @@ class ServingEngine:
                            for kind in state_spec]
             self._state_layers = sum(len(k.layers) for k in state_spec)
             self._state_tokens = 0
+            # the chunks a prefill program's whole-prompt form walks (the
+            # rows' prompts) and skips (the rest of the bucket), a layer
+            # at a time, for the kinds that run in chunks
+            self._chunked = [(k.chunk, len(k.layers)) for k in state_spec
+                             if k.chunk]
+            self._prefill_chunks = np.zeros(2, np.int64)
             # prefix reuse runs on the one pool of a model whose layers
             # are all of a kind; with window layers or a recurrent state
             # nothing is inserted or matched
@@ -1909,6 +1915,11 @@ class ServingEngine:
                     and self.prefix_cache.missing_blocks(plan.prompt)
                     >= self._min_insert):
                 self.prefix_cache.insert_shared(plan.prompt, ids[0])
+        for chunk, layers in self._chunked:
+            live = layers * sum(-(-(len(p.prompt) - p.start) // chunk)
+                                for p in plans)
+            self._prefill_chunks += (live,
+                                     layers * k * -(-bucket // chunk) - live)
         self.peak_active = max(self.peak_active, self.active_slots)
         self._guard.check()
         return out
@@ -2591,17 +2602,21 @@ class ServingEngine:
         }
 
     def pop_state_stats(self) -> Optional[tuple]:
-        """``(slots live, bytes, tokens)`` of the state kept a row a slot:
-        rows holding a request now, the bytes of all such arrays, and the
-        tokens x layers whose state the programs advanced since the last
-        call (cleared on read); ``None`` for a model that keeps none. The
-        scheduler drains it into
+        """``(slots live, bytes, tokens, live chunks, padding chunks)`` of
+        the state kept a row a slot: rows holding a request now, the bytes
+        of all such arrays, the tokens x layers whose state the programs
+        advanced, and the chunks x layers that the prefill programs'
+        whole-prompt form walked and skipped (``SlotStateKind.chunk``),
+        each since the last call (cleared on read); ``None`` for a model
+        that keeps none. The scheduler drains it into
         :class:`~chainermn_tpu.serving.metrics.ServingMetrics`."""
         if not (self.paged and self._state):
             return None
         tokens, self._state_tokens = self._state_tokens, 0
+        live, padding = (int(x) for x in self._prefill_chunks)
+        self._prefill_chunks[:] = 0
         return (self.active_slots, sum(st.bytes for st in self._state),
-                tokens)
+                tokens, live, padding)
 
     def flush_inserts(self) -> None:
         """Run the deferred trie inserts (one compiled copy per prompt

@@ -104,6 +104,12 @@ class ServingMetrics:
         self._g_state_bytes = reg.gauge("serving_state_bytes", labels)
         self._c_state_tokens = reg.counter("linear_state_tokens_total",
                                            labels)
+        # chunks x layers a prefill's whole-prompt form walked (the rows'
+        # prompts) and skipped (the rest of the bucket)
+        self._c_prefill_chunks = {
+            kind: reg.counter("linear_prefill_chunks_total",
+                              dict(labels, kind=kind))
+            for kind in ("live", "padding")}
         self._g_queue = reg.gauge("serving_queue_depth_now", labels)
         self._g_active = reg.gauge("serving_active_slots", labels)
         # paged-KV series (PR 7): store occupancy gauges sampled per step,
@@ -211,13 +217,17 @@ class ServingMetrics:
         self._c_moe_total.inc(total)
 
     def record_slot_state(self, slots_live: int, n_bytes: int,
-                          tokens: int) -> None:
+                          tokens: int, chunks_live: int,
+                          chunks_padding: int) -> None:
         """The engine's state kept a row a slot: rows holding a request
-        now, the bytes of its arrays, and the tokens x layers whose state
-        the programs advanced since the last call."""
+        now, the bytes of its arrays, the tokens x layers whose state the
+        programs advanced, and the chunks x layers the prefill programs
+        walked and skipped, since the last call."""
         self._g_state_slots.set(slots_live)
         self._g_state_bytes.set(n_bytes)
         self._c_state_tokens.inc(tokens)
+        self._c_prefill_chunks["live"].inc(chunks_live)
+        self._c_prefill_chunks["padding"].inc(chunks_padding)
 
     def record_token(self, t_prev_token: float, t_token: float) -> None:
         self._h_tpot.observe(t_token - t_prev_token)
@@ -459,6 +469,8 @@ class ServingMetrics:
             out["state_slots_live"] = int(self._g_state_slots.value)
             out["state_bytes"] = int(self._g_state_bytes.value)
             out["linear_state_tokens"] = int(self._c_state_tokens.value)
+            for kind, counter in self._c_prefill_chunks.items():
+                out[f"linear_prefill_chunks_{kind}"] = int(counter.value)
         for hist, prefix in ((self._h_queue, "queue_depth"),
                              (self._h_occ, "slot_occupancy")):
             samples = hist.samples

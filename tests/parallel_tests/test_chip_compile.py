@@ -5,7 +5,8 @@ refuses; these hold the decode layer and the scale write to the real
 lowering at the served widths, and count the passes over a scale array.
 ISSUE 36's relayout of every expert row into padded float32 was the chip's
 tiling at work on a ``[t, k, d]`` array: the mixture layer's combine is held
-to the compiled program here too.
+to the compiled program here too, and so is the linear-attention layer's
+prefill: one Mosaic kernel, no chunk's matrices in HBM.
 
 One file, the topology in a fixture: only the worker that runs these tests
 loads the TPU's library (the on-chip-measurement guide, section 2)."""
@@ -106,3 +107,20 @@ def test_combine_forms_no_float32_array_of_the_expert_rows(topo, layer):
     assert sum(o["op"] == "fusion kCustom" and o["result"].startswith(rows)
                and o["scope"].endswith("combine/gather")
                for o in listed) == rec["k"]
+
+
+@pytest.mark.parametrize("program", ["256x4", "6144x1"])
+def test_linear_prefill_is_one_kernel_with_no_chunk_matrices(topo, program):
+    """``scripts/aot_gdn_prefill.py``'s count for one Gated DeltaNet layer
+    at cell 5's widths writing into its state store, as the cell's prefill
+    programs hold it (4 rows of 256, 1 row of 6144): Mosaic takes the
+    kernel at heads of 128, it is the one kernel under ``gdn/recurrence``,
+    and no operation has a float32 result of a chunk's ``[64, 64]``
+    matrices (the XLA form's ``[.., N, 64, 64]``: 11 and 15 of them, 1.7 GB
+    at 6144)."""
+    script = _script("aot_gdn_prefill")
+    with _traced_as_on_the_chip():
+        rec, _ = next(script.records(
+            topo, [p for p in script.PROGRAMS if p[0] == program]))
+    assert rec["mosaic_calls"] == 1
+    assert rec["chunk_arrays"] == 0

@@ -3,8 +3,8 @@ full-attention layers with a share of the experts held, against the plain
 reference that sits beside the benchmark's configuration
 (``benchmarks/configs/qwen3-next-80b-a3b-l4-ep2.py``: ``jax.numpy``,
 float32, the recurrence a token at a time, nothing of the program): the
-whole model's logits, the chunked form against the recurrence at fast and at
-slow decay, prefill and then decode through the block store and the state
+whole model's logits, the chunked form (the Pallas kernel and the XLA form)
+against the recurrence at fast and at slow decay, prefill and then decode through the block store and the state
 store, slots reused, a preempted request replayed, the two shares of the
 experts, what an engine refuses for such a model, its instruments, the
 kernels at heads of 256, and the scopes its device operations are found by.
@@ -29,6 +29,7 @@ from chainermn_tpu.models.qwen3_next import (
     recurrent_gated_delta_rule,
 )
 from chainermn_tpu.ops import flash_attention
+from chainermn_tpu.ops.gated_delta import chunk_gated_delta, kernel_takes
 from chainermn_tpu.parallel.moe import DroplessMoE
 from chainermn_tpu.parallel.sequence import (
     paged_scale_shape,
@@ -152,59 +153,133 @@ def test_the_spec_names_both_kinds_of_state(lm):
     assert isinstance(full, KVCacheKind) and full.layers == (3,)
     assert (full.kv_heads, full.head_dim, full.window) == (1, 16, None)
     assert isinstance(linear, SlotStateKind) and linear.layers == (0, 1, 2)
+    assert linear.chunk == CHUNK
     assert linear.arrays == (("S", (4, 8, 8), "float32"),
                              ("conv", (3, 2 * 16 + 32), "float32"))
 
 
 # -- the two forms of the gated delta rule ---------------------------------- #
 
-def _rule_inputs(seed, b, t, h, dk, dv, keep):
-    """Normed q and k, v, beta, and g such that a head keeps ``keep`` of
-    its state a token (a pair: the range)."""
+def _rule_inputs(seed, b, t, hk, hv, dk, dv, keep):
+    """q and k on ``hk`` key heads (not normed: :func:`_normed`), v, beta
+    and g on ``hv`` value heads, g such that a head keeps ``keep`` of its
+    state a token (a pair: the range)."""
     rng = np.random.default_rng(seed)
-    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(rng.standard_normal((b, t, h, dk))) / np.sqrt(dk)
-    k = unit(rng.standard_normal((b, t, h, dk)))
-    v = rng.standard_normal((b, t, h, dv))
-    beta = 1 / (1 + np.exp(-rng.standard_normal((b, t, h))))
-    g = np.log(rng.uniform(*keep, (b, t, h)))
+    q = rng.standard_normal((b, t, hk, dk))
+    k = rng.standard_normal((b, t, hk, dk))
+    v = rng.standard_normal((b, t, hv, dv))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((b, t, hv))))
+    g = np.log(rng.uniform(*keep, (b, t, hv)))
     return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
 
 
+def _normed(q, k, hv):
+    """q and k as the rule takes them: normed a head (``x * rsqrt(sum x^2
+    + 1e-6)``), q scaled by ``dk^-1/2``, repeated to the value heads that
+    read them."""
+    norm = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                       + 1e-6)
+    repeat = lambda x: jnp.repeat(x, hv // x.shape[2], axis=2)
+    return repeat(norm(q) * q.shape[-1] ** -0.5), repeat(norm(k))
+
+
+def _chunked_form(form, q, k, v, g, beta, valid):
+    """``(o, final state)`` of a chunked form over rows of ``valid`` real
+    tokens: the kernel takes the convolution's output as it is, q and k
+    unnormed, and ``valid``; the XLA form what the layer gives it, normed
+    q and k and ``g = beta = 0`` past a row's length."""
+    b, t, hk, dk = q.shape
+    hv = v.shape[2]
+    if form == "kernel":
+        qkv = jnp.concatenate([x.reshape(b, t, -1) for x in (q, k, v)], -1)
+        return chunk_gated_delta(qkv, g, beta, valid, k_heads=hk, dk=dk)
+    real = (jnp.arange(t)[None, :] < valid[:, None])[..., None]
+    return chunk_gated_delta_rule(*_normed(q, k, hv), v,
+                                  jnp.where(real, g, 0.0),
+                                  jnp.where(real, beta, 0.0))
+
+
+# (key heads, value heads, dk, dv): heads of whole lanes as served, and
+# narrow ones as the small model's
+WIDTHS = {"lanes": (2, 4, 128, 128), "narrow": (2, 4, 16, 8)}
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
 @pytest.mark.parametrize("t", [1, CHUNK - 1, CHUNK, CHUNK + 1, 150, 300])
 @pytest.mark.parametrize("keep", [(0.25, 0.75), (0.99, 0.999)])
-def test_chunked_form_is_the_recurrence(t, keep):
-    """Lengths that are no multiple of the chunk, heads that forget in a
-    few tokens and heads that keep 0.99-0.999 a token (a state 300 tokens
-    deep): outputs and final state to float32's rounding."""
-    args = _rule_inputs(t, 2, t, 3, 16, 8, keep)
-    o_rec, s_rec = recurrent_gated_delta_rule(*args)
-    o_chunk, s_chunk = chunk_gated_delta_rule(*args)
-    np.testing.assert_allclose(o_chunk, o_rec, atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(s_chunk, s_rec, atol=2e-5, rtol=2e-5)
+def test_chunked_form_is_the_recurrence(form, width, t, keep):
+    """Rows of a bucket of 320 as a prefill program holds them: one of
+    length ``t`` (no multiple of the chunk, or one), one 20 shorter and one
+    that holds no request (``valid == 0``); heads that forget in a few
+    tokens and heads that keep 0.99-0.999 a token (a state 300 tokens
+    deep). Each row's outputs and final state are the recurrence's over
+    its real tokens, to float32's rounding; the kernel gives zeros past a
+    row's length and a zero state for the empty row."""
+    hk, hv, dk, dv = WIDTHS[width]
+    args = _rule_inputs(t, 3, 320, hk, hv, dk, dv, keep)
+    valid = jnp.asarray([t, max(t - 20, 1), 0], jnp.int32)
+    o, state = _chunked_form(form, *args, valid)
+    for row, length in enumerate(np.asarray(valid)):
+        if length == 0:
+            assert float(jnp.max(jnp.abs(state[row]))) == 0.0
+            continue
+        q, k, v, g, beta = (x[row:row + 1, :length] for x in args)
+        o_rec, s_rec = recurrent_gated_delta_rule(*_normed(q, k, hv), v, g,
+                                                  beta)
+        np.testing.assert_allclose(o[row:row + 1, :length], o_rec,
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(state[row:row + 1], s_rec, atol=2e-5,
+                                   rtol=2e-5)
+    if form == "kernel":
+        past = jnp.arange(320)[None, :] >= valid[:, None]
+        assert float(jnp.max(jnp.abs(jnp.where(
+            past[..., None, None], o, 0.0)))) == 0.0
 
 
-def test_padding_of_a_bucket_row_leaves_the_state_alone():
-    """``g = 0`` and ``beta = 0`` past a row's length: the final state is
-    the state after its last real token, whatever the padding holds."""
-    q, k, v, g, beta = _rule_inputs(5, 2, 100, 3, 16, 8, (0.9, 0.99))
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_padding_of_a_bucket_row_leaves_the_state_alone(form):
+    """``g = 0`` and ``beta = 0`` past a row's length, the row given as
+    whole: the final state is the state after its last real token,
+    whatever the padding holds."""
+    q, k, v, g, beta = _rule_inputs(5, 2, 100, 2, 4, 16, 8, (0.9, 0.99))
     real = jnp.arange(100)[None, :, None] < jnp.asarray([37, 100])[:, None,
                                                                    None]
-    _, state = chunk_gated_delta_rule(q, k, v, jnp.where(real, g, 0.0),
-                                      jnp.where(real, beta, 0.0))
+    _, state = _chunked_form(form, q, k, v, jnp.where(real, g, 0.0),
+                        jnp.where(real, beta, 0.0), jnp.asarray([100, 100]))
     _, short = recurrent_gated_delta_rule(
-        *(x[:1, :37] for x in (q, k, v, g, beta)))
+        *_normed(q[:1, :37], k[:1, :37], 4),
+        *(x[:1, :37] for x in (v, g, beta)))
     np.testing.assert_allclose(state[0], short[0], atol=2e-5, rtol=2e-5)
 
 
+def test_kernel_takes_pairs_of_value_heads_on_whole_lanes():
+    """The one place the layer's whole-prompt form is chosen: the kernel
+    at two value heads of one key head a program, heads of whole tiles of
+    lanes (the published 16 key heads on 32 value heads of 128); the XLA
+    form elsewhere. The kernel itself refuses what it cannot pair."""
+    assert kernel_takes(16, 32, 128, 128) and kernel_takes(2, 4, 128, 128)
+    assert not kernel_takes(2, 4, 8, 8)           # the small model's
+    assert not kernel_takes(3, 3, 128, 128)       # one value head a key head
+    with pytest.raises(ValueError, match="two value heads of one key head"):
+        chunk_gated_delta(jnp.zeros((1, 64, 2 * 3 * 16 + 3 * 8)),
+                          jnp.zeros((1, 64, 3)), jnp.zeros((1, 64, 3)),
+                          k_heads=3, dk=16)
+
+
+@pytest.mark.parametrize("head", [8, 128])
 @pytest.mark.parametrize("keep", ["fast", "slow"])
-def test_layer_prefill_then_recurrence_is_the_whole_sequence(keep):
+def test_layer_prefill_then_recurrence_is_the_whole_sequence(keep, head):
     """The layer itself: a prompt through the chunked form into a slot's
     row (rows of unlike lengths, one of them padding only), then a token at
     a time through the recurrence on the store, against the whole sequence
-    at once."""
-    layer = GatedDeltaNet(d_model=32, n_k_heads=2, n_v_heads=4, d_k=8, d_v=8,
-                          conv_kernel=4, rms_norm_eps=1e-6,
+    at once. Heads of 128 run the kernel (``kernel_takes``), which leaves
+    zeros past a row's length: nothing the prefill hands on reads them
+    (the state after the last real token, the conv's last real inputs, the
+    outputs at real positions); heads of 8 run the XLA form."""
+    assert kernel_takes(2, 4, head, head) == (head == 128)
+    layer = GatedDeltaNet(d_model=32, n_k_heads=2, n_v_heads=4, d_k=head,
+                          d_v=head, conv_kernel=4, rms_norm_eps=1e-6,
                           compute_dtype=jnp.float32)
     a = jax.random.normal(jax.random.PRNGKey(0), (3, 90, 32))
     params = layer.init(jax.random.PRNGKey(1), a)
@@ -214,8 +289,8 @@ def test_layer_prefill_then_recurrence_is_the_whole_sequence(keep):
             if "A_log" in jax.tree_util.keystr(p) else x, params)
     whole, _ = layer.apply(params, a)
     lengths = jnp.asarray([70, 5, 0])
-    store = {"S": jnp.full((5, 4, 8, 8), 7.0),        # a former tenant's
-             "conv": jnp.full((5, 3, 64), 7.0)}
+    store = {"S": jnp.full((5, 4, head, head), 7.0),  # a former tenant's
+             "conv": jnp.full((5, 3, 8 * head), 7.0)}
     out, store = layer.apply(params, a[:, :80], dict(
         store, valid=lengths, slots=jnp.asarray([2, 0, 4])))
     np.testing.assert_allclose(out[0, :70], whole[0, :70], atol=1e-4,
@@ -450,11 +525,35 @@ def test_kv_stats_admission_and_the_three_instruments(lm):
     assert report["linear_state_tokens"] == 3 * (25 + (6 - 1) + (3 - 1))
     assert all(len(r.tokens) == n for r, n in zip(reqs, (6, 3)))
     names = {"serving_state_slots_live", "serving_state_bytes",
-             "linear_state_tokens_total"}
+             "linear_state_tokens_total", "linear_prefill_chunks_total"}
     assert names <= set(catalog.METRIC_NAMES)
     snap = get_registry().snapshot()
     assert names <= {key.split("{")[0] for kind in ("counters", "gauges")
                      for key in snap[kind]}
+
+
+def test_prefill_chunks_walked_and_skipped(lm):
+    """``linear_prefill_chunks_total``: a program walks ``cdiv(valid,
+    CHUNK)`` chunks of each row a linear layer (the rows' prompts), and
+    walked and skipped make the bucket's chunks times the rows it ran
+    (``prefill_rows_run_total``): prompts of 5 and 7 share a program of
+    bucket 8, 40 and 70 run in buckets 64 and 72 (two chunks a row), and
+    the last prompt of 6 runs one of bucket 8's two rows."""
+    model, params = lm
+    engine = engine_for(model, params)
+    work = [(5, 4), (7, 4), (40, 3), (70, 2), (6, 2)]
+    sched, reqs = serve(engine, work=work)
+    assert all(len(r.tokens) == a for r, (_, a) in zip(reqs, work))
+    report = sched.metrics.report()
+    assert report["prefill_batch_size_max"] == 2
+    layers = len(model.linear_layers())
+    live = layers * sum(-(-p // CHUNK) for p, _ in work)
+    assert report["linear_prefill_chunks_live"] == live == 18
+    rows_run = {bucket: int(run.value)
+                for bucket, (_, run) in sched.metrics._c_rows.items()}
+    assert live + report["linear_prefill_chunks_padding"] == layers * sum(
+        rows * -(-bucket // CHUNK) for bucket, rows in rows_run.items())
+    assert report["linear_prefill_chunks_padding"] > 0
 
 
 # -- the kernels at heads of 256 -------------------------------------------- #
