@@ -45,14 +45,20 @@ columns, a permutation that a checkpoint loader would apply.
 whole sequence from a zero state runs the chunked form (chunks of
 ``CHUNK``: inside a chunk one triangular system, solved by doubling;
 between chunks the state carries over), one token a row runs the recurrence
-on the state it is given. The chunked form is one Pallas kernel
-(:func:`~chainermn_tpu.ops.gated_delta.chunk_gated_delta`: the state in
-VMEM across a prompt's chunks, only the live chunks walked) at heads of
-whole lanes (:func:`~chainermn_tpu.ops.gated_delta.kernel_takes`: the
-published 128), and :func:`chunk_gated_delta_rule` in XLA at narrower ones
-(the tests' small model). Serving: ``kv_cache_spec()`` tells the engine two
-kinds of state, the full layers' K/V rows in a block store and the linear
-layers' ``S`` and the convolution's last inputs, a row a slot
+on the state it is given. Each form is one Pallas kernel at heads of whole
+lanes (the published 128) and XLA at narrower ones (the tests' small
+model). The chunked form:
+:func:`~chainermn_tpu.ops.gated_delta.chunk_gated_delta` (the state in
+VMEM across a prompt's chunks, only the live chunks walked;
+:func:`~chainermn_tpu.ops.gated_delta.kernel_takes`), else
+:func:`chunk_gated_delta_rule`. One token a row:
+:func:`~chainermn_tpu.ops.gated_delta.recurrent_gated_delta` (each batch
+row's state read once and written once where it lies in the store;
+:func:`~chainermn_tpu.ops.gated_delta.decode_kernel_takes`), else
+:func:`recurrent_gated_delta_step` over the whole store. Serving:
+``kv_cache_spec()`` tells the engine two kinds of state, the full layers'
+K/V rows in a block store and the linear layers' ``S`` and the
+convolution's last inputs, a row a slot
 (:class:`~chainermn_tpu.models.transformer.SlotStateKind`). With
 ``kv_caches`` the model takes whole fresh prompts from position 0 or one
 token a row; :class:`~chainermn_tpu.serving.ServingEngine` refuses what
@@ -78,7 +84,9 @@ from chainermn_tpu.models.transformer import KVCacheKind, SlotStateKind
 from chainermn_tpu.ops.gated_delta import (
     CHUNK,
     chunk_gated_delta,
+    decode_kernel_takes,
     kernel_takes,
+    recurrent_gated_delta,
 )
 from chainermn_tpu.parallel.moe import DroplessMoE
 
@@ -97,7 +105,9 @@ def recurrent_gated_delta_step(state, q, k, v, g, beta):
     read twice and written once, nothing of its size is kept beside it: the
     two reads against ``k`` and ``q`` are one pass, and ``o`` follows from
     them (``S_t^T q = exp(g) S^T q + d (k . q)``) without a pass over the
-    new state."""
+    new state. A layer's decode step at narrow heads; at heads of whole
+    lanes the kernel (:func:`~chainermn_tpu.ops.gated_delta.
+    recurrent_gated_delta`) computes the same and is held to it."""
     decay = jnp.exp(g)[..., None]                              # [R, H, 1]
     sk = jnp.sum(state * k[..., None], axis=-2)                # S^T k
     sq = jnp.sum(state * q[..., None], axis=-2)                # S^T q
@@ -215,6 +225,15 @@ class GatedDeltaNet(nn.Module):
     rms_norm_eps: float
     compute_dtype: jnp.dtype
 
+    def decodes_in_kernel(self) -> bool:
+        """Whether the decode step runs as one kernel
+        (:func:`~chainermn_tpu.ops.gated_delta.decode_kernel_takes`: heads
+        of whole lanes) or in XLA: the one place the form is chosen, read
+        by ``__call__`` and by the model's state kind, under which the
+        engine counts the rows a decode program advances."""
+        return decode_kernel_takes(self.n_k_heads, self.n_v_heads, self.d_k,
+                                   self.d_v)
+
     @nn.compact
     def __call__(self, a, state=None):
         dt = self.compute_dtype
@@ -260,6 +279,14 @@ class GatedDeltaNet(nn.Module):
             elif decode:
                 new_conv = jnp.where(valid[:, None, None] > 0, full[:, 1:],
                                      past.astype(u.dtype))
+        # the kernels take q, k and v as the convolution gives them; one
+        # token a row, as a row of ``[B, C]``, shaped here so that the
+        # convolution's fusion keeps its name
+        kernel = (self.decodes_in_kernel() if decode
+                  else kernel_takes(hk, hv, dk, dv))
+        if decode and kernel:
+            with jax.named_scope("conv"):
+                mixed = mixed[:, 0]
         with jax.named_scope("recurrence"):
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
@@ -268,7 +295,6 @@ class GatedDeltaNet(nn.Module):
                 real = jnp.arange(s)[None, :] < valid[:, None]
                 g = jnp.where(real[..., None], g, 0.0)
                 beta = jnp.where(real[..., None], beta, 0.0)
-            kernel = not decode and kernel_takes(hk, hv, dk, dv)
             if not kernel:
                 q = _l2norm(mixed[..., :key_dim].reshape(b, s, hk, dk))
                 k = _l2norm(mixed[..., key_dim:2 * key_dim].reshape(
@@ -277,11 +303,14 @@ class GatedDeltaNet(nn.Module):
                         for x in (q * dk ** -0.5, k))
                 v = mixed[..., 2 * key_dim:].reshape(b, s, hv, dv)
             new_state = None
-            if kernel:
-                # q, k and v read out of the convolution's output, q and k
-                # normed, inside the kernel
-                o, last = chunk_gated_delta(mixed, g, beta, valid,
-                                            k_heads=hk, dk=dk)
+            if decode and kernel:
+                # the batch rows' states read once and written once where
+                # they lie; q, k and v read out of the convolution's
+                # output, q and k normed, inside the kernel
+                o, new_s = recurrent_gated_delta(
+                    mixed, g[:, 0], beta[:, 0], state["S"], k_heads=hk,
+                    dk=dk)
+                o = o[:, None]
             elif decode:
                 # the store's rows past the batch (the scratch row) ride
                 # along with g = 0 and beta = 0, so the whole array is
@@ -292,13 +321,19 @@ class GatedDeltaNet(nn.Module):
                 o, new_s = recurrent_gated_delta_step(
                     state["S"], *(fit(x) for x in (q, k, v, g, beta)))
                 o = o[:b, None]
+            elif kernel:
+                # q, k and v read out of the convolution's output, q and k
+                # normed, inside the kernel
+                o, last = chunk_gated_delta(mixed, g, beta, valid,
+                                            k_heads=hk, dk=dk)
+            else:
+                o, last = chunk_gated_delta_rule(q, k, v, g, beta)
+            if decode:
                 new_state = {
                     "S": new_s,
                     "conv": lax.dynamic_update_slice_in_dim(
                         state["conv"], new_conv.astype(state["conv"].dtype),
                         0, axis=0)}
-            else:
-                o, last = chunk_gated_delta_rule(q, k, v, g, beta)
             if prefill:
                 new_state = {
                     "S": state["S"].at[state["slots"]].set(last),
@@ -350,6 +385,16 @@ class GatedAttention(nn.Module):
         return dense(self.d_model, "o_proj")(o), new_cache
 
 
+def _linear_mixer(m, **kw) -> GatedDeltaNet:
+    """The linear-attention mixer of a block or a model ``m``: both carry
+    its widths under the same names."""
+    return GatedDeltaNet(
+        d_model=m.d_model, n_k_heads=m.linear_k_heads,
+        n_v_heads=m.linear_v_heads, d_k=m.linear_k_dim,
+        d_v=m.linear_v_dim, conv_kernel=m.conv_kernel,
+        rms_norm_eps=m.rms_norm_eps, compute_dtype=m.compute_dtype, **kw)
+
+
 class Qwen3NextBlock(nn.Module):
     d_model: int
     linear: bool                    # a Gated DeltaNet layer
@@ -376,12 +421,7 @@ class Qwen3NextBlock(nn.Module):
         dt = self.compute_dtype
         a = nn.RMSNorm(epsilon=self.rms_norm_eps, dtype=dt, name="norm_1")(x)
         if self.linear:
-            y, new_cache = GatedDeltaNet(
-                d_model=self.d_model, n_k_heads=self.linear_k_heads,
-                n_v_heads=self.linear_v_heads, d_k=self.linear_k_dim,
-                d_v=self.linear_v_dim, conv_kernel=self.conv_kernel,
-                rms_norm_eps=self.rms_norm_eps, compute_dtype=dt,
-                name="gdn")(a, kv_cache)
+            y, new_cache = _linear_mixer(self, name="gdn")(a, kv_cache)
         else:
             y, new_cache = GatedAttention(
                 d_model=self.d_model, n_heads=self.n_heads,
@@ -455,7 +495,8 @@ class Qwen3NextLM(nn.Module):
                 ("S", (self.linear_v_heads, self.linear_k_dim,
                        self.linear_v_dim), "float32"),
                 ("conv", (self.conv_kernel - 1, conv_width),
-                 jnp.dtype(self.compute_dtype).name)), CHUNK))
+                 jnp.dtype(self.compute_dtype).name)), CHUNK,
+                _linear_mixer(self, parent=None).decodes_in_kernel()))
         return tuple(kinds)
 
     @nn.compact

@@ -65,12 +65,15 @@ class SlotStateKind:
     cannot be continued at an offset without the state at that offset.
     ``chunk``: the tokens a chunk of the kind's whole-prompt form holds,
     where a prefill walks a row's prompt chunk by chunk (``None``: it does
-    not); the engine counts the chunks a program walks and skips."""
+    not); the engine counts the chunks a program walks and skips.
+    ``decode_kernel``: whether a decode step advances the kind's rows in
+    one kernel (else in XLA); the engine counts the rows under each."""
 
     name: str
     layers: tuple
     arrays: tuple
     chunk: Optional[int] = None
+    decode_kernel: bool = False
 
 
 class TransformerBlock(nn.Module):

@@ -1,5 +1,7 @@
-"""Pallas TPU kernel for the gated delta rule over whole prompts (the
-linear-attention layer of :mod:`chainermn_tpu.models.qwen3_next`).
+"""Pallas TPU kernels for the gated delta rule (the linear-attention layer
+of :mod:`chainermn_tpu.models.qwen3_next`): over whole prompts
+(:func:`chunk_gated_delta`) and one token a row on the state store
+(:func:`recurrent_gated_delta`).
 
 Per value head the rule keeps a float32 state ``S [dk, dv]`` from zero and,
 token by token, ``S = exp(g_t) S``, ``d = beta_t (v_t - S^T k_t)``,
@@ -48,10 +50,23 @@ here:
   (I + A)^{-1} x``, so a chunk reads ``S`` through one product, ``[k; q]
   S``, and no ``u`` or ``w`` is formed.
 
-Off TPU the kernel runs in Pallas interpret mode
+One token a row (a decode step), the XLA form (``recurrent_gated_delta_step``
+in the model) must form ``S^T k`` before it can write the new state, so it
+passes over the whole store twice: one fusion reads it, a second reads it
+again and writes it. :func:`recurrent_gated_delta` makes one pass: a program
+copies a batch row's states into VMEM, forms ``S^T k`` and ``S^T q`` (sums
+down the sublanes), ``d``, the output and the new state from the copy, and
+writes the new state back over the old (``input_output_aliases``). It walks
+the batch rows only, so the store's rows past them (the scratch row) are
+neither read nor written; ``q`` and ``k`` are read at key-head width and
+normed in the kernel, as above. The copies bind it: on the v5e it takes
+what a kernel that only copies the same blocks takes (PERF.md).
+
+Off TPU the kernels run in Pallas interpret mode
 (:func:`~chainermn_tpu.ops.flash_attention.kernels_interpreted`), which takes
 any head width; Mosaic takes heads whose widths are whole tiles of 128 lanes,
-and a layer runs the kernel at those (:func:`kernel_takes`).
+and a layer runs the kernels at those (:func:`kernel_takes`,
+:func:`decode_kernel_takes`).
 """
 
 from __future__ import annotations
@@ -295,3 +310,120 @@ def _rule(qkv, g, beta, valid, *, k_heads: int, dk: int, eps: float,
         interpret=interpret,
     )(valid, qkv, qkv, qkv, g, beta)
     return (o[:, :t] if pad else o), state
+
+
+def decode_kernel_takes(k_heads: int, v_heads: int, dk: int, dv: int) -> bool:
+    """Whether a layer runs its decode step through
+    :func:`recurrent_gated_delta`: value heads a whole number a key head,
+    each head's ``[dk, dv]`` tile of the state whole tiles of lanes, as
+    Mosaic copies it. Narrower heads (the tests' small model) run the step
+    in XLA."""
+    return v_heads % k_heads == 0 and dk % _LANE == 0 and dv % _LANE == 0
+
+
+def _step_kernel(qkv_ref, g_ref, beta_ref, s_ref, o_ref, s_out_ref, *,
+                 k_heads: int, dk: int, eps: float):
+    """One batch row of the step: ``qkv_ref [rb, C]``, ``g_ref`` and
+    ``beta_ref [rb, Hv]`` hold ``rb`` batch rows of which this program's is
+    ``program % rb``; ``o_ref [1, Hv, dv]`` its output, ``s_ref`` and
+    ``s_out_ref [1, Hv, dk, dv]`` its states, one buffer in HBM."""
+    _, hv, _, dv = s_ref.shape
+    group = hv // k_heads
+    at = pl.ds(pl.program_id(0) % qkv_ref.shape[0], 1)
+    width = -(-2 * k_heads // 8) * 8
+    sub = jax.lax.broadcasted_iota(jnp.int32, (width, dk), 0)
+    lane_zeros = jnp.zeros((1, dv), jnp.float32)
+    x = qkv_ref[at, :]                                            # [1, C]
+    decay = jnp.exp(g_ref[at, :])                                 # [1, Hv]
+    beta = beta_ref[at, :]
+    # q and k of each key head normed, q scaled by dk^-1/2, then as
+    # columns: row j of [width, dk] is head j (q's, then k's)
+    heads = []
+    for j in range(2 * k_heads):
+        y = x[:, j * dk:(j + 1) * dk]
+        y = y * jax.lax.rsqrt(jnp.sum(y * y, axis=1, keepdims=True) + eps)
+        heads.append(y * dk ** -0.5 if j < k_heads else y)
+    cols = sum(jnp.where(sub == j, y, 0.0)
+               for j, y in enumerate(heads)).T                    # [dk, W]
+    for j in range(k_heads):
+        kq = jnp.sum(heads[j] * heads[k_heads + j], axis=1,
+                     keepdims=True)                               # [1, 1]
+        qc = jnp.broadcast_to(cols[:, j:j + 1], (dk, dv))
+        kc = jnp.broadcast_to(cols[:, k_heads + j:k_heads + j + 1], (dk, dv))
+        for h in range(j * group, (j + 1) * group):
+            s = s_ref[0, h]                                       # [dk, dv]
+            # along the lanes first (Mosaic broadcasts [1, 1] along one
+            # axis at a time), then down the sublanes where used
+            e = decay[:, h:h + 1] + lane_zeros
+            at_v = 2 * k_heads * dk + h * dv
+            d = beta[:, h:h + 1] * (
+                x[:, at_v:at_v + dv]
+                - e * jnp.sum(s * kc, axis=0, keepdims=True))
+            o_ref[0, h:h + 1, :] = (
+                e * jnp.sum(s * qc, axis=0, keepdims=True) + d * kq)
+            s_out_ref[0, h] = e * s + kc * d
+
+
+def recurrent_gated_delta(qkv, g, beta, state, *, k_heads: int, dk: int,
+                          eps: float = 1e-6,
+                          interpret: Optional[bool] = None):
+    """One token of the gated delta rule for each batch row, on the state
+    store in place.
+
+    - ``qkv``: ``[B, 2 Hk dk + Hv dv]`` float32, a row's token as the
+      convolution gives it: ``q`` and ``k`` on ``k_heads`` key heads of
+      ``dk`` (normed here, ``x * rsqrt(sum x^2 + eps)`` a head, and ``q``
+      scaled by ``dk^-1/2``), then ``v``; value head ``h`` reads key head
+      ``h // (Hv / Hk)``;
+    - ``g``, ``beta``: ``[B, Hv]`` float32;
+    - ``state``: ``[R, Hv, dk, dv]`` float32, ``R >= B``: batch row ``i``
+      advances store row ``i``. Rows past ``B`` are neither read nor
+      written, and a batch row with ``g = 0`` and ``beta = 0`` keeps its
+      state as it is.
+
+    Returns ``(o [B, Hv, dv], new state [R, Hv, dk, dv])``; the new state
+    is the same buffer as ``state`` (``input_output_aliases``), so a caller
+    that donates the store updates it where it lies. Off TPU runs in
+    interpret mode by default."""
+    hv = state.shape[1]
+    if hv % k_heads:
+        raise ValueError(f"{hv} value heads on {k_heads} key heads: a key "
+                         "head is read by a whole number of value heads")
+    if interpret is None:
+        interpret = kernels_interpreted()
+    return _step(qkv, g, beta, state, k_heads=k_heads, dk=dk,
+                 eps=float(eps), interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("k_heads", "dk", "eps",
+                                             "interpret"), inline=True)
+def _step(qkv, g, beta, state, *, k_heads: int, dk: int, eps: float,
+          interpret: bool):
+    """:func:`recurrent_gated_delta` with its defaults filled in, traced
+    once for a model's layers and written into the caller's trace under
+    the caller's names (``.../gdn/recurrence``), as :func:`_rule`.
+
+    A grid step takes one store row (the published 32 value heads: 2 MB
+    each way); the batch's small operands go in blocks of ``rb`` rows (8,
+    or the whole batch where 8 does not divide it), as Mosaic tiles them,
+    so a block of them is copied once for the grid steps that read it."""
+    b, c = qkv.shape
+    hv, _, dv = state.shape[1:]
+    rb = 8 if b % 8 == 0 else b
+    small = lambda width: pl.BlockSpec((rb, width), lambda i: (i // rb, 0))
+    s_spec = pl.BlockSpec((1, hv, dk, dv), lambda i: (i, 0, 0, 0))
+    vma = _out_vma(qkv, g, beta, state)
+    return pl.pallas_call(
+        functools.partial(_step_kernel, k_heads=k_heads, dk=dk, eps=eps),
+        grid=(b,),
+        in_specs=[small(c), small(hv), small(hv), s_spec],
+        out_specs=[pl.BlockSpec((1, hv, dv), lambda i: (i, 0, 0)),
+                   s_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hv, dv), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(state.shape, jnp.float32, vma=vma)],
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(qkv, g, beta, state)

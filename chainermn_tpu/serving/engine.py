@@ -702,6 +702,13 @@ class ServingEngine:
             self._chunked = [(k.chunk, len(k.layers)) for k in state_spec
                              if k.chunk]
             self._prefill_chunks = np.zeros(2, np.int64)
+            # the rows x layers a decode program runs through the kinds'
+            # step, in a kernel and in XLA: every slot's row, held or not
+            self._decode_rows_step = np.zeros(2, np.int64)
+            for k in state_spec:
+                self._decode_rows_step[0 if k.decode_kernel else 1] += (
+                    self.n_slots * len(k.layers))
+            self._decode_rows = np.zeros(2, np.int64)
             # prefix reuse runs on the one pool of a model whose layers
             # are all of a kind; with window layers or a recurrent state
             # nothing is inserted or matched
@@ -2602,21 +2609,26 @@ class ServingEngine:
         }
 
     def pop_state_stats(self) -> Optional[tuple]:
-        """``(slots live, bytes, tokens, live chunks, padding chunks)`` of
-        the state kept a row a slot: rows holding a request now, the bytes
-        of all such arrays, the tokens x layers whose state the programs
-        advanced, and the chunks x layers that the prefill programs'
-        whole-prompt form walked and skipped (``SlotStateKind.chunk``),
-        each since the last call (cleared on read); ``None`` for a model
-        that keeps none. The scheduler drains it into
+        """``(slots live, bytes, tokens, live chunks, padding chunks,
+        kernel rows, xla rows)`` of the state kept a row a slot: rows
+        holding a request now, the bytes of all such arrays, the tokens x
+        layers whose state the programs advanced, the chunks x layers that
+        the prefill programs' whole-prompt form walked and skipped
+        (``SlotStateKind.chunk``), and the rows x layers the decode
+        programs ran through the step in a kernel and in XLA
+        (``SlotStateKind.decode_kernel``), each since the last call
+        (cleared on read); ``None`` for a model that keeps none. The
+        scheduler drains it into
         :class:`~chainermn_tpu.serving.metrics.ServingMetrics`."""
         if not (self.paged and self._state):
             return None
         tokens, self._state_tokens = self._state_tokens, 0
         live, padding = (int(x) for x in self._prefill_chunks)
         self._prefill_chunks[:] = 0
+        kernel, xla = (int(x) for x in self._decode_rows)
+        self._decode_rows[:] = 0
         return (self.active_slots, sum(st.bytes for st in self._state),
-                tokens, live, padding)
+                tokens, live, padding, kernel, xla)
 
     def flush_inserts(self) -> None:
         """Run the deferred trie inserts (one compiled copy per prompt
@@ -2745,6 +2757,7 @@ class ServingEngine:
             if self.paged:
                 self._state_tokens += (int(self._active.sum())
                                        * self._state_layers)
+                self._decode_rows += self._decode_rows_step
             out = {}
             for slot in np.flatnonzero(self._active):
                 slot = int(slot)

@@ -110,6 +110,11 @@ class ServingMetrics:
             kind: reg.counter("linear_prefill_chunks_total",
                               dict(labels, kind=kind))
             for kind in ("live", "padding")}
+        # rows x layers a decode program ran through the step, by form
+        self._c_decode_rows = {
+            path: reg.counter("linear_decode_rows_total",
+                              dict(labels, path=path))
+            for path in ("kernel", "xla")}
         self._g_queue = reg.gauge("serving_queue_depth_now", labels)
         self._g_active = reg.gauge("serving_active_slots", labels)
         # paged-KV series (PR 7): store occupancy gauges sampled per step,
@@ -218,16 +223,20 @@ class ServingMetrics:
 
     def record_slot_state(self, slots_live: int, n_bytes: int,
                           tokens: int, chunks_live: int,
-                          chunks_padding: int) -> None:
+                          chunks_padding: int, rows_kernel: int,
+                          rows_xla: int) -> None:
         """The engine's state kept a row a slot: rows holding a request
         now, the bytes of its arrays, the tokens x layers whose state the
-        programs advanced, and the chunks x layers the prefill programs
-        walked and skipped, since the last call."""
+        programs advanced, the chunks x layers the prefill programs walked
+        and skipped, and the rows x layers the decode programs ran
+        through the step in a kernel and in XLA, since the last call."""
         self._g_state_slots.set(slots_live)
         self._g_state_bytes.set(n_bytes)
         self._c_state_tokens.inc(tokens)
         self._c_prefill_chunks["live"].inc(chunks_live)
         self._c_prefill_chunks["padding"].inc(chunks_padding)
+        self._c_decode_rows["kernel"].inc(rows_kernel)
+        self._c_decode_rows["xla"].inc(rows_xla)
 
     def record_token(self, t_prev_token: float, t_token: float) -> None:
         self._h_tpot.observe(t_token - t_prev_token)
@@ -471,6 +480,8 @@ class ServingMetrics:
             out["linear_state_tokens"] = int(self._c_state_tokens.value)
             for kind, counter in self._c_prefill_chunks.items():
                 out[f"linear_prefill_chunks_{kind}"] = int(counter.value)
+            for path, counter in self._c_decode_rows.items():
+                out[f"linear_decode_rows_{path}"] = int(counter.value)
         for hist, prefix in ((self._h_queue, "queue_depth"),
                              (self._h_occ, "slot_occupancy")):
             samples = hist.samples

@@ -5,8 +5,9 @@ refuses; these hold the decode layer and the scale write to the real
 lowering at the served widths, and count the passes over a scale array.
 ISSUE 36's relayout of every expert row into padded float32 was the chip's
 tiling at work on a ``[t, k, d]`` array: the mixture layer's combine is held
-to the compiled program here too, and so is the linear-attention layer's
-prefill: one Mosaic kernel, no chunk's matrices in HBM.
+to the compiled program here too, and so is the linear-attention layer:
+its prefill one Mosaic kernel with no chunk's matrices in HBM, its decode
+step one Mosaic kernel that passes over the state store once, in place.
 
 One file, the topology in a fixture: only the worker that runs these tests
 loads the TPU's library (the on-chip-measurement guide, section 2)."""
@@ -124,3 +125,18 @@ def test_linear_prefill_is_one_kernel_with_no_chunk_matrices(topo, program):
             topo, [p for p in script.PROGRAMS if p[0] == program]))
     assert rec["mosaic_calls"] == 1
     assert rec["chunk_arrays"] == 0
+
+
+def test_linear_decode_passes_over_the_state_store_once(topo):
+    """The same layer's decode program, one token for each of cell 5's 256
+    slots, the store donated: Mosaic takes the kernel at heads of 128, and
+    the one operation with the whole ``f32[257, 32, 128, 128]`` store as an
+    operand or result is that kernel: no copy of it, no second pass (the
+    XLA form's two fusions, one reading it and one reading and writing
+    it)."""
+    script = _script("aot_gdn_prefill")
+    with _traced_as_on_the_chip():
+        rec, _ = next(script.records(topo, script.DECODE, decode=True))
+    assert rec["mosaic_calls"] == 1
+    assert rec["store_op_kinds"] == ["custom-call"]
+    assert rec["store_copies"] == 0

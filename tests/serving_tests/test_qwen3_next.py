@@ -22,14 +22,21 @@ import numpy as np
 import pytest
 
 from chainermn_tpu.models import KVCacheKind, Qwen3NextLM, SlotStateKind
+from chainermn_tpu.models import qwen3_next
 from chainermn_tpu.models.qwen3_next import (
     CHUNK,
     GatedDeltaNet,
     chunk_gated_delta_rule,
     recurrent_gated_delta_rule,
+    recurrent_gated_delta_step,
 )
 from chainermn_tpu.ops import flash_attention
-from chainermn_tpu.ops.gated_delta import chunk_gated_delta, kernel_takes
+from chainermn_tpu.ops.gated_delta import (
+    chunk_gated_delta,
+    decode_kernel_takes,
+    kernel_takes,
+    recurrent_gated_delta,
+)
 from chainermn_tpu.parallel.moe import DroplessMoE
 from chainermn_tpu.parallel.sequence import (
     paged_scale_shape,
@@ -312,6 +319,111 @@ def test_layer_prefill_then_recurrence_is_the_whole_sequence(keep, head):
     assert float(jnp.max(jnp.abs(store["S"][1] - 7.0))) == 0.0
 
 
+# -- the decode step as one kernel ------------------------------------------ #
+
+def _step_inputs(seed, rows, hk, hv, scale=1.0):
+    """A token's convolution output for each of ``rows`` batch rows (q and
+    k unnormed, on ``hk`` key heads of 128, then v on ``hv`` value heads),
+    g and beta, and a store of ``rows + 2`` rows of ``scale``-sized
+    states: the rows past the batch stand for the scratch row."""
+    rng = np.random.default_rng(seed)
+    qkv = rng.standard_normal((rows, (2 * hk + hv) * 128))
+    g = np.log(rng.uniform(0.25, 0.999, (rows, hv)))
+    beta = 1 / (1 + np.exp(-rng.standard_normal((rows, hv))))
+    store = scale * rng.standard_normal((rows + 2, hv, 128, 128))
+    return [jnp.asarray(x, jnp.float32) for x in (qkv, g, beta, store)]
+
+
+def _step_reference(qkv, g, beta, state, hk):
+    """:func:`recurrent_gated_delta_step` on the batch rows, q and k split
+    out, normed and repeated as the XLA form of the layer gives them."""
+    rows, hv = g.shape
+    q, k = (qkv[:, i * hk * 128:(i + 1) * hk * 128].reshape(rows, 1, hk, 128)
+            for i in (0, 1))
+    q, k = (x[:, 0] for x in _normed(q, k, hv))
+    v = qkv[:, 2 * hk * 128:].reshape(rows, hv, 128)
+    return recurrent_gated_delta_step(state[:rows], q, k, v, g, beta)
+
+
+# (key heads, value heads) at heads of 128: the engine test's one key head,
+# the published ratio of two value heads a key head, the published 16 on 32
+DECODE_HEADS = [(1, 2), (2, 4), (16, 32)]
+
+
+@pytest.mark.parametrize("heads", DECODE_HEADS, ids=str)
+@pytest.mark.parametrize("rows", [1, 3, 8])
+@pytest.mark.parametrize("scale", [1.0, 1e-30], ids=["random", "near-zero"])
+def test_decode_kernel_is_the_step(heads, rows, scale):
+    """One token a row through the kernel against the XLA step: outputs
+    and new states to float32's rounding, and the store's rows past the
+    batch as they were, bit for bit (the kernel neither reads nor writes
+    them)."""
+    hk, hv = heads
+    qkv, g, beta, store = _step_inputs(rows, rows, hk, hv, scale)
+    o, new = recurrent_gated_delta(qkv, g, beta, store, k_heads=hk, dk=128)
+    o_ref, s_ref = _step_reference(qkv, g, beta, store, hk)
+    np.testing.assert_allclose(o, o_ref, rtol=1e-5,
+                               atol=1e-5 * float(jnp.max(jnp.abs(o_ref))))
+    np.testing.assert_allclose(new[:rows], s_ref, rtol=1e-5,
+                               atol=1e-5 * float(jnp.max(jnp.abs(s_ref))))
+    np.testing.assert_array_equal(new[rows:], store[rows:])
+
+
+@pytest.mark.parametrize("heads", DECODE_HEADS, ids=str)
+def test_decode_kernel_keeps_a_row_that_holds_nothing(heads):
+    """Rows with ``g = 0`` and ``beta = 0`` (a slot that holds no request)
+    give back their state bit for bit; the rows beside them advance."""
+    hk, hv = heads
+    qkv, g, beta, store = _step_inputs(7, 4, hk, hv)
+    idle = jnp.asarray([True, False, True, False])[:, None]
+    g, beta = (jnp.where(idle, 0.0, x) for x in (g, beta))
+    _, new = recurrent_gated_delta(qkv, g, beta, store, k_heads=hk, dk=128)
+    np.testing.assert_array_equal(new[0::2], store[0::2])
+    assert float(jnp.min(jnp.max(jnp.abs(new[1:4:2] - store[1:4:2]),
+                                 axis=(1, 2, 3)))) > 1e-3
+
+
+@pytest.mark.parametrize("heads", DECODE_HEADS[:2], ids=str)
+def test_decode_kernel_twenty_steps_are_the_recurrence(heads):
+    """Twenty tokens a token at a time on a store, from zero, against the
+    recurrence over the same tokens at once."""
+    hk, hv = heads
+    rows, steps = 3, 20
+    q, k, v, g, beta = _rule_inputs(11, rows, steps, hk, hv, 128, 128,
+                                    (0.9, 0.999))
+    store = jnp.zeros((rows + 1, hv, 128, 128), jnp.float32)
+    outs = []
+    for t in range(steps):
+        qkv = jnp.concatenate(
+            [x[:, t].reshape(rows, -1) for x in (q, k, v)], -1)
+        o, store = recurrent_gated_delta(qkv, g[:, t], beta[:, t], store,
+                                         k_heads=hk, dk=128)
+        outs.append(o)
+    o_rec, s_rec = recurrent_gated_delta_rule(*_normed(q, k, hv), v, g,
+                                              beta)
+    np.testing.assert_allclose(jnp.stack(outs, 1), o_rec, atol=2e-5,
+                               rtol=2e-5)
+    np.testing.assert_allclose(store[:rows], s_rec, atol=2e-5, rtol=2e-5)
+    assert float(jnp.max(jnp.abs(store[rows]))) == 0.0
+
+
+def test_decode_kernel_takes_heads_of_whole_lanes():
+    """The one place the layer's decode form is chosen: the kernel at heads
+    of whole tiles of lanes and a whole number of value heads a key head
+    (the published 16 on 32 of 128), XLA at the small model's 8."""
+    assert decode_kernel_takes(16, 32, 128, 128)
+    assert decode_kernel_takes(1, 2, 128, 128)
+    assert decode_kernel_takes(3, 3, 128, 128)
+    assert not decode_kernel_takes(2, 4, 8, 8)
+    assert not decode_kernel_takes(2, 4, 128, 64)
+    assert not decode_kernel_takes(2, 3, 128, 128)
+    with pytest.raises(ValueError, match="whole number of value heads"):
+        recurrent_gated_delta(jnp.zeros((1, 5 * 128)), jnp.zeros((1, 3)),
+                              jnp.zeros((1, 3)),
+                              jnp.zeros((2, 3, 128, 128)), k_heads=2,
+                              dk=128)
+
+
 # -- prefill, then decode, through both stores ------------------------------ #
 
 def served_gap(params, prompt, served):
@@ -511,21 +623,28 @@ def test_kv_stats_admission_and_the_three_instruments(lm):
     assert list(engine.blocks_needed(40, 60)) == [13, 1]
     assert engine.kv_blocks_admittable()[1] == 3
     sched = FCFSScheduler(engine)
+    steps = engine._c_decode_steps.value       # the process's, shared
     reqs = [sched.submit(np.arange(p), a) for p, a in ((5, 6), (20, 3))]
     sched.step()
     sched.step()
     assert engine.kv_stats()["kinds"]["linear"]["slots_live"] == 2
     assert engine.kv_blocks_admittable()[1] == 1
     sched.run_until_idle()
+    steps = int(engine._c_decode_steps.value - steps)
     report = sched.metrics.report()
     assert report["state_bytes"] == kinds["linear"]["bytes"]
     assert report["state_slots_live"] == 0
     # tokens x linear layers whose state a program advanced: both prompts,
     # and every token decoded after a request's first
     assert report["linear_state_tokens"] == 3 * (25 + (6 - 1) + (3 - 1))
+    # heads of 8 decode in XLA: every decode step's slots x linear layers
+    assert steps > 0
+    assert (report["linear_decode_rows_kernel"],
+            report["linear_decode_rows_xla"]) == (0, steps * 3 * 3)
     assert all(len(r.tokens) == n for r, n in zip(reqs, (6, 3)))
     names = {"serving_state_slots_live", "serving_state_bytes",
-             "linear_state_tokens_total", "linear_prefill_chunks_total"}
+             "linear_state_tokens_total", "linear_prefill_chunks_total",
+             "linear_decode_rows_total"}
     assert names <= set(catalog.METRIC_NAMES)
     snap = get_registry().snapshot()
     assert names <= {key.split("{")[0] for kind in ("counters", "gauges")
@@ -554,6 +673,70 @@ def test_prefill_chunks_walked_and_skipped(lm):
     assert live + report["linear_prefill_chunks_padding"] == layers * sum(
         rows * -(-bucket // CHUNK) for bucket, rows in rows_run.items())
     assert report["linear_prefill_chunks_padding"] > 0
+
+
+# a model of one linear layer and one full layer whose linear heads are 128
+# wide (one key head, two value heads), so that its decode step takes the
+# kernel (``decode_kernel_takes``)
+WIDE = dict(CFG, num_hidden_layers=2, full_attention_interval=2,
+            linear_num_key_heads=1, linear_num_value_heads=2,
+            linear_key_head_dim=128, linear_value_head_dim=128)
+
+
+def _calls(jaxpr, name):
+    """The equations of primitive ``name`` in ``jaxpr`` and the jaxprs
+    inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _calls(inner, name)
+
+
+def test_decode_kernel_serves_what_the_xla_step_serves(monkeypatch):
+    """An engine over a model whose linear heads are 128 wide decodes
+    through the kernel: the decode program hands the kernel the state
+    store as the buffer it writes (aliased, no copy), the requests get the
+    greedy tokens the same model gets forced onto the XLA step, and
+    ``linear_decode_rows_total`` counts every decode step's slots x linear
+    layers under ``kernel`` (under ``xla`` when forced)."""
+    model = build(WIDE)
+    params = seeded(model)
+    # four requests on three slots (one waits for a freed slot), two
+    # prompts in one program of bucket 8
+    work = [(5, 12), (40, 16), (7, 9), (33, 6)]
+
+    def run():
+        """The engine, its scheduler's ``linear_decode_rows_total`` by
+        path, the slots x decode steps it ran (its step counter is the
+        process's, shared by every engine) and the tokens served."""
+        engine = engine_for(model, params, prefill_buckets=(8, 64))
+        steps = engine._c_decode_steps.value
+        sched, reqs = serve(engine, work=work)
+        assert all(len(r.tokens) == a for r, (_, a) in zip(reqs, work))
+        report = sched.metrics.report()
+        rows = int(engine._c_decode_steps.value - steps) * engine.n_slots
+        assert rows > 0
+        return engine, (report["linear_decode_rows_kernel"],
+                        report["linear_decode_rows_xla"]), rows, [
+                            list(r.tokens) for r in reqs]
+
+    engine, counted, rows, tokens = run()
+    assert counted == (rows, 0)
+    store = engine._store[0]["S"]
+    steps = [eqn for eqn in _calls(jax.make_jaxpr(engine._decode_fn)(
+        *engine._decode_args()).jaxpr, "pallas_call")
+        if eqn.invars[3].aval.shape == store.shape]
+    assert len(steps) == len(model.linear_layers()) == 1
+    assert steps[0].params["input_output_aliases"] == ((3, 1),)
+    monkeypatch.setattr(qwen3_next, "decode_kernel_takes",
+                        lambda *heads: False)
+    _, counted, rows, forced = run()
+    assert counted == (0, rows)
+    assert forced == tokens
 
 
 # -- the kernels at heads of 256 -------------------------------------------- #
